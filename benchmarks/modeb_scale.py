@@ -7,7 +7,7 @@ with a small dirty set out of 10k groups, and a killed node converging
 after missing one commit on EVERY group.
 
 Usage: python benchmarks/modeb_scale.py [--groups 10240] [--platform cpu]
-Prints JSON lines; commit the output into the current round artifact (benchmarks/results_r5.json).
+Prints JSON lines.
 """
 
 from __future__ import annotations

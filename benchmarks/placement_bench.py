@@ -22,7 +22,7 @@ real placement counters (EWMA demand folded on device through the compact
 dispatch).
 
 Usage: python benchmarks/placement_bench.py [--rebalance] [--ticks N] ...
-Prints one JSON line; commit into benchmarks/results_placement_pr2.json.
+Prints one JSON line.
 """
 
 from __future__ import annotations

@@ -14,7 +14,7 @@ names round-robining across active subsets, k names in flight at a time.
 
 Usage: python benchmarks/reconfig_rate.py [--names N] [--rounds R]
        [--inflight K]
-Prints one JSON line; commit the output into results_r5.json.
+Prints one JSON line.
 """
 
 from __future__ import annotations

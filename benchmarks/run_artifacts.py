@@ -30,8 +30,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax  # noqa: E402
 
-if os.environ.get("GPTPU_BENCH_PLATFORM"):
-    jax.config.update("jax_platforms", os.environ["GPTPU_BENCH_PLATFORM"])
+# every artifact this script writes is a CPU record (ROADMAP A6), and it
+# spawns sibling scripts after running JAX work itself: pinned to the CPU,
+# the parent never holds a chip a child would need
+jax.config.update("jax_platforms", "cpu")
 
 
 def bench_capacity(groups: int = 10, init_load: float = 200.0,

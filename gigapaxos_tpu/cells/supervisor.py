@@ -26,6 +26,7 @@ to it (``make_client``), so group->cell resolution needs zero RC hops.
 
 from __future__ import annotations
 
+import glob
 import json
 import os
 import queue
@@ -48,6 +49,36 @@ from ..utils import reqtrace
 from .routing import CellRouter
 
 SUP_ID = "SUP"
+
+
+def visible_tpu_chips() -> int:
+    """TPU chips this host would hand a JAX process, learned WITHOUT
+    importing JAX: a supervisor that initialised the backend would hold the
+    chips its workers need.  0 when the environment pins another platform
+    (``JAX_PLATFORMS=cpu``) or shows no chip.  ``TPU_VISIBLE_CHIPS`` is
+    libtpu's own confinement variable; otherwise count the device nodes."""
+    plats = os.environ.get("JAX_PLATFORMS", "")
+    if plats and "tpu" not in plats.split(","):
+        return 0
+    vis = os.environ.get("TPU_VISIBLE_CHIPS")
+    if vis is not None:
+        return len([c for c in vis.split(",") if c.strip()])
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def chip_env(chip: int, port: int) -> Dict[str, str]:
+    """Environment that confines one worker to one chip of a multi-chip
+    host as a single-process slice of its own (libtpu's variables; each
+    process needs its own slice-builder port)."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(chip),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_ADDRESSES": f"localhost:{port}",
+        "TPU_PROCESS_PORT": str(port),
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
 
 
 def free_port() -> int:
@@ -79,6 +110,13 @@ class CellSpec:
     drain_timeout_s: float = 10.0
     flight: Optional[str] = None
     stats_interval_s: float = 2.0
+    #: how long the worker's planes may take to come up (their compile):
+    #: the supervisor waits this long for "ready", so the worker must not
+    #: give up on its own planes any sooner
+    ready_timeout_s: float = 600.0
+    #: extra process environment (chip confinement) — not part of the
+    #: worker's JSON spec
+    env: Dict[str, str] = field(default_factory=dict)
 
     def to_json(self) -> str:
         return json.dumps({
@@ -93,6 +131,7 @@ class CellSpec:
             "drain_timeout_s": self.drain_timeout_s,
             "flight": self.flight,
             "stats_interval_s": self.stats_interval_s,
+            "ready_timeout_s": self.ready_timeout_s,
         })
 
 
@@ -107,13 +146,21 @@ class CellHandle:
             os.path.abspath(__file__))))
         env["PYTHONPATH"] = root + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-        env.pop("JAX_PLATFORMS", None)  # the worker forces cpu itself
-        self.proc = subprocess.Popen(
-            [python or sys.executable, "-m", "gigapaxos_tpu.cells.worker",
-             spec.to_json()],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE,
-            stderr=subprocess.DEVNULL, text=True, env=env,
-        )
+        # the worker runs on whatever platform this environment names
+        # (JAX_PLATFORMS and XLA_FLAGS pass through), on the chip spec.env
+        # confines it to
+        env.update(spec.env)
+        # a worker that cannot get its device says so on stderr: keep it
+        cell_dir = os.path.dirname(spec.wal_dir)
+        os.makedirs(cell_dir, exist_ok=True)
+        self.stderr_path = os.path.join(cell_dir, "worker.stderr")
+        with open(self.stderr_path, "ab") as err:
+            self.proc = subprocess.Popen(
+                [python or sys.executable, "-m",
+                 "gigapaxos_tpu.cells.worker", spec.to_json()],
+                stdin=subprocess.PIPE, stdout=subprocess.PIPE,
+                stderr=err, text=True, env=env,
+            )
         self.lines: "queue.Queue[str]" = queue.Queue()
         threading.Thread(target=self._read, daemon=True,
                          name=f"cell{spec.cell}-out").start()
@@ -132,10 +179,16 @@ class CellHandle:
             left = deadline - time.monotonic()
             if left <= 0:
                 raise TimeoutError(
-                    f"cell {self.spec.cell}: no '{prefix}' line")
+                    f"cell {self.spec.cell}: no '{prefix}' line "
+                    f"(worker stderr: {self.stderr_path})")
             try:
-                line = self.lines.get(timeout=left)
+                line = self.lines.get(timeout=min(left, 0.5))
             except queue.Empty:
+                if self.proc.poll() is not None and self.lines.empty():
+                    raise RuntimeError(
+                        f"cell {self.spec.cell}: worker exited "
+                        f"rc={self.proc.returncode} before '{prefix}' "
+                        f"(worker stderr: {self.stderr_path})")
                 continue
             if line.startswith(prefix):
                 return line
@@ -219,6 +272,15 @@ class CellSupervisor:
     ):
         self.cc = cells or CellsConfig(enabled=True)
         self.n_cells = self.cc.n_cells or max(1, (os.cpu_count() or 2) - 1)
+        # each worker is one process and a chip belongs to one process at a
+        # time: a second worker on the same chip hangs in backend init
+        chips = visible_tpu_chips()
+        if chips and self.n_cells > chips:
+            raise ValueError(
+                f"{self.n_cells} cells on a host with {chips} TPU chip(s): "
+                f"each cell worker needs a chip of its own — set "
+                f"cells.n_cells <= {chips}, or JAX_PLATFORMS=cpu for a CPU "
+                f"host plane")
         self.base_dir = base_dir
         self.python = python
         self.ready_timeout_s = ready_timeout_s
@@ -279,6 +341,8 @@ class CellSupervisor:
                 ledger=ledger,
                 drain_timeout_s=self.cc.drain_timeout_s,
                 flight=os.path.join(base_dir, f"c{k}", "flight.json"),
+                ready_timeout_s=ready_timeout_s,
+                env=chip_env(k, free_port()) if chips > 1 else {},
             )
         self.cells: Dict[int, CellHandle] = {}
         self._thread: Optional[threading.Thread] = None

@@ -23,6 +23,7 @@ Spawned as ``python -m gigapaxos_tpu.cells.worker '<spec json>'`` with::
    "ledger": true,                                  # record (r,name,slot,rid)
    "flight": ".../flight.json",                     # crash recorder artifact
    "stats_interval_s": 2.0,                         # StatsReporter cadence
+   "ready_timeout_s": 600.0,                        # planes' compile budget
    "drain_timeout_s": 10.0}
 
 Line protocol on stdin/stdout (the Mode B worker's idiom, extended):
@@ -53,12 +54,6 @@ import os
 import sys
 import threading
 import time
-
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=2")
-
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
 
 # --------------------------------------------------------------- S1 ledger
 #: execution observations [r, name, slot, rid, is_stop] — appended by the
@@ -102,6 +97,7 @@ def main() -> None:
     if spec.get("ledger"):
         _install_ledger()
 
+    from gigapaxos_tpu import compile_cache
     from gigapaxos_tpu import overload as _overload
     from gigapaxos_tpu.config import GigapaxosTpuConfig
     from gigapaxos_tpu.models.replicable import KVApp
@@ -120,6 +116,7 @@ def main() -> None:
 
     from .routing import cell_of
 
+    compile_cache.configure()
     cfg = GigapaxosTpuConfig()
     for k, v in (spec.get("paxos") or {}).items():
         setattr(cfg.paxos, k, v)
@@ -153,11 +150,19 @@ def main() -> None:
             rc_group_size=len(cfg.nodes.reconfigurators),
             wal_dir=spec["wal_dir"],
             rc_wal_dir=spec["rc_wal_dir"],
+            ready_timeout_s=spec.get("ready_timeout_s"),
         )
     except Exception as e:  # startup must be observable, not a silent death
         emit(f"startup_failed {type(e).__name__}: {e}")
         sys.exit(1)
     recovery_t1 = time.time()
+    # which device this cell landed on, in the stderr the supervisor keeps
+    import jax
+
+    print(f"cell {cell}: planes up on {jax.default_backend()} "
+          f"{[d.id for d in jax.local_devices()]} "
+          f"(TPU_VISIBLE_CHIPS={os.environ.get('TPU_VISIBLE_CHIPS')})",
+          file=sys.stderr, flush=True)
 
     # other cells' endpoints + the supervisor: reachable for edge forwarding
     # and control pings, but NOT part of this cell's consensus topology

@@ -33,8 +33,9 @@ class PaxosTuning:
     # and undelivered decisions (replaces the reference's sparse
     # accepted/committed maps, PaxosAcceptor.java:108-115).  Power of two.
     # Default 4: tick cost scales with W (the ring gathers do W-way selects
-    # over W planes), and at the 1M-group design point W=8 measured 84.5k
-    # dec/s vs 193.9k at W=4 (benchmarks/results_r5.json).  Raise it for
+    # over W planes).  The default was chosen on a CPU run of the full stack
+    # at 1M groups (W=4 about 2.3x W=8 there); on the chip the W=4 vs W=8
+    # cost is not measured (ROADMAP A5).  Raise it for
     # workloads with deep per-group pipelining or laggy replicas: a replica
     # more than W slots behind can no longer catch up from the decision
     # ring and needs a full checkpoint transfer (gap-sync; see README

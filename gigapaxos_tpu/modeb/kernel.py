@@ -50,7 +50,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ..ops.pallas_gather import shard_local_trace
 from ..ops.tick import TickInbox, paxos_tick_impl
 
 #: own-row state fields shipped in replica frames ([R, G] / [R, W, G])
@@ -77,11 +76,8 @@ def node_tick_impl(state, inbox: TickInbox, r: int, fast: bool = False):
     rule to this node's fast pushes.
     """
     # a node program is single-device by construction (each Mode-B process
-    # owns one chip) — never GSPMD-partitioned — so the Pallas gathers are
-    # safe here even when the host exposes multiple devices, where the
-    # backend-wide heuristic in use_pallas_gather() would refuse them
-    with shard_local_trace():
-        new, out = paxos_tick_impl(state, inbox, own_row=r, fast_elect=fast)
+    # owns one chip), so on a TPU it runs the Pallas gathers
+    new, out = paxos_tick_impl(state, inbox, own_row=r, fast_elect=fast)
     R = state.exec_slot.shape[0]
     row2 = (jnp.arange(R) == r)[:, None]        # [R, 1]
     row3 = row2[:, None, :]                      # [R, 1, 1]

@@ -35,7 +35,7 @@ from .config import GigapaxosTpuConfig
 from .models.replicable import Replicable
 from .net.failure_detection import FailureDetection
 from .net.messenger import Messenger, NodeMap
-from .paxos.driver import TickDriver
+from .paxos.driver import PlaneDown, TickDriver
 from .paxos.manager import PaxosManager
 from .placement import GroupMigrator, MigrationStats, ShardRebalancer
 from .reconfiguration.active_replica import ActiveReplica
@@ -150,7 +150,13 @@ class InProcessCluster:
         spare_rc_slots: int = 0,
         wal_dir: Optional[str] = None,
         rc_wal_dir: Optional[str] = None,
+        ready_timeout_s: Optional[float] = None,
     ):
+        """``ready_timeout_s``: how long each plane's first tick (its
+        compile) may take before construction raises
+        :class:`~gigapaxos_tpu.paxos.driver.PlaneDown`; None = the driver's
+        default (see ``TickDriver.wait_ready``).  Raise it for planes of
+        100k+ groups compiled cold."""
         self.cfg = cfg
         active_ids = cfg.nodes.active_ids()
         rc_ids = cfg.nodes.reconfigurator_ids()
@@ -253,9 +259,22 @@ class InProcessCluster:
                 is_node_up=lambda n: self._liveness.get(n, True),
             )
         # block until both planes' jitted ticks are compiled — otherwise the
-        # first client RPC races a multi-second XLA compile and times out
-        self.driver.wait_ready()
-        self.rc_driver.wait_ready()
+        # first client RPC races a multi-second XLA compile and times out.
+        # A plane whose first tick raised (compiler refusal, device out of
+        # memory) or never finished fails construction here: served, it
+        # would only ever look like client timeouts.
+        try:
+            self.driver.require_ready(ready_timeout_s)
+            self.rc_driver.require_ready(ready_timeout_s)
+        except PlaneDown:
+            # nothing was served yet: release the endpoints without
+            # waiting on a tick that is dead or may never return
+            self.driver.abandon()
+            self.rc_driver.abandon()
+            for ep in (*self.actives.values(),
+                       *self.reconfigurators.values()):
+                ep.close()
+            raise
         if start_fd:
             for r in rc_ids:
                 self.fds[r] = FailureDetection(
@@ -404,8 +423,16 @@ class InProcessCluster:
             busy = False
             for drv, m in planes:
                 wal = getattr(m, "wal", None)
-                if m.pending_count() > 0 or (wal is not None
-                                             and not wal.is_synced()):
+                with m.lock:
+                    # a pipelined plane always holds its newest tick's
+                    # outbox (idle probe ticks included): complete it here,
+                    # it never goes away by waiting
+                    pipe = getattr(m, "drain_pipeline", None)
+                    if pipe is not None:
+                        pipe()
+                    pending = m.pending_count()
+                if pending > 0 or (wal is not None
+                                   and not wal.is_synced()):
                     busy = True
                     drv.kick()
             if not busy:

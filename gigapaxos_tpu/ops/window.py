@@ -64,20 +64,15 @@ def gather_planes(arr, idx):
 
     On TPU backends the select chain is executed by a pallas kernel that
     keeps the Wp-way work in VMEM (ops/pallas_gather.py) — the XLA
-    formulation materializes the broadcast temporaries in HBM and was
-    measured at >99% of the fused tick's time at W=8, G=1M.  This one-hot
-    path remains the portable fallback and semantic reference.
+    formulation materializes the broadcast temporaries in HBM.  There the
+    kernel is the only path: a shape it cannot serve (lanes not a multiple
+    of 128, an idx rank it does not know) raises at trace time instead of
+    dropping to the select chain.  This one-hot path is what other
+    backends run, and the semantic reference.
     """
-    from .pallas_gather import use_pallas_gather
+    from .pallas_gather import gather_planes_pallas, use_pallas_gather
 
-    if (
-        use_pallas_gather()
-        and arr.ndim >= 2
-        and arr.shape[-1] % 128 == 0
-        and (idx.ndim == 2 or idx.shape == arr.shape[:-2] + idx.shape[-2:])
-    ):
-        from .pallas_gather import gather_planes_pallas
-
+    if use_pallas_gather():
         return gather_planes_pallas(arr, idx)
     wp = arr.shape[-2]
     res = None
@@ -103,17 +98,9 @@ def match_planes(vals, keys, idx):
     sort lowers catastrophically there, and this E-way select keeps the
     lane axis fully parallel).
     """
-    from .pallas_gather import use_pallas_gather
+    from .pallas_gather import match_planes_pallas, use_pallas_gather
 
-    if (
-        use_pallas_gather()
-        and vals.ndim == 2
-        and keys.shape == vals.shape
-        and idx.ndim == 2
-        and vals.shape[-1] % 128 == 0
-    ):
-        from .pallas_gather import match_planes_pallas
-
+    if use_pallas_gather():
         return match_planes_pallas(vals, keys, idx)
     e_planes = vals.shape[-2]
     res = jnp.zeros(vals.shape[:-2] + idx.shape[-2:], vals.dtype)

@@ -123,12 +123,20 @@ def sharded_tick(mesh: Mesh):
     matching, vote tally psum, decision sync) compile to cross-replica
     collectives riding ICI; the groups axis never communicates.
     """
+    from ..ops.pallas_gather import global_view_trace
     from ..ops.tick import paxos_tick_impl
+
+    def tick(state, inbox):
+        # GSPMD partitions this program: a pallas call here would have its
+        # operands replicated across the mesh, so this one formulation
+        # keeps the select chain (the shard_map tick runs the kernels)
+        with global_view_trace():
+            return paxos_tick_impl(state, inbox)
 
     st_sh = state_shardings(mesh)
     ib_sh = inbox_shardings(mesh)
     return jax.jit(
-        paxos_tick_impl,
+        tick,
         in_shardings=(st_sh, ib_sh),
         donate_argnums=(0,),
     )

@@ -3,16 +3,16 @@
 ``parallel/mesh.sharded_tick`` writes global-view code and lets GSPMD
 partition it.  That is correct but slow in exactly the way that matters at
 the BASELINE design point: inside a GSPMD program the Pallas ring gather has
-no sharding rule, so ``use_pallas_gather()`` must disable it and the tick
-falls back to the W²-broadcast XLA select chain — the multi-chip deployment
-runs the unoptimized path.
+no sharding rule, so that formulation must disable it
+(``pallas_gather.global_view_trace``) and falls back to the W²-broadcast XLA
+select chain — a multi-chip deployment built on it would run the
+unoptimized path.
 
-This module instead wraps the UNCHANGED tick body in
-``jax.experimental.shard_map`` over the (replica, groups) mesh:
+This module instead wraps the UNCHANGED tick body in ``jax.shard_map`` over
+the (replica, groups) mesh:
 
 * Each shard sees a concrete local ``[R_local(, W), G_local]`` block, so the
-  Pallas kernels run per-shard (``shard_local_trace`` flips
-  ``use_pallas_gather`` back on during body tracing).
+  Pallas kernels run per-shard, as in a single-device program.
 * Cross-replica exchange is explicit: the body ``all_gather``s the
   replica-led state/inbox fields over the ``replica`` axis (one tiled ICI
   collective per field — the ACCEPT fan-out / ACCEPT_REPLY fan-in), runs the
@@ -33,15 +33,16 @@ Outbox pack / compaction stays OUTSIDE the shard_map (global-view GSPMD):
 the compact prefix-scatter is a global cumsum over all groups, and keeping
 it global means ``CompactLayout`` / ``unpack_compact`` and the whole host
 loop are byte-compatible with the single-device path.  It runs as a SECOND
-jit dispatch, not fused into the tick program: on this jax version,
-consuming ``shard_map(check_rep=False)`` outputs downstream *in the same
-jit* miscompiles — even a plain concatenate of the outbox fields returns
-wrong values, and reductions come back multiplied by the groups-axis size
-(the partitioner double-reduces the already-assembled outputs).  Across a
-dispatch boundary the outbox is an ordinary committed sharded array and the
-GSPMD pack/compact program is correct (verified bit-identical in
-tests/test_sharding_stack.py).  Cost: one extra ~100us dispatch per tick;
-the outbox intermediate stays device-resident and sharded either way.
+jit dispatch, not fused into the tick program: the tick's outputs cross the
+dispatch boundary as ordinary committed sharded arrays, and the GSPMD
+pack/compact program over them is verified bit-identical in
+tests/test_sharding_stack.py.  (The split dates from a jax release on which
+consuming unchecked shard_map outputs downstream in the same jit returned
+wrong values.  Under jax 0.9.0 a fused tick + compaction gave the identical
+buffer on a 4-device virtual CPU mesh, for one and two replica shards; the
+two-dispatch structure stays until ROADMAP C1 folds the entry points.)
+Cost: one extra dispatch per tick; the outbox intermediate stays
+device-resident and sharded either way.
 """
 
 from __future__ import annotations
@@ -50,11 +51,10 @@ import functools
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops import tick as tk
-from ..ops.pallas_gather import shard_local_trace
+from ..ops.pallas_gather import check_lanes
 from ..ops.tick import TickInbox, TickOutbox
 from ..paxos.state import PaxosState
 from .mesh import (GROUPS_AXIS, REPLICA_AXIS, _INBOX_SPECS, _STATE_SPECS,
@@ -96,6 +96,7 @@ def validate_mesh_for(mesh: Mesh, R: int, G: int) -> None:
         raise ValueError(f"replica dim {R} not divisible by {rs} shards")
     if G % gs:
         raise ValueError(f"group dim {G} not divisible by {gs} shards")
+    check_lanes(G // gs, f"paxos.max_groups / {gs} group shards")
 
 
 def shard_tick_body(mesh: Mesh, own_row: int = -1, exec_budget: int = 0):
@@ -117,10 +118,9 @@ def shard_tick_body(mesh: Mesh, own_row: int = -1, exec_budget: int = 0):
                 **{f: ag(getattr(state, f)) for f in _REPLICA_LED}
             )
             inbox = inbox._replace(req=ag(inbox.req), stop=ag(inbox.stop))
-        with shard_local_trace():
-            new, out = tk.paxos_tick_impl(
-                state, inbox, own_row, exec_budget, group_axis=group_axis
-            )
+        new, out = tk.paxos_tick_impl(
+            state, inbox, own_row, exec_budget, group_axis=group_axis
+        )
         if rs > 1:
             ri = jax.lax.axis_index(REPLICA_AXIS)
             rloc = new.exec_slot.shape[0] // rs
@@ -142,14 +142,14 @@ def shard_tick_body(mesh: Mesh, own_row: int = -1, exec_budget: int = 0):
             )
         return new, out
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(PaxosState(**_STATE_SPECS), TickInbox(**_INBOX_SPECS)),
         out_specs=(PaxosState(**_STATE_SPECS), TickOutbox(**_OUTBOX_SPECS)),
         # the body mixes collectives with device-varying slicing (and pallas
-        # calls, which have no replication rule); skip static rep checking.
-        check_rep=False,
+        # calls, which have no replication rule); skip the static check.
+        check_vma=False,
     )
 
 
@@ -166,13 +166,12 @@ def make_shardmap_tick(mesh: Mesh, own_row: int = -1, exec_budget: int = 0):
 def fetch_host_outbox(out: TickOutbox) -> "tk.HostOutbox":
     """Assemble the full outbox on the host directly from the sharded fields.
 
-    The mesh full-outbox path skips the on-device ``pack_outbox_impl``: on
-    this jax version a GSPMD concatenate over the mixed-sharding outbox
-    fields returns wrong values (same partitioner issue as the same-jit
-    fusion, see module docstring), while per-field assembly from the
-    committed shards is exact and moves the same bytes.  Full-outbox mode is
-    the small-scale/debug path; at scale the compact path is the transfer
-    that matters.
+    The mesh full-outbox path skips the on-device ``pack_outbox_impl``
+    (historically a GSPMD concatenate over the mixed-sharding outbox fields
+    returned wrong values, see module docstring); per-field assembly from
+    the committed shards is exact and moves the same bytes.  Full-outbox
+    mode is the small-scale/debug path; at scale the compact path is the
+    transfer that matters.
     """
     jax.block_until_ready(out)
     return tk.HostOutbox(*(np.asarray(f) for f in out))

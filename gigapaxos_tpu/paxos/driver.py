@@ -21,15 +21,22 @@ from .manager import PaxosManager
 
 log = logging.getLogger(__name__)
 
-#: process-wide hook for unrecoverable storage failures surfacing in a tick
-#: loop (fsyncgate semantics: the kernel may have dropped dirty pages, so
-#: retrying the write would ack data that never reached disk).  The cells
-#: worker installs a handler that dumps the flight recorder and exits the
-#: process nonzero so the supervisor restarts the cell onto intact storage;
-#: in-process embeddings (tests, notebooks) leave it None and observe
-#: ``driver.fatal`` instead — the driver thread stops ticking either way,
-#: which is exactly "the node stops acking".
+#: process-wide hook for failures that end a tick loop.  A storage failure
+#: (fsyncgate semantics: the kernel may have dropped dirty pages, so
+#: retrying the write would ack data that never reached disk) or anything
+#: else a tick raises — a program the device compiler refuses, HBM
+#: exhaustion — stops the plane for good.  The cells worker installs a
+#: handler that dumps the flight recorder and exits the process nonzero so
+#: the supervisor restarts the cell; in-process embeddings (tests,
+#: notebooks) leave it None and observe ``driver.fatal`` instead — the
+#: driver thread stops ticking either way, which is exactly "the node stops
+#: acking".
 FATAL_HANDLER: Optional[Callable[[BaseException], None]] = None
+
+
+class PlaneDown(RuntimeError):
+    """A plane did not come up: its first tick raised, or never finished
+    within the start-up timeout."""
 
 
 class TickDriver:
@@ -45,8 +52,10 @@ class TickDriver:
         self.manager = manager
         self.idle_sleep_s = idle_sleep_s
         self.drain_ticks = drain_ticks
-        #: the WalError that fail-stopped this driver, if any
+        #: the exception that fail-stopped this driver, if any
         self.fatal: Optional[BaseException] = None
+        #: wall time of the first tick — its compile, for a cold plane
+        self.first_tick_s: Optional[float] = None
         self._stop = threading.Event()
         self._kick = threading.Event()
         self._first_tick = threading.Event()
@@ -74,11 +83,29 @@ class TickDriver:
         if timeout_s is None:
             timeout_s = 360.0 if getattr(self.manager, "mesh", None) \
                 is not None else 120.0
-        return self._first_tick.wait(timeout=timeout_s)
+        return self._first_tick.wait(timeout=timeout_s) and self.fatal is None
 
-    def stop(self) -> None:
+    def require_ready(self, timeout_s: float | None = None) -> None:
+        """:meth:`wait_ready`, raising :class:`PlaneDown` when the plane
+        is not serving — so a dead plane fails its owner's start-up
+        instead of looking like client timeouts later."""
+        if self.wait_ready(timeout_s):
+            return
+        if self.fatal is not None:
+            raise PlaneDown(
+                f"tick driver died on {type(self.fatal).__name__}: "
+                f"{self.fatal}") from self.fatal
+        raise PlaneDown("first tick did not complete within the start-up "
+                        "timeout (still compiling, or the device hangs)")
+
+    def abandon(self) -> None:
+        """Ask the loop to end without waiting for it (its tick may be
+        stuck in a compile or on a hung device)."""
         self._stop.set()
         self._kick.set()
+
+    def stop(self) -> None:
+        self.abandon()
         self._thread.join(timeout=10)
         # a pipelined manager may hold one final unprocessed outbox whose
         # callbacks clients are still waiting on
@@ -95,6 +122,7 @@ class TickDriver:
             "min_tick_interval_s", 0.0,
         ) or 0.0
         last = 0.0
+        started = time.monotonic()
         while not self._stop.is_set():
             if min_ivl > 0:
                 gap = min_ivl - (time.monotonic() - last)
@@ -103,18 +131,24 @@ class TickDriver:
                 last = time.monotonic()
             try:
                 self.manager.tick()
-            except WalError as e:
+            except Exception as e:
                 # fail-stop: storage lost (or refused) a write the plane
-                # was about to ack.  Stop ticking — no further decision is
-                # acked from this node — and surface the failure instead of
-                # dying as a silent daemon thread.
+                # was about to ack, or the tick itself cannot run (compile
+                # refusal, device out of memory).  Stop ticking — no further
+                # decision is acked from this node — and surface the failure
+                # instead of dying as a silent daemon thread.
                 self.fatal = e
-                log.critical("tick driver fail-stop (WAL): %s", e)
+                log.critical(
+                    "tick driver fail-stop%s: %s",
+                    " (WAL)" if isinstance(e, WalError) else "", e,
+                    exc_info=not isinstance(e, WalError))
                 self._first_tick.set()  # unblock wait_ready() callers
                 handler = FATAL_HANDLER
                 if handler is not None:
                     handler(e)
                 return
+            if self.first_tick_s is None:
+                self.first_tick_s = time.monotonic() - started
             self._first_tick.set()
             # CPython locks are unfair: without a yield window the driver
             # re-acquires manager.lock before any waiting control-plane

@@ -49,6 +49,7 @@ _MGR_SEQ = _itertools.count()
 from . import state as st
 from .bulkstore import BulkOverrun, BulkStore
 from .paystore import PayloadStore
+from ..ops.pallas_gather import check_lanes
 from ..ops.tick import (LP_ASN, LP_EPOCH, LP_HOLDER, LP_UNTIL, LP_WAIT,
                         CompactHostOutbox, HostOutbox, TickInbox,
                         frontier_rows, health_clear_rows, init_health,
@@ -114,6 +115,9 @@ class PaxosManager:
         # structure and code path bit-identical to pre-register builds.
         self.G_reg = cfg.paxos.register_groups
         self.G_total = self.G + self.G_reg
+        if not cfg.paxos.mesh_devices:  # a mesh checks its per-shard width
+            check_lanes(self.G, "paxos.max_groups")
+        check_lanes(self.G_reg, "paxos.register_groups")
         self.state = st.init_state(self.R, self.G, self.W)
         self.rstate = (st.init_state(self.R, self.G_reg, 1)
                        if self.G_reg else None)

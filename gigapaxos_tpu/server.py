@@ -38,6 +38,7 @@ import signal
 import threading
 from typing import Callable, Optional
 
+from . import compile_cache
 from .config import GigapaxosTpuConfig, load_properties
 from .models.replicable import KVApp, Replicable
 from .modeb import ModeBLogger, ModeBNode, recover_modeb
@@ -45,7 +46,7 @@ from .modeb.coordinator import ModeBReplicaCoordinator, ModeBRepliconfigurableDB
 from .net.failure_detection import FailureDetection
 from .net.messenger import Messenger, NodeMap
 from .net.security import TransportSecurity
-from .paxos.driver import TickDriver
+from .paxos.driver import PlaneDown, TickDriver
 from .reconfiguration.active_replica import ActiveReplica
 from .reconfiguration.demand import AbstractDemandProfile, DemandProfile
 from .reconfiguration.rc_db import ReconfiguratorDB
@@ -377,6 +378,12 @@ class ModeBServer:
         """Block until every plane's jitted tick compiled."""
         return all(d.wait_ready(timeout_s) for d in self.drivers)
 
+    def require_ready(self, timeout_s: float = 180.0) -> None:
+        """:meth:`wait_ready`, raising ``PlaneDown`` for a plane whose first
+        tick raised or never finished."""
+        for d in self.drivers:
+            d.require_ready(timeout_s)
+
     def close(self) -> None:
         self._closing = True
         if self.timeline_rec is not None:
@@ -451,10 +458,18 @@ def main(argv=None) -> None:
         return
     if not args.node:
         ap.error("--node is required unless --cells is set")
+    compile_cache.configure()
     server = ModeBServer(
         args.node, cfg, log_dir=args.log_dir, start_fd=not args.no_fd
     )
-    server.wait_ready()
+    try:
+        server.require_ready()
+    except PlaneDown:
+        # a plane that did not come up must not announce "ready": its
+        # clients would only ever see timeouts
+        for d in server.drivers:
+            d.abandon()
+        raise
     print(f"gigapaxos_tpu server {args.node} ready", flush=True)
 
     stop = threading.Event()

@@ -198,19 +198,33 @@ def set_journal_wrapper(fn) -> None:
     _JOURNAL_WRAP = fn
 
 
+#: reasons already logged for running the Python journal where the native
+#: one was asked for (one line per reason, not one per journal roll)
+_NATIVE_FALLBACK_LOGGED: set = set()
+
+
 def _new_journal(path: str, native_ok: bool):
     j = None
     if native_ok:
-        try:
-            from .native_journal import NativeJournal
+        from .native_journal import NativeJournal, NativeUnavailable
 
+        try:
             j = NativeJournal(path)
         except JournalCorruptError:
             # scribble: PyJournal would refuse identically — surface it,
-            # the silent-fallback path is for missing toolchains only
+            # the fallback path is for missing toolchains only
             raise
-        except Exception:
-            pass
+        except (NativeUnavailable, OSError) as e:
+            # same on-disk format either way, so the Python journal is a
+            # correct stand-in — but never a silent one
+            reason = f"{type(e).__name__}: {e}"
+            if reason not in _NATIVE_FALLBACK_LOGGED:
+                _NATIVE_FALLBACK_LOGGED.add(reason)
+                import logging
+
+                logging.getLogger("gptpu.wal").warning(
+                    "native journal unavailable, using the Python journal "
+                    "(%s)", reason)
     if j is None:
         from .journal import PyJournal
 
@@ -1070,6 +1084,42 @@ def _replay_admin_op(m, rec) -> None:
             m.sync_laggard(r, name, donor=donor)
 
 
+class _AdminReplay:
+    """Applies journaled admin ops in order, folding each run of
+    consecutive plain creates with one member set and epoch into ONE
+    batched create.  A journaled bulk create is one OP_CREATE per name
+    (``log_creates``), and a single create rewrites every state array
+    whole: replayed name by name, a populate of 1M groups is 1M full-state
+    copies.  ``create_paxos_instances`` allocates the same rows in the same
+    order as the single path, so the result is the one the record-by-record
+    replay would reach.  Any other record ends the run; the caller flushes
+    before a tick."""
+
+    def __init__(self, m):
+        self.m = m
+        #: chain managers have no batched create: every record goes singly
+        self.batched = hasattr(m, "create_paxos_instances")
+        self.names: list = []
+        self.key = None  # (members, epoch) of the open run
+
+    def apply(self, rec) -> None:
+        if self.batched and rec[0] == OP_CREATE and len(rec) == 4:
+            key = (tuple(rec[2]), rec[3])
+            if key != self.key:
+                self.flush()
+                self.key = key
+            self.names.append(rec[1])
+            return
+        self.flush()
+        _replay_admin_op(self.m, rec)
+
+    def flush(self) -> None:
+        if self.names:
+            names, self.names = self.names, []
+            self.m.create_paxos_instances(
+                names, list(self.key[0]), self.key[1])
+
+
 def replay_journals(m, log_dir, start_seq, make_record, new_buffers, place,
                     build_inbox, tick_fn, bulk_replay=None, progress=None):
     """Shared journal-replay loop (passes 2–3 of recovery) for any manager.
@@ -1091,6 +1141,7 @@ def replay_journals(m, log_dir, start_seq, make_record, new_buffers, place,
     # (writer resets _pay_seen at every roll), so an empty table fills in
     # from raw bodies as records — including snapshot-skipped ticks — decode
     pay_tab: dict = {}
+    admin = _AdminReplay(m)
     # OP_REG stash: register-plane placements for the NEXT OP_TICK (the
     # writer appends them immediately before it, same tick_num)
     pending_reg = None
@@ -1126,8 +1177,9 @@ def replay_journals(m, log_dir, start_seq, make_record, new_buffers, place,
             if op == OP_REG:
                 pending_reg = (rec[1], rec[2])
             elif op != OP_TICK:
-                _replay_admin_op(m, rec)
+                admin.apply(rec)
             else:
+                admin.flush()
                 _, tick_num, placed, alive_b = rec[:4]
                 bulk_rec = rec[4] if len(rec) > 4 else None
                 if pending_reg is not None:
@@ -1157,6 +1209,7 @@ def replay_journals(m, log_dir, start_seq, make_record, new_buffers, place,
                 else:
                     m._process_outbox(out)
                 m.tick_num = tick_num + 1
+    admin.flush()
     # laggard repairs during replay come ONLY from OP_SYNC records, but the
     # replayed completions still queued the lag they observed — discard it,
     # or the first live tick bursts through a journal's worth of stale
@@ -1190,7 +1243,7 @@ def _sparse_rows(acts: np.ndarray, width: int) -> np.ndarray:
     unspecified order.  A plane too small to be worth slicing is taken
     whole."""
     A = len(acts)
-    Ap = 8
+    Ap = 128  # one TPU lane block: the narrow plane runs the same kernels
     while Ap < A:
         Ap *= 2
     if Ap >= width:
@@ -1533,6 +1586,7 @@ def replay_journals_batched(m, log_dir, start_seq, make_record, new_buffers,
         batch_ticks = int(os.environ.get("GPTPU_REPLAY_BATCH", "8"))
     disp = _BatchedReplay(m, make_record, new_buffers, place, build_inbox,
                           tick_fn, bulk_replay, batch_ticks)
+    admin = _AdminReplay(m)
     pay_tab: dict = {}
     pending_reg = None
     paths = sorted(glob.glob(os.path.join(log_dir, "journal.*.log")))
@@ -1577,11 +1631,13 @@ def replay_journals_batched(m, log_dir, start_seq, make_record, new_buffers,
                     pending_reg = None
                 if tick_num < m.tick_num:
                     continue  # already inside the snapshot
+                admin.flush()
                 disp.add(rec)
             else:
                 disp.flush()  # admin ops mutate outside the tick body
-                _replay_admin_op(m, rec)
+                admin.apply(rec)
     disp.flush()
+    admin.flush()
     # same post-replay hygiene as the reference arm (see its comments)
     if hasattr(m, "_lag_sync_due"):
         m._lag_sync_due.clear()
@@ -1769,6 +1825,20 @@ def recover(cfg, n_replicas: int, apps, log_dir: str, native: bool = True,
             m._process_compact(co, m._placed, bulk_placed, er, em)
 
         m._replay_process = _proc
+    elif getattr(m, "mesh", None) is not None:
+        # a mesh manager's state is partitioned over its devices: replay
+        # through the shard_map program the live run dispatched (a
+        # single-device jit fed that state would be GSPMD-partitioned,
+        # with the pallas calls' operands replicated over the mesh)
+        from ..parallel.shard_tick import (fetch_host_outbox,
+                                           make_shardmap_tick)
+
+        mesh_tick = make_shardmap_tick(
+            m.mesh, -1, m._exec_budget if m._use_compact else 0)
+
+        def tick_host(state, inbox):
+            state, out = mesh_tick(state, inbox)
+            return state, fetch_host_outbox(out)
     else:
         def tick_host(state, inbox):
             # replay must evolve state EXACTLY as the live run did, so the

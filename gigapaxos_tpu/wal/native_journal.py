@@ -1,8 +1,11 @@
 """ctypes binding for the C++ journal backend (``native/journal.cc``).
 
-Builds the shared library on first use if the toolchain is available (no
-pybind11 in the target image — plain C ABI + ctypes).  On-disk format is
-byte-identical to :mod:`gigapaxos_tpu.wal.journal`, so readers are shared.
+The shared library is a build product, not a tracked file: it is built from
+``journal.cc`` on first use wherever it is absent (a fresh clone, the chip
+tool's copy) if the toolchain is available (no pybind11 in the target image
+— plain C ABI + ctypes).  After editing ``journal.cc``, ``make -C native``.
+On-disk format is byte-identical to :mod:`gigapaxos_tpu.wal.journal`, so
+readers are shared.
 """
 
 from __future__ import annotations
@@ -13,7 +16,7 @@ import subprocess
 import threading
 
 _LIB = None
-_LOAD_ERROR: Exception | None = None
+_LOAD_ERROR: NativeUnavailable | None = None
 _LOCK = threading.Lock()
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 
@@ -30,16 +33,15 @@ def _load():
         if _LOAD_ERROR is not None:
             # cache the failure: re-running the build subprocess on every
             # journal roll would put a fork+compile on the durability path
-            raise NativeUnavailable(str(_LOAD_ERROR)) from _LOAD_ERROR
+            raise _LOAD_ERROR
         so = os.path.abspath(os.path.join(_NATIVE_DIR, "libgpjournal.so"))
         src = os.path.abspath(os.path.join(_NATIVE_DIR, "journal.cc"))
         try:
-            if not os.path.exists(so) or (
-                os.path.exists(src)
-                and os.path.getmtime(src) > os.path.getmtime(so)
-            ):
+            if not os.path.exists(so):
                 if not os.path.exists(src):
                     raise NativeUnavailable("journal.cc not found")
+                # the Makefile renames the finished library into place, so
+                # cell workers that all find it absent can build at once
                 subprocess.run(
                     ["make", "-C", os.path.dirname(src), "libgpjournal.so"],
                     check=True,
@@ -47,8 +49,13 @@ def _load():
                 )
             lib = ctypes.CDLL(so)
         except Exception as e:
-            _LOAD_ERROR = e
-            raise NativeUnavailable(f"native journal unavailable: {e}") from e
+            # a failed build's reason is the compiler's stderr, not make's
+            # exit status
+            err = getattr(e, "stderr", None)
+            tail = f" | {err.decode(errors='replace')[-400:]}" if err else ""
+            _LOAD_ERROR = NativeUnavailable(
+                f"native journal unavailable: {e}{tail}")
+            raise _LOAD_ERROR from e
         lib.gpj_open.restype = ctypes.c_void_p
         lib.gpj_open.argtypes = [ctypes.c_char_p]
         lib.gpj_append.restype = ctypes.c_int

@@ -9,12 +9,14 @@ replica death, laggard checkpoint repair — same scripted workload on the
 8-device virtual CPU mesh vs one device, every state field and every app
 table compared exactly.
 
-Plus the tentpole's kernel property: the Pallas ring gather traces and
-executes INSIDE the shard_map body (where each shard sees a concrete local
-block), while the plain multi-device heuristic still refuses it.
+Plus the kernel policy: on a TPU backend the Pallas ring gather traces in a
+single-device program and INSIDE the shard_map body (where each shard sees
+a concrete local block) whatever the visible device count, and only the
+global-view GSPMD formulation refuses it.
 """
 
 import os
+import shutil
 
 import jax
 import jax.numpy as jnp
@@ -118,6 +120,45 @@ def test_stack_mesh_full_outbox_bit_identical(tmp_path):
     assert_same_run(ref, got)
 
 
+def test_mesh_recovery_replays_through_the_shard_map_tick(tmp_path):
+    """A mesh manager's state is partitioned over its devices, so its WAL
+    replays through the same shard_map program the live run dispatched —
+    and lands on the state a single-device recovery of the same journal
+    reaches."""
+    from gigapaxos_tpu.wal.logger import recover
+
+    R = 3
+    live = run_stack(str(tmp_path / "mesh"), R, mesh_devices=8)
+    wal_dir = os.path.join(str(tmp_path / "mesh"), "wal")
+
+    def cfg_for(mesh_devices):
+        cfg = GigapaxosTpuConfig()
+        cfg.paxos.max_groups = 256
+        cfg.paxos.window = W
+        cfg.paxos.compact_outbox = True
+        cfg.paxos.pipeline_ticks = True
+        cfg.paxos.deactivation_ticks = 0
+        cfg.paxos.mesh_devices = mesh_devices
+        return cfg
+
+    got = {}
+    for tag, mesh_devices in (("mesh", 8), ("one", 0)):
+        copy = str(tmp_path / f"replay_{tag}")
+        shutil.copytree(wal_dir, copy)
+        apps = [KVApp() for _ in range(R)]
+        m = recover(cfg_for(mesh_devices), R, apps, copy)
+        assert (m.mesh is not None) == bool(mesh_devices)
+        got[tag] = (jax.tree.map(np.asarray, m.state),
+                    [{k: dict(v) for k, v in a.db.items()} for a in apps])
+        m.wal.close()
+    for f in got["one"][0]._fields:
+        np.testing.assert_array_equal(
+            getattr(got["one"][0], f), getattr(got["mesh"][0], f), err_msg=f)
+        np.testing.assert_array_equal(
+            getattr(live[0], f), getattr(got["mesh"][0], f), err_msg=f)
+    assert got["one"][1] == got["mesh"][1] == live[1]
+
+
 # ------------------------------------------------------- pallas-in-shard_map
 def _build_state(R, G, W_):
     s = st.init_state(R, G, W_)
@@ -171,20 +212,32 @@ def test_pallas_gather_executes_inside_shard_map(monkeypatch):
 
     monkeypatch.setattr(pg, "gather_planes_pallas", counting_gather)
     monkeypatch.setattr(pg, "match_planes_pallas", counting_match)
-    # pretend: TPU backend with 2 devices (kernels default to interpret so
-    # they actually execute on this CPU host)
-    monkeypatch.setattr(pg, "_backend_info", lambda: ("tpu", 2))
+    # pretend: TPU backend (kernels default to interpret so they actually
+    # execute on this CPU host, which shows 8 devices)
+    monkeypatch.setattr(pg, "_on_tpu", lambda: True)
     monkeypatch.setenv("GPTPU_PALLAS_INTERPRET", "1")
     monkeypatch.delenv("GPTPU_PALLAS", raising=False)
     monkeypatch.delenv("GPTPU_NO_PALLAS", raising=False)
+    assert len(jax.devices()) > 1
 
-    # global-view trace: multi-device backend, not shard-local -> refused
-    jax.jit(paxos_tick_impl).lower(_build_state(R, G, W),
+    # global-view trace: GSPMD would replicate the kernels' operands ->
+    # that one formulation keeps the select chain
+    mesh = pmesh.make_mesh(jax.devices()[:2], replica_shards=1)
+    pmesh.sharded_tick(mesh).lower(_build_state(R, G, W),
                                    _load_inbox(R, G, seed=0))
     assert calls["gather"] == 0 and calls["match"] == 0
 
+    # single-device program on a host that SHOWS several devices: where the
+    # program runs decides, not how many devices are visible
+    def one_device_tick(state, inbox):
+        return paxos_tick_impl(state, inbox)
+
+    jax.jit(one_device_tick).lower(_build_state(R, G, W),
+                                   _load_inbox(R, G, seed=0))
+    assert calls["gather"] > 0 and calls["match"] > 0
+    calls["gather"] = calls["match"] = 0
+
     # shard_map trace: shard-local -> the pallas kernels are in the program
-    mesh = pmesh.make_mesh(jax.devices()[:2], replica_shards=1)
     tick = stk.make_shardmap_tick(mesh)
     s = pmesh.shard_state(_build_state(R, G, W), mesh)
     sm_outs = []

@@ -1,0 +1,116 @@
+"""Every tick program a manager can dispatch, compiled for a TPU v5e — here.
+
+The sandbox has no chip, but libtpu is installed, and it will compile for a
+described topology without one (``jax.experimental.topologies``).  So a
+program Mosaic or XLA:TPU refuses — a kernel shape, a layout, a shard_map
+body — fails this test on the CPU box instead of costing chip time to find.
+What it cannot show is that the programs run and agree with the reference:
+that is ``chip_smoke.py``'s job, on the chip.
+
+Runs in a fresh process: loading libtpu is kept out of the test process, and
+no jit cache traced for the CPU can stand in.  Skipped where libtpu cannot
+describe a topology (an image without it).
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+SCRIPT = r'''
+import sys
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+except Exception as e:
+    print("NO-TOPOLOGY", type(e).__name__, e)
+    sys.exit(0)
+
+from gigapaxos_tpu.ops import tick as tk
+from gigapaxos_tpu.parallel import mesh as pmesh, shard_tick as stk
+from gigapaxos_tpu.paxos import state as st
+
+R, W, P, G, Lb = 3, 4, 4, 4096, 1024
+E = 2 * G
+one = SingleDeviceSharding(topo.devices[0])
+
+
+def shaped(tree, sharding=None):
+    if sharding is None:
+        sharding = jax.tree.map(lambda _: one, tree)
+    return jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        tree, sharding)
+
+
+def S(shape, dt=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dt, sharding=one)
+
+
+def inbox(g):
+    return tk.TickInbox(S((R, P, g)), S((R, P, g), jnp.bool_),
+                        S((R,), jnp.bool_))
+
+
+state = shaped(jax.eval_shape(lambda: st.init_state(R, G, W)))
+rstate = shaped(jax.eval_shape(lambda: st.init_state(R, G, 1)))
+lease = shaped(jax.eval_shape(lambda: tk.init_lease(G, 8)))
+health = shaped(jax.eval_shape(lambda: tk.init_health(G)))
+narrow = shaped(jax.eval_shape(lambda: st.init_state(R, 128, W)))
+K, M = 8, 8
+xs = {"e": S((K, M)), "p": S((K, M)), "g": S((K, M)), "rid": S((K, M)),
+      "stop": S((K, M), jnp.bool_), "alive": S((K, R), jnp.bool_)}
+mesh = pmesh.make_mesh(topo.devices, replica_shards=1)
+m_state = shaped(jax.eval_shape(lambda: st.init_state(R, G, W)),
+                 pmesh.state_shardings(mesh))
+m_inbox = shaped(inbox(G), pmesh.inbox_shardings(mesh))
+
+programs = [
+    # (name, jitted fn, args, Pallas calls it must carry)
+    ("log-plane compact tick", tk.paxos_tick_compact,
+     (state, inbox(G), -1, E, Lb), 26),
+    ("mixed log+register tick (W=4 and W=1)", tk.paxos_tick_mixed_compact,
+     (state, rstate, inbox(2 * G), -1, E, Lb), 52),
+    ("lease tick", tk.paxos_tick_compact_lease,
+     (state, lease, inbox(G), -1, E, Lb, 64), 26),
+    ("generic health tick", tk.paxos_tick_health,
+     (state, None, None, None, health, None, inbox(G), -1, E, Lb, 64, True,
+      32, 6, 8), 26),
+    ("replay scan, sparse window of 128 lanes", tk.replay_scan_ticks,
+     (narrow, xs, P, E, E, Lb), 26),
+    ("shard_map tick over four chips", stk.make_shardmap_tick(mesh, -1, E),
+     (m_state, m_inbox), 26),
+]
+for name, fn, args, want in programs:
+    low = fn.lower(*args)
+    got = low.as_text().count("@tpu_custom_call")
+    assert got == want, f"{name}: {got} Mosaic custom calls, expected {want}"
+    low.compile()
+    print("COMPILED", name, flush=True)
+print("ALL-COMPILED")
+'''
+
+
+def test_tick_programs_compile_for_v5e_without_a_chip():
+    env = dict(os.environ)
+    env.update(JAX_PLATFORMS="cpu", GPTPU_PALLAS="1",
+               # compile-only: no device to contend for, so another process
+               # holding libtpu's lockfile is no reason to fail
+               ALLOW_MULTIPLE_LIBTPU_LOAD="1",
+               PYTHONPATH=ROOT + os.pathsep + env.get("PYTHONPATH", ""))
+    env.pop("GPTPU_PALLAS_INTERPRET", None)
+    env.pop("GPTPU_NO_PALLAS", None)
+    out = subprocess.run([sys.executable, "-c", SCRIPT], cwd=ROOT, env=env,
+                         text=True, capture_output=True, timeout=900)
+    if "NO-TOPOLOGY" in out.stdout:
+        pytest.skip("libtpu cannot describe a v5e topology here: "
+                    + out.stdout.strip()[-300:])
+    assert out.returncode == 0 and "ALL-COMPILED" in out.stdout, (
+        out.stdout[-2000:] + out.stderr[-4000:])

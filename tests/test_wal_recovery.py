@@ -208,3 +208,43 @@ def test_recovery_with_native_backend(tmp_path):
     m2 = recover(cfg, 3, apps2, str(tmp_path), native=True)
     assert apps2[0].db == apps[0].db
     m2.wal.close()
+
+
+def test_bulk_create_replays_as_batched_creates(tmp_path, monkeypatch):
+    """A journaled bulk create is one OP_CREATE per name; replayed name by
+    name it is one full-state rewrite per name (a populate of 1M groups
+    could not be recovered in any useful time).  Replay folds each run of
+    consecutive creates into one batched create and reaches the same rows,
+    the same device state and the same app state — in both replay arms."""
+    from gigapaxos_tpu.paxos import state as st
+
+    cfg, apps, m = mk(tmp_path)
+    bulk = [f"bulk{i}" for i in range(20)]
+    assert m.create_paxos_instances(bulk, [0, 1, 2]) == 20
+    m.create_paxos_instance("solo", [0, 1])  # another member set: own run
+    for n in ("bulk3", "bulk17", "solo"):
+        m.propose(n, b"PUT k v")
+    m.run_ticks(4)
+    assert m.create_paxos_instances(["late0", "late1"], [0, 1, 2]) == 2
+    m.propose("late1", b"PUT k late")
+    m.run_ticks(4)
+    live_rows = dict(m.rows.items())
+    live_state = [np.array(a) for a in m.state]
+    live_db = [dict(a.db) for a in apps]
+    m.wal.close()  # crash
+
+    calls = []
+    orig = st.create_groups
+    monkeypatch.setattr(st, "create_groups", lambda s, rows, *a, **k: (
+        calls.append(len(rows)), orig(s, rows, *a, **k))[1])
+    for mode in ("batched", "reference"):
+        calls.clear()
+        apps2 = [KVApp() for _ in range(3)]
+        m2 = recover(cfg, 3, apps2, str(tmp_path), native=False,
+                     replay_mode=mode)
+        assert calls == [20, 1, 2], (mode, calls)  # runs, not names
+        assert dict(m2.rows.items()) == live_rows
+        for got, want in zip(m2.state, live_state):
+            assert np.array_equal(np.array(got), want)
+        assert [dict(a.db) for a in apps2] == live_db
+        m2.wal.close()

@@ -1,0 +1,412 @@
+"""One run of one cell: set-up, warm-up, the measured window, the drain, the
+check against the reference, and the result line.
+
+Everything a cell is made of comes from data files (``spec.py``); this file
+holds no cell's name or number.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import faulthandler
+import json
+import os
+import shutil
+import sys
+import tempfile
+import threading
+import time
+
+import numpy as np
+
+from . import deployment, load, reference, spec, stats, tracing
+
+#: a later run of a cell must exit within 360 s, the first (compiling) within
+#: 1200 s; past this the process dumps every thread's stack and exits non-zero
+DEADLINE_S = 1150
+#: the profiler traces this much of the middle of the window (a whole 20 s
+#: window at 1M groups would be a 10 MB trace)
+TRACE_SLICE_S = 5.0
+#: the warm-up's schedule ends here whatever the ticks did (a plane that
+#: needs longer than this for its first ticks is broken, not cold)
+WARMUP_MAX_S = 150.0
+#: lead between the last set-up step and the window's first due instant
+LEAD_S = 0.05
+
+
+_T0 = time.monotonic()
+
+
+def note(msg: str) -> None:
+    """Progress goes to stderr: stdout's last line is the result."""
+    print(f"[{time.monotonic() - _T0:7.1f}s] {msg}", file=sys.stderr, flush=True)
+
+
+@dataclasses.dataclass
+class Run:
+    """What the readers of per-layer metrics are given."""
+
+    cell: spec.Cell
+    device: dict
+    load: load.Load            # the window's offered schedule and replies
+    window_s: float            # between the two registry snapshots
+    snap0: dict                # obs registry at the window's start
+    snap1: dict                # ... and end
+    trace: tracing.Trace | None
+
+
+def parse_args(argv):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", help="a workload of BENCHMARK.json")
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--config-file", help="rehearsal only: a configuration "
+                    "file in no cell, with --traffic")
+    ap.add_argument("--traffic", help="rehearsal only: a traffic mix")
+    return ap.parse_args(argv)
+
+
+def device_report(n_chips: int) -> dict:
+    import jax
+
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": jax.device_count(),
+            "memory_peak_bytes": deployment.memory_peak_bytes(n_chips)}
+
+
+def align_planes(cluster, settle_ticks: int = 3) -> None:
+    """Start every window from the same interleaving of the two planes.
+
+    Both planes' pipelined ticks share one device queue, and at start-up they
+    lock into one of two stable orders: data, data, control, control (about
+    7 of 10 starts at 1M groups) or strictly alternating, which differ by
+    17% in ``commit_p95_ms`` and stay for a whole window (my chip runs, PR
+    24).  A run that left it to that race would measure the race.  Holding
+    the control plane's lock for a few data-plane ticks and releasing it
+    right after a data-plane dispatch gives the first, commoner and slower
+    order every time: the control plane then dispatches twice (about 70 and
+    170 ms on) before the data plane's next dispatch (about 385 ms on).  No
+    traffic is in flight while it is held, and the window starts after it."""
+    m = cluster.manager
+    with cluster.rc_manager.lock:
+        until = m.tick_num + settle_ticks
+        give_up = time.monotonic() + 30.0
+        while m.tick_num < until and time.monotonic() < give_up:
+            time.sleep(0.001)
+
+
+def warm_up(cell, gen, seed: int, m, client, names: list, actives: list):
+    """Offer the cell's own traffic until the data plane has carried it for
+    ``warmup_ticks`` ticks and is two ticks past the next multiple of
+    ``warmup_past_multiple_of`` (the traffic file says why), then wait for
+    the replies.  Returns the load."""
+    warm = load.Load(gen.schedule(cell.traffic["params"], seed, WARMUP_MAX_S,
+                                  len(names), len(actives), stream=1,
+                                  seq0=10 ** 9), names, actives)
+    warm_ticks = int(cell.traffic["warmup_ticks"])
+    multiple = int(cell.traffic["warmup_past_multiple_of"])
+    first: dict = {}
+
+    def warmed() -> bool:
+        # counted from the first send: idle ticks before it carry nothing
+        if not first:
+            first["tick"] = m.tick_num
+            return False
+        past = (first["tick"] // multiple + 1) * multiple + 2
+        return m.tick_num >= max(first["tick"] + warm_ticks, past)
+
+    warm.offer(client, time.monotonic() + LEAD_S, stop=warmed)
+    answered = warm.wait_replies(client.default_deadline_s)
+    note(f"warm-up: {warm.n_sent} requests over ticks {first['tick']}-"
+         f"{m.tick_num}, {warm.answered()} answered"
+         + ("" if answered else " (the rest count as unknown writes)"))
+    return warm
+
+
+def _install_recorders(cluster) -> list:
+    recorders = []
+    for plane, m in (("ar", cluster.manager), ("rc", cluster.rc_manager)):
+        inner = getattr(m, "_pc", None)
+        if inner is None or not hasattr(inner, "mark"):
+            continue
+        rec = tracing.PhaseRecorder(inner, plane)
+        m._pc = rec
+        recorders.append((m, rec))
+    return recorders
+
+
+def _remove_recorders(recorders: list) -> None:
+    for m, rec in recorders:
+        m._pc = rec._inner
+
+
+def _trace_slice(t0: float, seconds: float, trace_dir: str) -> int:
+    """Trace ``TRACE_SLICE_S`` from the middle of the window.  Returns the
+    ``perf_counter_ns`` at which the clock-sync annotation opened."""
+    import jax
+
+    length = min(TRACE_SLICE_S, seconds / 2)
+    start = t0 + (seconds - length) / 2
+    time.sleep(max(0.0, start - time.monotonic()))
+    # no Python-function events: they slow the host loop under observation
+    # and the metrics read none; TraceMe annotations (the sync mark) stay
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    try:
+        sync_perf_ns = time.perf_counter_ns()
+        with jax.profiler.TraceAnnotation(tracing.SYNC_MARK):
+            time.sleep(0.001)
+        time.sleep(length)
+    finally:
+        jax.profiler.stop_trace()
+    return sync_perf_ns
+
+
+class _TickTimeline:
+    """Diagnostics under ``CHIPBENCH_KEEP=<dir>``: both planes' tick numbers
+    sampled every 5 ms through the window and the drain, saved with every
+    request's due, sent and done instants, for a look by hand at which ticks
+    carried which requests.  Not part of any metric."""
+
+    def __init__(self, cluster):
+        self._managers = (cluster.manager, cluster.rc_manager)
+        self._rows: list = []
+        self._stop = threading.Event()
+        self._thread = threading.Thread(target=self._sample, daemon=True,
+                                        name="chipbench-timeline")
+        self._thread.start()
+
+    def _sample(self) -> None:
+        while not self._stop.wait(0.005):
+            self._rows.append((time.monotonic(),
+                               *(m.tick_num for m in self._managers)))
+
+    def save(self, path: str, window) -> None:
+        self._stop.set()
+        self._thread.join()
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        np.savez_compressed(path, ticks=np.array(self._rows), t0=window.t0,
+                            due=window.due, sent=window.sent, done=window.done,
+                            status=window.status, entry=window.sched.entry)
+
+
+def _collect_writes(loads: list, names: list) -> tuple:
+    """(name -> [Write], [(name, request, reply)] of the acknowledged) over
+    every request the run sent, warm-up included."""
+    from gigapaxos_tpu.reconfiguration import packets as pkt
+
+    writes: dict = {}
+    replies = []
+    for ld in loads:
+        s = ld.sched
+        for i in range(ld.n_sent):
+            name = names[s.name[i]]
+            st = int(ld.status[i])
+            status = ("ok" if st == stats.OK else
+                      "unknown" if st == stats.PENDING else "refused")
+            writes.setdefault(name, []).append(reference.Write(
+                s.value[i], float(ld.sent[i]), float(ld.done[i]), status))
+            if st == stats.OK:
+                replies.append((name, s.payload[i],
+                                pkt.b64d(ld.reply[i]) or b""))
+    return writes, replies
+
+
+def check(cell, cluster, client, loads: list, names: list, actives: list,
+          seed: int) -> list:
+    """The reference against the replies, the replicas and a read-back
+    through the client; returns the problems found."""
+    key = loads[-1].sched.key
+    writes, replies = _collect_writes(loads, names)
+    acked = sorted(n for n, ws in writes.items()
+                   if any(w.status == "ok" for w in ws))
+    rng = np.random.default_rng([seed, 2])
+    pick = rng.choice(len(acked), replace=False, size=min(
+        len(acked), int(cell.traffic["readback_names"])))
+    readback = load.read_back(client, [acked[i] for i in pick], actives, key,
+                              client.default_deadline_s)
+    problems = reference.check_run(
+        writes, lambda n: deployment.replica_tables(cluster, n), replies,
+        readback, key)
+    for p in problems[:10]:
+        note(f"WRONG: {p}")
+    note(f"check: {len(writes)} names touched on {cluster.manager.R} replicas,"
+         f" {len(replies)} replies, {len(readback)} read back by GET; "
+         f"{len(problems)} problem(s)")
+    return problems
+
+
+def run(args, t_start: float) -> dict:
+    rehearsal = os.environ.get("CHIPBENCH_REHEARSAL") == "1"
+    if args.config_file or args.traffic:
+        if not rehearsal:
+            raise SystemExit("--config-file/--traffic are for rehearsals "
+                             "(CHIPBENCH_REHEARSAL=1); a measured run names "
+                             "a --workload of BENCHMARK.json")
+        cell = spec.rehearsal_cell(args.config_file, args.traffic)
+    else:
+        cell = spec.load_cell(args.workload)
+
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "tpu" and not rehearsal:
+        raise SystemExit(f"JAX's default backend is {backend!r}, not a TPU; "
+                         f"no result (CHIPBENCH_REHEARSAL=1 rehearses)")
+    if jax.device_count() < cell.chips:
+        raise SystemExit(f"{cell.name} needs {cell.chips} chip(s); JAX shows "
+                         f"{jax.device_count()}")
+    from gigapaxos_tpu import compile_cache
+    from gigapaxos_tpu.client import ReconfigurableAppClient
+    from gigapaxos_tpu.obs.metrics import registry
+
+    cache_dir = compile_cache.configure()
+    note(f"{cell.name}: backend {backend}, compile cache {cache_dir}")
+
+    gen = spec.generator(cell.traffic["generator"])
+    run_dir = tempfile.mkdtemp(prefix="chipbench_")
+    cluster = client = None
+    recorders: list = []
+    try:
+        cfg = deployment.make_config(cell.config)
+        cluster = deployment.build_cluster(cell.config, cfg, run_dir)
+        m = cluster.manager
+        note(f"cluster up: R={m.R} G={m.G} W={m.W} P={m.P}; first ticks "
+             f"{cluster.driver.first_tick_s:.1f}s / "
+             f"{cluster.rc_driver.first_tick_s:.1f}s")
+        names = deployment.populate(cluster, int(cell.config["populate_groups"]))
+        actives = list(cfg.nodes.active_ids())
+        note(f"populated and adopted {len(names):,} groups")
+        client = ReconfigurableAppClient(cfg.nodes)
+
+        warm = warm_up(cell, gen, args.seed, m, client, names, actives)
+        align_planes(cluster)
+
+        # ---- the window
+        window = load.Load(gen.schedule(cell.traffic["params"], args.seed,
+                                        args.seconds, len(names),
+                                        len(actives)), names, actives)
+        if args.trace:
+            recorders = _install_recorders(cluster)
+        reg = registry()
+        t0 = time.monotonic() + LEAD_S
+        setup_s = t0 - t_start
+        sender = threading.Thread(target=window.offer, args=(client, t0),
+                                  name="chipbench-generator")
+        time.sleep(max(0.0, t0 - time.monotonic()))
+        snap0, t_snap0 = reg.snapshot(), time.monotonic()
+        sender.start()
+        keep = os.environ.get("CHIPBENCH_KEEP")
+        timeline = _TickTimeline(cluster) if keep else None
+        sync_perf_ns = None
+        trace_dir = os.path.join(run_dir, "trace")
+        if args.trace:
+            sync_perf_ns = _trace_slice(t0, args.seconds, trace_dir)
+        time.sleep(max(0.0, t0 + args.seconds - time.monotonic()))
+        snap1, t_snap1 = reg.snapshot(), time.monotonic()
+        sender.join()
+        answered = window.wait_replies(client.default_deadline_s)
+        if timeline is not None:
+            timeline.save(os.path.join(
+                keep, f"{cell.name}.{args.seed}.t{args.trace}.npz"), window)
+        _remove_recorders(recorders)
+        # every request of the window's schedule is due inside the window
+        e2e = stats.end_to_end(window.due, window.done, window.status,
+                               np.ones(len(window.due), bool), args.seconds)
+        note(f"window: {e2e['attempted']} due, {e2e['by_status']}, all "
+             f"answered: {answered}; data-plane tick {m.tick_num}")
+
+        problems = check(cell, cluster, client, [warm, window], names,
+                         actives, args.seed)
+        device = device_report(cell.chips)
+        result = {"correct": not problems and e2e["attempted"] > 0,
+                  "attempted": e2e["attempted"], "failed": e2e["failed"],
+                  "metrics": {}, "device": device}
+        if not args.trace:
+            values = dict(e2e, setup_s=setup_s)
+            for metric in cell.end_to_end:
+                if metric["name"] in values:
+                    result["metrics"][metric["name"]] = {
+                        "value": values[metric["name"]], "unit": metric["unit"]}
+        else:
+            trace_report(result, Run(cell, device, window,
+                                     t_snap1 - t_snap0, snap0, snap1, None),
+                         trace_dir, [r for _, r in recorders], sync_perf_ns,
+                         m, rehearsal)
+        return result
+    finally:
+        if client is not None:
+            client.close()
+        if cluster is not None:
+            cluster.close()
+        shutil.rmtree(run_dir, ignore_errors=True)
+
+
+def trace_report(result: dict, the_run: Run, trace_dir: str, recorders: list,
+                 sync_perf_ns, manager, rehearsal: bool) -> None:
+    """Fill ``result`` with the per-layer metrics, the device's busy time and
+    the breakdown of a traced run."""
+    cell, device = the_run.cell, the_run.device
+    trace = None
+    try:
+        trace = tracing.load_xplane(tracing.newest_xplane(trace_dir))
+    except Exception as e:  # no trace: the trace metrics are left out
+        note(f"trace: {type(e).__name__}: {e}")
+    if rehearsal:
+        trace = None  # no device metric is printed off the chip
+    the_run.trace = trace
+    for metric in cell.per_layer:
+        value = spec.reader(metric["reader"]).read(the_run, **metric["args"])
+        if value is not None:
+            result["metrics"][metric["name"]] = {"value": value,
+                                                 "unit": metric["unit"]}
+    if trace is None:
+        return
+    busy_s, window_s = tracing.busy_and_window_s(trace)
+    device.update(busy_s=busy_s, window_s=window_s)
+    label = None
+    if trace.sync_ns is not None and recorders:
+        label = tracing.phase_labeller(recorders, sync_perf_ns, trace.sync_ns)
+    result["breakdown"] = {"device_ops": tracing.top_ops(trace, 10),
+                           "idle_gaps": tracing.idle_gaps(trace, 10, label)}
+    _report_kernels(manager, trace)
+    keep = os.environ.get("CHIPBENCH_KEEP")
+    if keep:
+        trace.to_json(os.path.join(keep, f"{cell.name}.trace.json.gz"))
+        shutil.copy(tracing.newest_xplane(trace_dir),
+                    os.path.join(keep, f"{cell.name}.xplane.pb"))
+
+
+def _report_kernels(m, trace) -> None:
+    """Cross-check on stderr: the kernels the dispatched program carries
+    against the kernel events the trace shows per execution."""
+    try:
+        fn, fn_args = deployment.tick_program(m)
+        k = deployment.kernels_in(fn, *fn_args)
+    except Exception as e:
+        note(f"kernels_in: {type(e).__name__}: {e}")
+        return
+    execs = len(trace.line(tracing.MODULES))
+    for which in ("gather", "match"):
+        events, secs = tracing.op_seconds(trace, f"^%{k[which + '_name']}")
+        note(f"kernel {k[which + '_name']}: {k['mosaic_' + which]} Mosaic "
+             f"calls in the program, {events} events over {execs} executions "
+             f"in the trace, {secs:.4f}s")
+    note(f"kernels: {k['pallas_calls']} pallas calls traced, "
+         f"{k['interpreted']} interpreted")
+
+
+def main(argv, t_start: float) -> int:
+    args = parse_args(argv)
+    if not (args.workload or (args.config_file and args.traffic)):
+        print("chipbench: --workload is required", file=sys.stderr)
+        return 2
+    faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
+    result = run(args, t_start)
+    faulthandler.cancel_dump_traceback_later()
+    print(json.dumps(result), flush=True)
+    return 0

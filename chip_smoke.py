@@ -126,28 +126,13 @@ class RefKV:
         raise ValueError(f"reference does not know {request!r}")
 
 
-class CacheCounter:
-    """Persistent-compile-cache hits and misses, from JAX's own monitoring
-    events (one of each per program looked up)."""
+def cache_since(snap) -> str:
+    """Persistent-compile-cache hits and misses since ``snap`` (a
+    ``compiles.cache_lookups()`` pair), from the program's own counters."""
+    from gigapaxos_tpu.obs import compiles
 
-    def __init__(self):
-        import jax.monitoring
-
-        self.hits = self.misses = 0
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event: str, **_kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return self.hits, self.misses
-
-    def since(self, snap) -> str:
-        return (f"{self.hits - snap[0]} from the cache, "
-                f"{self.misses - snap[1]} compiled")
+    hits, misses = compiles.cache_lookups()
+    return f"{hits - snap[0]} from the cache, {misses - snap[1]} compiled"
 
 
 # ------------------------------------------------------------------- stages
@@ -682,6 +667,7 @@ def run(groups: int = FULL_GROUPS, wave: int = FULL_WAVE, seed: int = 0,
     same stages, the kernels interpreted (the caller sets GPTPU_PALLAS=1 and
     GPTPU_PALLAS_INTERPRET=1), and no Mosaic text to find."""
     from gigapaxos_tpu import compile_cache
+    from gigapaxos_tpu.obs import compiles
 
     log = Log(log_path)
     if backend_init_s is not None:
@@ -692,12 +678,12 @@ def run(groups: int = FULL_GROUPS, wave: int = FULL_WAVE, seed: int = 0,
         cache_dir = compile_cache.configure()
         with log.stage("environment"):
             device = report_environment(log, cache_dir)
-        cache = CacheCounter()
+        compiles.install()  # before the first program: it counts from here
         ref = RefKV()
         with log.stage("build cluster"):
             cluster = build_cluster(log, make_config(groups, mesh_devices),
                                     run_dir, ready_timeout_s)
-            log(f"tick programs: {cache.since((0, 0))}")
+            log(f"tick programs: {cache_since((0, 0))}")
             if mesh_devices:
                 check_mesh_spread(log, cluster.manager, mesh_devices)
         with log.stage("populate"):
@@ -718,15 +704,15 @@ def run(groups: int = FULL_GROUPS, wave: int = FULL_WAVE, seed: int = 0,
             cluster = None
             check(drained, "shutdown did not drain within 120s")
         with log.stage("other programs"):
-            snap = cache.snapshot()
+            snap = compiles.cache_lookups()
             other_programs(log, groups, on_chip)
-            log(f"other programs: {cache.since(snap)}")
+            log(f"other programs: {cache_since(snap)}")
         with log.stage("restart"):
-            snap = cache.snapshot()
+            snap = compiles.cache_lookups()
             cluster = restart(log, make_config(groups, mesh_devices),
                               run_dir, ref, client_names, wave_names,
                               ready_timeout_s, rpc_timeout_s)
-            log(f"restart leg programs: {cache.since(snap)}")
+            log(f"restart leg programs: {cache_since(snap)}")
             check(cluster.shutdown(drain_timeout_s=120.0),
                   "second shutdown did not drain")
             cluster = None

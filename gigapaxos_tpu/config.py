@@ -428,10 +428,6 @@ class ObsConfig:
     # Stamp client app requests with a cross-process trace id ("trace" wire
     # key); equivalent to GPTPU_REQTRACE on the client process.
     trace_wire: bool = False
-    # Opt-in exact device phase timing: block on the dispatch result and
-    # record a "device_step" phase (costs the pipeline overlap — bench-style
-    # measurement, not for production).
-    blocking_phases: bool = False
     # Flight recorder: ring capacity and artifact directory ("" = alongside
     # the WAL / base dir of whatever plane hosts the recorder).
     flight_cap: int = 256

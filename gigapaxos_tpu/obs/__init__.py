@@ -10,9 +10,11 @@ story made production-shaped for the dense TPU stack:
   under ``GPTPU_METRICS=0`` (the overhead A/B in
   ``benchmarks/obs_overhead.py`` flips exactly this switch).
 * :mod:`.phase` — per-tick phase clocks for the Mode A / Mode B / chain tick
-  drivers.  Host-timestamped at dispatch and completion, so the always-on
-  mode adds **no device sync**; the opt-in blocking mode reuses bench.py's
-  cumulative-prefix technique for exact device step time.
+  drivers.  Host-timestamped at dispatch and completion, so it adds **no
+  device sync**; under a profiler the same phases are trace annotations, and
+  the tick programs' device phases are named scopes (``TICK_SCOPES``).
+* :mod:`.compiles` — JAX's trace / lower / compile durations and persistent
+  cache lookups as metrics: what stalls a tick from inside.
 * :mod:`.prom` — Prometheus text exposition, including per-cell label
   injection so a CellSupervisor can serve one host-level scrape.
 * :mod:`.http` — the scrape endpoint (``/metrics``, ``/trace/<id>``,

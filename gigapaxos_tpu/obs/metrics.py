@@ -221,7 +221,11 @@ class Registry:
             return [m for (n, _), m in self._metrics.items() if n == name]
 
     def snapshot(self) -> dict:
-        """Flat JSON-able dump (flight-recorder / StatsReporter payload)."""
+        """Flat JSON-able dump (flight-recorder / StatsReporter payload).
+
+        A histogram's ``buckets`` maps bucket index (as a string: JSON keys)
+        to count; bucket ``i`` holds samples up to ``(2**i - 1)`` base units
+        (microseconds for ``unit="s"``), as ``Histogram.bucket_upper``."""
         out = {}
         for m in self.metrics():
             key = m.name
@@ -234,6 +238,12 @@ class Registry:
                     "p50": m.percentile(0.50),
                     "p90": m.percentile(0.90),
                     "p99": m.percentile(0.99),
+                    # non-empty buckets only (the flight recorder writes a
+                    # snapshot every second): the difference of two
+                    # snapshots gives a window's quantile or maximum,
+                    # which the since-start p50/p90/p99 cannot
+                    "buckets": {str(i): c for i, c in enumerate(m.buckets)
+                                if c},
                 }
             else:
                 out[key] = m.value

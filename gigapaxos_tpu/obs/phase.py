@@ -6,14 +6,23 @@ mark into ``tick_phase_seconds{driver=,plane=,phase=}``.  The timestamps are
 host-side (taken at dispatch enqueue and at completion/unpack), so the
 always-on mode adds **no device synchronization** — the ``dispatch`` phase
 is enqueue cost and the ``tally`` phase absorbs the device wait exactly as
-the manager already experiences it.  For exact device step time there is an
-opt-in blocking mode (``cfg.obs.blocking_phases``): the driver calls
-``jax.block_until_ready`` on the dispatch result before marking, the same
-measurement bench.py's cumulative-prefix jits isolate offline.
+the manager already experiences it.  Exact device time comes from a profiler
+trace, where both halves of the program's vocabulary show on one clock:
 
-The canonical phase vocabularies below are the contract the static
-coverage test (``tests/test_obs_coverage.py``) greps driver sources
-against — add a phase here AND a ``mark`` there, or tier-1 fails.
+* each host phase of a driver listed in ``PHASE_RUNS`` is also a
+  ``jax.profiler.TraceAnnotation`` named ``gptpu/<driver>/<plane>/<phase>``
+  with the phase's true start and end, on the profile's ``/host:CPU`` plane.
+  While no profile is being taken it costs one ``TraceMe`` flag check per
+  phase;
+* each device phase of the tick programs (``ops/tick.py``) runs under a
+  ``jax.named_scope`` from ``TICK_SCOPES``, so every ``XLA Ops`` event of a
+  tick program names the phase its instruction came from.  XLA fuses across
+  scope boundaries and a fusion carries one instruction's metadata, so
+  device time splits by op, not by source line.
+
+The canonical vocabularies below are the contract the static coverage test
+(``tests/test_obs_coverage.py``) greps driver and tick sources against — add
+a phase here AND a ``mark`` (or a scope) there, or tier-1 fails.
 """
 
 from __future__ import annotations
@@ -40,8 +49,29 @@ DRIVER_PHASES: Dict[str, Tuple[str, ...]] = {
                     "tally", "execute", "outbox_pack", "egress"),
 }
 
-#: The extra phase recorded only under cfg.obs.blocking_phases.
-BLOCKING_PHASE = "device_step"
+#: driver -> the runs of phases its tick marks in a fixed order, each run
+#: back to back: ``begin()`` opens the first run and ``touch()`` the second
+#: (the completion of a pipelined tick), and ``mark(p)`` opens ``p``'s
+#: successor within its run.  A trace annotation's name is fixed when it
+#: opens, while ``mark`` names a phase when it closes, so the clock has to
+#: know what comes next; a driver without an entry emits no annotations.
+PHASE_RUNS: Dict[str, Tuple[Tuple[str, ...], ...]] = {
+    "modea": (("repair", "intake", "dispatch", "wal_fsync"),
+              ("tally", "execute", "egress", "sweep")),
+}
+
+#: the ``jax.named_scope`` names inside the tick programs (``ops/tick.py``):
+#: the phases of ``paxos_tick_impl`` under the names its own comments use,
+#: the lease and health folds, and the programs around it
+TICK_SCOPES: Tuple[str, ...] = (
+    "candidacy", "prepare", "intake", "accept", "tally", "decision_sync",
+    "execute", "freeze", "repair_summary", "lease_fold", "health_fold",
+    "compact_outbox", "sweep_frontier", "frontier_rows",
+)
+
+
+def annotation_name(driver: str, plane: str, phase: str) -> str:
+    return f"gptpu/{driver}/{plane}/{phase}"
 
 
 class PhaseClock:
@@ -51,16 +81,38 @@ class PhaseClock:
     advances the mark.  ``touch`` re-arms the mark without observing — the
     pipelined completion path (``drain_pipeline``) uses it so a deferred
     ``_complete_tick`` doesn't attribute cross-tick idle time to ``tally``.
+
+    While a profile is being taken the phases of a ``PHASE_RUNS`` driver are
+    trace annotations too (module docstring).  ``annotation`` is the class
+    that makes them (``jax.profiler.TraceAnnotation``; a test substitutes
+    its own): a context manager per name, and ``is_enabled()`` says whether
+    a profile is on.
     """
 
-    __slots__ = ("driver", "plane", "_reg", "_h", "_tick_h", "_t", "_t0")
+    __slots__ = ("driver", "plane", "_reg", "_h", "_tick_h", "_t", "_t0",
+                 "_first", "_resume", "_next", "_open", "_annotation")
 
     def __init__(self, driver: str, plane: str = "default",
-                 reg: Optional[Registry] = None):
+                 reg: Optional[Registry] = None, annotation=None):
         self.driver = driver
         self.plane = plane
         self._reg = registry() if reg is None else reg
         self._h: Dict[str, Histogram] = {}
+        runs = PHASE_RUNS.get(driver, ())
+        # annotation names, built once: what begin() and touch() open, and
+        # what follows each phase (None at the end of a run)
+        def name(phase: str) -> str:
+            return annotation_name(driver, plane, phase)
+
+        self._first = name(runs[0][0]) if runs else None
+        self._resume = name(runs[1][0]) if len(runs) > 1 else None
+        self._next: Dict[str, Optional[str]] = {
+            p: name(q) for run in runs for p, q in zip(run, run[1:])}
+        self._open = None
+        if runs and annotation is None:
+            # here and not at import: the package stays importable off JAX
+            from jax.profiler import TraceAnnotation as annotation
+        self._annotation = annotation
         self._tick_h = self._reg.histogram(
             "tick_seconds", help="whole-tick wall time",
             driver=driver, plane=plane)
@@ -81,21 +133,34 @@ class PhaseClock:
                 driver=self.driver, plane=self.plane, phase=phase)
         return h
 
+    def _annotate(self, name: Optional[str]) -> None:
+        """Close the open annotation and open ``name`` (None: nothing)."""
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+        if name is not None and self._annotation.is_enabled():
+            self._open = self._annotation(name)
+            self._open.__enter__()
+
     def begin(self) -> None:
         now = time.perf_counter()
         self._t = now
         self._t0 = now
+        self._annotate(self._first)
 
     def touch(self) -> None:
         self._t = time.perf_counter()
+        self._annotate(self._resume)
 
     def mark(self, phase: str) -> None:
         now = time.perf_counter()
         self._phase_h(phase).observe(now - self._t)
         self._t = now
+        self._annotate(self._next.get(phase))
 
     def end(self) -> None:
         self._tick_h.observe(time.perf_counter() - self._t0)
+        self._annotate(None)
 
 
 class _NullPhaseClock:

@@ -40,6 +40,8 @@ replay recovery (see ``wal/logger.py``).
 
 from __future__ import annotations
 
+import contextlib
+import functools
 from typing import NamedTuple
 
 import jax
@@ -51,11 +53,50 @@ from .ballot import bal_ge, bal_gt
 from .window import gather_planes, match_planes
 
 I32 = jnp.int32
+
 # numpy scalar, NOT jnp: a module-level jnp value would initialize the
 # default backend at import time, and importing this module must not touch
 # a device (a supervisor that imports it would take the chip its workers
 # need)
 NEG_INF = np.int32(-(2**31))
+
+
+# Device phases by name.  Every op of a tick program carries, as metadata,
+# the ``jax.named_scope`` it was traced under (``obs/phase.py`` TICK_SCOPES is
+# the vocabulary, held to this file by tests/test_obs_coverage.py), so a
+# profiler trace splits a program's device time by phase.  Metadata only: the
+# compiled programs are otherwise the same, and outside a trace it costs
+# nothing.  XLA fuses across scope boundaries and a fusion keeps one
+# instruction's metadata, so the split is by op.
+class _PhaseScopes:
+    """One ``jax.named_scope`` at a time, switched the way the phase clock
+    marks: ``scope(name)`` closes the open scope and opens ``name``, so the
+    phases of one long function body are named without indenting it.  Used
+    as a context manager, so an error while tracing leaves no scope open."""
+
+    def __init__(self):
+        self._open = contextlib.ExitStack()
+
+    def __enter__(self):
+        return self
+
+    def __call__(self, name: str) -> None:
+        self._open.close()
+        self._open.enter_context(jax.named_scope(name))
+
+    def __exit__(self, *exc) -> None:
+        self._open.close()
+
+
+def _scoped(name: str):
+    """Decorator: trace the whole function under ``jax.named_scope(name)``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
 
 
 class TickInbox(NamedTuple):
@@ -382,6 +423,19 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
     requires real promises/votes carried by received frames — mirroring the
     reference, where a minority partition can never decide
     (PaxosCoordinatorState majority tally, WaitforUtility)."""
+    with _PhaseScopes() as scope:
+        return _tick_phases(scope, state, inbox, own_row, exec_budget,
+                            group_axis, fast_elect, lease, lease_horizon,
+                            health, wedge_ticks, health_decay_shift,
+                            health_topk)
+
+
+def _tick_phases(scope: _PhaseScopes, state, inbox: TickInbox, own_row: int,
+                 exec_budget: int, group_axis, fast_elect: bool, lease,
+                 lease_horizon: int, health, wedge_ticks: int,
+                 health_decay_shift: int, health_topk: int):
+    """The body of :func:`paxos_tick_impl`; ``scope(name)`` opens each
+    phase's named scope (what comes before the first is shared set-up)."""
     R, G = state.exec_slot.shape
     W = state.acc_req.shape[1]
     P = inbox.req.shape[1]
@@ -425,6 +479,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
     i_j = jnp.bitwise_and(s_j, Wm)  # [W, G] ring indices (replica-agnostic)
 
     # ---------------- phase 0: candidacy ----------------
+    scope("candidacy")
     coord_dead = ~alive_at(state.bal_coord)  # [R, G]
     caught_up = (state.exec_slot - base[None, :]) >= 0
     # candidate = first live *caught-up* member: a stuck laggard must not
@@ -460,6 +515,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
     coord_fast = state.coord_fast
 
     # ---------------- phase 1: prepare / promise / carryover ----------------
+    scope("prepare")
     prep_mask = coord_preparing & acc_ok  # [R, G] candidates broadcasting
     pn = jnp.where(prep_mask, coord_bnum, NEG_INF)
     best_pn, best_pc = _lexmax(pn, jnp.broadcast_to(r_idx, (R, G)), axis=0)  # [G]
@@ -569,6 +625,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
     prop_valid = prop_valid & ~retire[:, None, :]
 
     # ---------------- phase 2a: intake + slot assignment ----------------
+    scope("intake")
     an = jnp.where(coord_active & acc_ok, coord_bnum, NEG_INF)
     w_n, w_c = _lexmax(an, jnp.broadcast_to(r_idx, (R, G)), axis=0)  # [G]
     has_coord = w_n != NEG_INF
@@ -670,6 +727,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
         coord_bnum = jnp.where(any_adopt, coord_bnum + 1, coord_bnum)
 
     # ---------------- phase 2b: accept ----------------
+    scope("accept")
     pushing = (coord_active & acc_ok)[:, None, :] & prop_valid  # [R, W, G]
     cand_n = jnp.where(pushing, coord_bnum[:, None, :], NEG_INF)
     cand_c = jnp.broadcast_to(r_idx[:, None, :], (R, W, G))
@@ -753,6 +811,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
         coord_bnum = jnp.where(demote, coord_bnum + 1, coord_bnum)
 
     # ---------------- phase 2c: tally + quorum ----------------
+    scope("tally")
     A_bnum = gather_planes(acc_bnum, i_j)
     A_bcoord = gather_planes(acc_bcoord, i_j)
     A_req = gather_planes(acc_req, i_j)
@@ -785,6 +844,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
     dec_valid = jnp.where(dwrite, True, state.dec_valid)
 
     # ---------------- phase 3: decision sync (laggard catch-up) ----------------
+    scope("decision_sync")
     # latest decision per ring plane among live serving members, then each
     # replica adopts entries that fall inside its own forward window.
     rel = jnp.where(
@@ -810,6 +870,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
     dec_valid = jnp.where(adopt, True, dec_valid)
 
     # ---------------- phase 4: in-order execution ----------------
+    scope("execute")
     s_own = state.exec_slot[:, None, :] + jw[None]  # [R, W, G]
     i_own = jnp.bitwise_and(s_own, Wm)
     Dreq = gather_planes(dec_req, i_own)
@@ -875,6 +936,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
     prop_valid = prop_valid & (prop_slot - exec_slot[:, None, :] >= 0)
 
     # ---------------- freeze dead replica slots ----------------
+    scope("freeze")
     al3 = alive[:, None, None]
     al2 = alive[:, None]
 
@@ -909,6 +971,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
         prop_stop=fr3(prop_stop, state.prop_stop),
     )
     # ------------- laggard repair control summary (donor selection) --------
+    scope("repair_summary")
     # The host repair path used to re-derive the donor from a full [R, G]
     # exec pull (manager.sync_laggard); emit it from the tick instead so the
     # host never touches [R, G] state for repair.  Donor for laggard r =
@@ -955,6 +1018,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
     )
     if lease is not None:
         # ---- lease grant/renew fold (ISSUE 17) ----
+        scope("lease_fold")
         # Renewal piggybacks on the accept traffic this same tick pushed:
         # the effective winner keeps its lease alive just by staying the
         # winner.  A grant needs the previous lease gone (never held, or
@@ -979,6 +1043,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
         ])
     if health is not None:
         # ---- group health fold (ISSUE 18) ----
+        scope("health_fold")
         # Read-only w.r.t. consensus: every input below is a fact the tick
         # already computed.  Device-visible backlog = offered intake (the
         # host re-places rejected requests every tick, so a wedged group
@@ -1172,6 +1237,7 @@ class CompactHostOutbox(NamedTuple):
     l_lexec: "np.ndarray"  # i32 — the laggard's own post-tick exec watermark
 
 
+@_scoped("compact_outbox")
 def _compact_outbox_impl(out: TickOutbox, exec_budget: int,
                          lag_budget: int) -> jnp.ndarray:
     R, W, G = out.exec_req.shape
@@ -1351,6 +1417,7 @@ def unpack_compact(flat, R: int, G: int, exec_budget: int,
 # --------------------------------------------------------------------------
 
 
+@_scoped("sweep_frontier")
 def sweep_frontier_impl(exec_slot, member, alive):
     """Per-group payload-sweep frontier, the device twin of the host
     reductions ``_sweep_outstanding`` used to run over full ``[R, G]``
@@ -1383,6 +1450,7 @@ def sweep_frontier_impl(exec_slot, member, alive):
 sweep_frontier = jax.jit(sweep_frontier_impl)
 
 
+@_scoped("frontier_rows")
 def _frontier_rows_impl(amin, base, live, rows):
     return (jnp.take(amin, rows, mode="clip"),
             jnp.take(base, rows, mode="clip"),

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import collections
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
@@ -37,7 +38,8 @@ from .. import overload as _overload
 from ..models.replicable import Replicable
 from ..types import GroupStatus, NO_REQUEST
 from ..utils.intmap import RowAllocator
-from ..obs.phase import BLOCKING_PHASE as _BLOCKING_PHASE
+from ..obs import compiles as _compiles
+from ..obs.metrics import registry as _obs_registry
 from ..obs.phase import phase_clock as _phase_clock
 from ..utils.locking import ContendedLock, locked as _locked
 from ..utils.reqtrace import tracer as _reqtrace
@@ -76,6 +78,10 @@ class RequestRecord:
     slot: int = -1  # filled at first execution
     executed_by: set = field(default_factory=set)
     responded: bool = False
+    #: perf_counter() when propose staged it / when _build_inbox first
+    #: placed it in a tick's inbox (request_stage_seconds; 0.0 = not yet)
+    t_staged: float = 0.0
+    t_placed: float = 0.0
 
 
 def _pad_rows(rows: np.ndarray, oob: int) -> np.ndarray:
@@ -463,11 +469,24 @@ class PaxosManager:
         #: always-on tick phase clock (obs/phase.py): host timestamps only —
         #: "dispatch" is enqueue cost, the device wait lands in "tally" at
         #: the unpack sync point, so no device synchronization is added.
-        #: cfg.obs.blocking_phases adds an exact "device_step" phase by
-        #: blocking on the dispatch result (bench-style measurement).
+        #: Exact device time is the profiler trace's to give.
         self._pc = _phase_clock("modea", plane=spill_ns)
-        self._obs_block = bool(getattr(getattr(cfg, "obs", None),
-                                       "blocking_phases", False))
+        #: where a request's time goes on this plane, observed once per
+        #: acknowledged scalar request when its response is handed to the
+        #: held callbacks: staged -> first placed in a tick's inbox (queue),
+        #: then -> that hand-over (commit).  Refused, failed and expired
+        #: requests observe nothing; the bulk path carries no time per
+        #: request and is left out.
+        self._stage_queue_h, self._stage_commit_h = (
+            _obs_registry().histogram(
+                "request_stage_seconds",
+                help="scalar request: staged->placed (queue), "
+                     "placed->response held (commit)",
+                plane=spill_ns, stage=stage)
+            for stage in ("queue", "commit"))
+        # compiles and cache lookups inside the served path are metrics
+        # from the first manager of the process on
+        _compiles.install()
         # Control-plane threads (messenger readers, protocol tasks) call the
         # admin/propose API while a tick driver loops on tick(); one reentrant
         # lock serializes them (the reference synchronizes on the instance map
@@ -1352,7 +1371,7 @@ class PaxosManager:
             rid = self._next_rid
             self._next_rid += 1
         self._staged.append((rid, name, payload, callback, stop, entry,
-                             deadline))
+                             deadline, time.perf_counter()))
         if self.reqtrace.enabled:
             self.reqtrace.event(rid, "staged", name=name)
         return rid
@@ -1401,10 +1420,12 @@ class PaxosManager:
             self._next_rid += 1
         if self.reqtrace.enabled:
             self.reqtrace.event(rid, "staged", name=name, path="slow")
-        self._admit(rid, name, row, payload, callback, stop, entry)
+        self._admit(rid, name, row, payload, callback, stop, entry,
+                    time.perf_counter())
         return rid
 
-    def _admit(self, rid, name, row, payload, callback, stop, entry) -> None:
+    def _admit(self, rid, name, row, payload, callback, stop, entry,
+               t_staged) -> None:
         """Insert one request into the per-row queues (manager lock held)."""
         if isinstance(payload, bytes):
             payload = self._paystore.intern(payload)
@@ -1414,7 +1435,8 @@ class PaxosManager:
             # replica set — a non-member never executes, so its callback
             # would be orphaned)
             entry = int(members[rid % len(members)]) if len(members) else 0
-        rec = RequestRecord(rid, name, row, payload, stop, callback, entry)
+        rec = RequestRecord(rid, name, row, payload, stop, callback, entry,
+                            t_staged=t_staged)
         self.outstanding[rid] = rec
         self._row_outstanding[row] += 1
         self._queues[row].append(rid)
@@ -1435,8 +1457,8 @@ class PaxosManager:
         try:
             while True:
                 try:
-                    rid, name, payload, callback, stop, entry, deadline = \
-                        self._staged.popleft()
+                    (rid, name, payload, callback, stop, entry, deadline,
+                     t_staged) = self._staged.popleft()
                 except IndexError:
                     return
                 if _overload.expired(deadline):
@@ -1459,7 +1481,8 @@ class PaxosManager:
                     if self.reqtrace.enabled:
                         self.reqtrace.event(rid, "failed", name=name)
                     continue
-                self._admit(rid, name, row, payload, callback, stop, entry)
+                self._admit(rid, name, row, payload, callback, stop, entry,
+                            t_staged)
         finally:
             self._draining = False
 
@@ -1896,6 +1919,7 @@ class PaxosManager:
             req[_e, _p, _rw] = 0
             stp[_e, _p, _rw] = False
             self._bulk_placed = None
+        now = time.perf_counter()  # one read for all of this tick's placements
         placed = []
         for row, q in self._queues.items():
             used = collections.Counter()
@@ -1923,6 +1947,8 @@ class PaxosManager:
                 req[entry, p, row] = rid
                 stp[entry, p, row] = rec.stop
                 take.append((rid, entry, p))
+                if not rec.t_placed:
+                    rec.t_placed = now  # a rejected intake is placed again
                 if self.reqtrace.enabled:
                     self.reqtrace.event(rid, "placed", tick=self.tick_num)
             if take:
@@ -2268,13 +2294,6 @@ class PaxosManager:
             )
             if fr is not None:
                 frontier = self._frontier_gather(fr)
-        if self._obs_block:
-            # opt-in exact device step (bench.py's cumulative-prefix
-            # measurement, online): costs the overlap the pipeline buys
-            import jax
-
-            jax.block_until_ready(packed)
-            pc.mark(_BLOCKING_PHASE)
         pc.mark("dispatch")
         if self.wal is not None:
             self.wal.log_inbox(self.tick_num, inbox)
@@ -2509,6 +2528,10 @@ class PaxosManager:
             rec.responded = True
             if rec.callback is not None:
                 self._held_callbacks.append((rec.callback, rid, response))
+            if rec.t_staged and rec.t_placed:  # a recovered record has none
+                self._stage_queue_h.observe(rec.t_placed - rec.t_staged)
+                self._stage_commit_h.observe(
+                    time.perf_counter() - rec.t_placed)
             if self.reqtrace.enabled:
                 self.reqtrace.event(rid, "responded", slot=slot)
         members = int(self._n_members_np[row])

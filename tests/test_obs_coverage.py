@@ -14,7 +14,7 @@ fsync fails here in milliseconds instead of silently holing a dashboard.
 import os
 import re
 
-from gigapaxos_tpu.obs.phase import BLOCKING_PHASE, DRIVER_PHASES
+from gigapaxos_tpu.obs.phase import DRIVER_PHASES, PHASE_RUNS, TICK_SCOPES
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -36,8 +36,6 @@ def test_driver_phases_contract_is_sane():
     for driver, phases in DRIVER_PHASES.items():
         assert phases, driver
         assert len(phases) == len(set(phases)), f"{driver}: duplicate phase"
-        # the opt-in blocking mark is extra, never part of the base contract
-        assert BLOCKING_PHASE not in phases, driver
     # every driver journals and executes — the two phases any SLO story
     # starts from
     for driver, phases in DRIVER_PHASES.items():
@@ -52,12 +50,46 @@ def test_every_declared_phase_is_marked_in_its_driver():
         marked = set(re.findall(r'\.mark\(\s*["\']([a-z_]+)["\']', src))
         missing = set(DRIVER_PHASES[driver]) - marked
         assert not missing, f"{rel}: declared but never marked: {missing}"
-        undeclared = marked - set(DRIVER_PHASES[driver]) - {BLOCKING_PHASE}
+        undeclared = marked - set(DRIVER_PHASES[driver])
         assert not undeclared, (
             f"{rel}: marks {undeclared} not in DRIVER_PHASES[{driver!r}] — "
             f"add them to obs/phase.py so dashboards see the contract")
         # begin/end bracket the marks
         assert ".begin()" in src and ".end()" in src, rel
+
+
+def test_phase_runs_are_the_order_the_driver_marks_in():
+    """A trace annotation is named when it opens, so the clock opens the
+    successor of the phase just marked (obs/phase.py PHASE_RUNS): the runs
+    must be the declared phases, in the order the driver's source marks
+    them, or the trace would show a phase under its neighbour's name."""
+    for driver, runs in PHASE_RUNS.items():
+        flat = [p for run in runs for p in run]
+        assert flat == list(DRIVER_PHASES[driver]), driver
+        marks = re.findall(r'\bpc\.mark\(\s*["\']([a-z_]+)["\']',
+                           _src(DRIVER_FILES[driver]))
+        # a phase marked in both arms of a branch shows twice in a row
+        in_source = [p for i, p in enumerate(marks)
+                     if i == 0 or marks[i - 1] != p]
+        assert in_source == flat, (driver, in_source)
+
+
+def test_tick_scopes_are_the_scopes_of_the_tick_programs():
+    """TICK_SCOPES (obs/phase.py) against ops/tick.py, both ways: a scope a
+    trace reader is promised must exist, and a scope the source opens must
+    be in the vocabulary a reader splits device time by."""
+    src = _src("gigapaxos_tpu/ops/tick.py")
+    opened = re.findall(r'^\s*(?:scope|@_scoped)\(\s*"([a-z_]+)"\)', src,
+                        re.M)
+    assert len(opened) == len(set(opened)), "a scope is opened twice"
+    assert set(opened) == set(TICK_SCOPES), (
+        set(opened) ^ set(TICK_SCOPES))
+    assert len(TICK_SCOPES) == len(set(TICK_SCOPES))
+    # the phases keep the names and the order of the source's own banners
+    banners = re.findall(r"# -+ (?:phase \w+: )?([a-z][a-z +/()\-]*?) -+\n"
+                         r'\s*scope\("([a-z_]+)"\)', src)
+    assert [s for _, s in banners] == list(TICK_SCOPES[:len(banners)])
+    assert len(banners) == 9, banners
 
 
 def test_wal_fsync_goes_through_instrumented_sync_only():
@@ -90,6 +122,10 @@ WIRING = {
     # metric family -> file that must create it
     "tick_phase_seconds": "gigapaxos_tpu/obs/phase.py",
     "tick_seconds": "gigapaxos_tpu/obs/phase.py",
+    # where a request's time goes and what stalls a tick (ISSUE 26)
+    "request_stage_seconds": "gigapaxos_tpu/paxos/manager.py",
+    "jit_compile_seconds": "gigapaxos_tpu/obs/compiles.py",
+    "compile_cache_lookups_total": "gigapaxos_tpu/obs/compiles.py",
     "wal_fsync_seconds": "gigapaxos_tpu/wal/logger.py",
     "wal_fsync_stalls_total": "gigapaxos_tpu/wal/logger.py",
     "wal_appended_bytes_total": "gigapaxos_tpu/wal/logger.py",

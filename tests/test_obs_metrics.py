@@ -75,6 +75,39 @@ def test_registry_get_or_create_and_null_twin():
     assert render_registry(n) == ""
 
 
+def test_snapshot_carries_sparse_buckets_and_two_give_a_windows_maximum():
+    import json
+
+    r = Registry()
+    h = r.histogram("tick_seconds", driver="modea", plane="t")
+    key = "tick_seconds{driver=modea,plane=t}"
+    # before the window: one long first tick (a compile), many short ones
+    h.observe(2.5)
+    for _ in range(10):
+        h.observe(0.090)
+    snap0 = r.snapshot()
+    b0 = snap0[key]["buckets"]
+    # only the buckets that hold a sample, index as a string (a JSON key)
+    assert b0 == {str(int(0.090 * 1e6).bit_length()): 10,
+                  str(int(2.5 * 1e6).bit_length()): 1}
+    assert json.loads(json.dumps(snap0)) == snap0
+    assert sum(b0.values()) == snap0[key]["count"]
+    # the window: short ticks and one stall of 0.7 s
+    for _ in range(20):
+        h.observe(0.095)
+    h.observe(0.7)
+    b1 = r.snapshot()[key]["buckets"]
+    rose = [int(i) for i, c in b1.items() if c > b0.get(i, 0)]
+    longest = h.bucket_upper(max(rose))
+    # the window's maximum to its bucket (within 2x above the sample): the
+    # since-start p99 still says 2.5 s, the first tick's
+    assert 0.7 <= longest < 1.4
+    assert h.percentile(0.99) > 2.5
+    # an empty histogram has no bucket to show
+    r.histogram("idle_seconds")
+    assert r.snapshot()["idle_seconds"]["buckets"] == {}
+
+
 # ---------------------------------------------------------------- rendering
 def test_render_registry_prometheus_text():
     r = Registry()

@@ -1,0 +1,336 @@
+"""Where a request's time goes and what stalls a tick, from inside the
+program (ISSUE 26): the ``request_stage_seconds`` histograms of the Mode A
+manager, the JAX compile listener, the named scopes of the tick programs and
+the phase clock's trace annotations.
+
+The default registry is process-wide and other test files share the process,
+so every assertion here is on the difference of two snapshots, on a plane
+label of the test's own where a manager is built directly.
+"""
+
+import threading
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from gigapaxos_tpu.client import ReconfigurableAppClient
+from gigapaxos_tpu.config import GigapaxosTpuConfig
+from gigapaxos_tpu.models.replicable import KVApp
+from gigapaxos_tpu.node import InProcessCluster
+from gigapaxos_tpu.obs import compiles
+from gigapaxos_tpu.obs.metrics import Registry, registry
+from gigapaxos_tpu.obs.phase import (DRIVER_PHASES, PHASE_RUNS, TICK_SCOPES,
+                                     PhaseClock, annotation_name)
+from gigapaxos_tpu.ops import tick as tk
+from gigapaxos_tpu.paxos import state as st
+from gigapaxos_tpu.paxos.manager import PaxosManager
+
+
+def _hist(snap: dict, family: str, **labels) -> tuple:
+    """(count, sum) over the family's series that carry ``labels``."""
+    count, total = 0, 0.0
+    for key, val in snap.items():
+        name, _, rest = key.partition("{")
+        have = dict(kv.split("=", 1) for kv in rest.rstrip("}").split(",")
+                    if "=" in kv)
+        if name == family and all(have.get(k) == v
+                                  for k, v in labels.items()):
+            count, total = count + val["count"], total + val["sum"]
+    return count, total
+
+
+def _delta(snap0: dict, snap1: dict, family: str, **labels) -> tuple:
+    a, b = _hist(snap0, family, **labels), _hist(snap1, family, **labels)
+    return b[0] - a[0], b[1] - a[1]
+
+
+# ------------------------------------------------------------ request stages
+@pytest.fixture(scope="module")
+def cluster():
+    cfg = GigapaxosTpuConfig()
+    cfg.paxos.max_groups = 16
+    for i in range(3):
+        cfg.nodes.actives[f"AR{i}"] = ("127.0.0.1", 0)
+        cfg.nodes.reconfigurators[f"RC{i}"] = ("127.0.0.1", 0)
+    cl = InProcessCluster(cfg, KVApp)
+    client = ReconfigurableAppClient(cfg.nodes)
+    try:
+        assert client.create("staged", timeout=120)["ok"]
+        yield cl, client
+    finally:
+        client.close()
+        cl.close()
+
+
+def test_every_acknowledged_put_adds_one_queue_and_one_commit_sample(cluster):
+    _, client = cluster
+    reg = registry()
+    n = 6
+    snap0 = reg.snapshot()
+    for i in range(n):
+        assert client.request("staged", f"PUT k{i} v{i}".encode(),
+                              timeout=60) == b"OK"
+    snap1 = reg.snapshot()
+    queue = _delta(snap0, snap1, "request_stage_seconds",
+                   plane="ar", stage="queue")
+    commit = _delta(snap0, snap1, "request_stage_seconds",
+                    plane="ar", stage="commit")
+    assert queue[0] == n and commit[0] == n
+    assert queue[1] >= 0 and commit[1] > 0
+    # the control plane carried none of them
+    assert _delta(snap0, snap1, "request_stage_seconds",
+                  plane="rc", stage="commit")[0] == 0
+
+
+def test_queue_plus_commit_lies_inside_the_ars_commit_latency(cluster):
+    """The AR times arrival -> release; staged -> placed -> response held
+    lies inside it, request by request, so also in the sums."""
+    _, client = cluster
+    reg = registry()
+    snap0 = reg.snapshot()
+    for i in range(4):
+        assert client.request("staged", f"PUT q{i} w".encode(),
+                              timeout=60) == b"OK"
+    snap1 = reg.snapshot()
+    ar = _delta(snap0, snap1, "commit_latency_seconds")  # over its nodes
+    queue = _delta(snap0, snap1, "request_stage_seconds",
+                   plane="ar", stage="queue")
+    commit = _delta(snap0, snap1, "request_stage_seconds",
+                    plane="ar", stage="commit")
+    assert ar[0] == queue[0] == commit[0] == 4
+    # each histogram's sum is rounded to a microsecond in the snapshot
+    assert queue[1] + commit[1] <= ar[1] + 3e-6
+
+
+def _tick_until(m: PaxosManager, done: threading.Event, limit: int = 64):
+    for _ in range(limit):
+        m.tick()
+        if done.is_set():
+            break
+    m.drain_pipeline()
+
+
+def test_a_refused_or_expired_request_adds_no_sample():
+    cfg = GigapaxosTpuConfig()
+    cfg.paxos.max_groups = 8
+    plane = "t_stage_refused"
+    m = PaxosManager(cfg, 3, [KVApp() for _ in range(3)], spill_ns=plane)
+    m.create_paxos_instance("a", [0, 1, 2])
+    reg = registry()
+
+    def stages(snap0, snap1):
+        return [_delta(snap0, snap1, "request_stage_seconds", plane=plane,
+                       stage=s)[0] for s in ("queue", "commit")]
+
+    # an unknown name is refused at propose; a request whose deadline has
+    # passed is dropped at intake; one staged for a group that is removed
+    # before the tick drains it fails: none of them is a sample
+    snap0 = reg.snapshot()
+    assert m.propose("nobody", b"PUT k v") is None
+    expired, failed = threading.Event(), threading.Event()
+    seen = []
+    m.propose("a", b"PUT k v", lambda rid, r: (seen.append((rid, r)),
+                                               expired.set()), deadline=1)
+    _tick_until(m, expired)
+    m.create_paxos_instance("gone", [0, 1, 2])
+    m.propose("gone", b"PUT k v", lambda rid, r: (seen.append((rid, r)),
+                                                  failed.set()))
+    m.remove_paxos_instance("gone")
+    _tick_until(m, failed)
+    assert expired.is_set() and failed.is_set()
+    assert all(r is None for _, r in seen)
+    assert stages(snap0, reg.snapshot()) == [0, 0]
+    # ... and the acknowledged one beside them is exactly one of each
+    done = threading.Event()
+    m.propose("a", b"PUT k v", lambda rid, r: done.set())
+    _tick_until(m, done)
+    assert done.is_set()
+    assert stages(snap0, reg.snapshot()) == [1, 1]
+
+
+# ------------------------------------------------------------------ compiles
+def test_the_compile_listener_counts_a_fresh_jit():
+    compiles.install()
+    reg = registry()
+    snap0 = reg.snapshot()
+
+    @jax.jit
+    def never_compiled_before(x):
+        return x * 26 + 2026
+
+    never_compiled_before(jnp.arange(7)).block_until_ready()
+    snap1 = reg.snapshot()
+    for stage in ("trace", "lower", "backend"):
+        count, seconds = _delta(snap0, snap1, "jit_compile_seconds",
+                                stage=stage)
+        assert count >= 1 and seconds > 0, stage
+    # the same shape again compiles nothing
+    never_compiled_before(jnp.arange(7)).block_until_ready()
+    assert _delta(snap1, reg.snapshot(), "jit_compile_seconds")[0] == 0
+
+
+def test_the_compile_listener_is_installed_once_however_many_managers():
+    cfg = GigapaxosTpuConfig()
+    cfg.paxos.max_groups = 8
+    for i in range(2):
+        PaxosManager(cfg, 3, [KVApp() for _ in range(3)],
+                     spill_ns=f"t_listener_{i}")
+    compiles.install()
+    reg = registry()
+    hits0, misses0 = compiles.cache_lookups()
+    # a listener of the test's own counts the same events: the program's
+    # must have seen each once, where a second installation would see it twice
+    seen = []
+
+    def witness(event, duration_secs, **_kw):
+        if event in compiles.STAGE_EVENTS:
+            seen.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(witness)
+    try:
+        snap0 = reg.snapshot()
+
+        @jax.jit
+        def compiled_once(x):
+            return x - 26
+
+        compiled_once(jnp.arange(5)).block_until_ready()
+        snap1 = reg.snapshot()
+    finally:
+        jax.monitoring.unregister_event_duration_listener(witness)
+    assert seen
+    assert _delta(snap0, snap1, "jit_compile_seconds")[0] == len(seen)
+    hits, misses = compiles.cache_lookups()
+    assert hits >= hits0 and misses >= misses0
+
+
+# -------------------------------------------------------------- named scopes
+@pytest.fixture(scope="module")
+def lowered():
+    """Every program that opens a scope, lowered on the CPU with its
+    locations: scope name -> the text its ops' metadata must show it in."""
+    R, W, P, G, Lb = 3, 4, 4, 128, 16
+    E = 2 * G
+
+    def shaped(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+
+    def S(shape, dt=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    state = shaped(jax.eval_shape(lambda: st.init_state(R, G, W)))
+    lease = shaped(jax.eval_shape(lambda: tk.init_lease(G, 8)))
+    health = shaped(jax.eval_shape(lambda: tk.init_health(G)))
+    inbox = tk.TickInbox(S((R, P, G)), S((R, P, G), jnp.bool_),
+                         S((R,), jnp.bool_))
+
+    def text(fn, *args):
+        return fn.lower(*args).as_text(debug_info=True)
+
+    compact = text(tk.paxos_tick_compact, state, inbox, -1, E, Lb)
+    texts = {scope: compact for scope in TICK_SCOPES}
+    texts["lease_fold"] = text(tk.paxos_tick_compact_lease, state, lease,
+                               inbox, -1, E, Lb, 64)
+    texts["health_fold"] = text(
+        tk.paxos_tick_health, state, None, None, None, health, None, inbox,
+        -1, E, Lb, 64, True, 32, 6, 8)
+    texts["sweep_frontier"] = text(tk.sweep_frontier, S((R, G)),
+                                   S((R, G), jnp.bool_), S((R,), jnp.bool_))
+    texts["frontier_rows"] = text(tk.frontier_rows, S((G,)), S((G,)),
+                                  S((G,), jnp.bool_), S((16,)))
+    return texts
+
+
+@pytest.mark.parametrize("scope", TICK_SCOPES)
+def test_each_tick_scope_is_in_the_lowered_programs_op_metadata(lowered,
+                                                                scope):
+    text = lowered[scope]
+    # an op's location is "jit(<program>)/<scope>/<primitive>"
+    assert f')/{scope}/' in text, scope
+
+
+# --------------------------------------------------------- trace annotations
+class FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: no profiler session,
+    which a test under xdist could not own."""
+
+    enabled = True
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.enabled
+
+    def __enter__(self):
+        self.log.append(("open", self.name))
+
+    def __exit__(self, *exc):
+        self.log.append(("close", self.name))
+
+
+@pytest.fixture
+def fake_annotation():
+    FakeAnnotation.enabled, FakeAnnotation.log = True, []
+    return FakeAnnotation
+
+
+def _one_pipelined_tick(pc: PhaseClock) -> None:
+    first, second = PHASE_RUNS["modea"]
+    pc.begin()
+    for phase in first:
+        pc.mark(phase)
+    pc.touch()
+    for phase in second:
+        pc.mark(phase)
+    pc.end()
+
+
+def test_phase_clock_opens_and_closes_one_annotation_per_marked_phase(
+        fake_annotation):
+    pc = PhaseClock("modea", plane="t", reg=Registry(),
+                    annotation=fake_annotation)
+    _one_pipelined_tick(pc)
+    _one_pipelined_tick(pc)
+    want = []
+    for _ in range(2):
+        for phase in DRIVER_PHASES["modea"]:
+            name = annotation_name("modea", "t", phase)
+            assert name == f"gptpu/modea/t/{phase}"
+            want += [("open", name), ("close", name)]
+    assert fake_annotation.log == want
+
+
+def test_phase_clock_emits_nothing_while_no_profile_is_taken(fake_annotation):
+    fake_annotation.enabled = False
+    reg = Registry()
+    pc = PhaseClock("modea", plane="t", reg=reg, annotation=fake_annotation)
+    _one_pipelined_tick(pc)
+    assert fake_annotation.log == []
+    # the histograms are fed all the same
+    assert all(h.count == 1 for h in reg.find("tick_phase_seconds"))
+    # a profile that starts in the middle of a phase opens at the next one,
+    # and one that stops leaves nothing open
+    pc.begin()
+    fake_annotation.enabled = True
+    pc.mark("repair")
+    fake_annotation.enabled = False
+    pc.mark("intake")
+    pc.mark("dispatch")
+    assert fake_annotation.log == [("open", "gptpu/modea/t/intake"),
+                                   ("close", "gptpu/modea/t/intake")]
+
+
+def test_a_driver_without_declared_runs_emits_no_annotation(fake_annotation):
+    pc = PhaseClock("modeb", plane="t", reg=Registry(),
+                    annotation=fake_annotation)
+    pc.begin()
+    for phase in DRIVER_PHASES["modeb"]:
+        pc.mark(phase)
+    pc.touch()
+    pc.end()
+    assert fake_annotation.log == []

@@ -92,7 +92,13 @@ for name, fn, args, want in programs:
     low = fn.lower(*args)
     got = low.as_text().count("@tpu_custom_call")
     assert got == want, f"{name}: {got} Mosaic custom calls, expected {want}"
-    low.compile()
+    hlo = low.compile().as_text()
+    # the named scopes survive XLA:TPU's fusion: the compaction's fusions
+    # still say where they came from, which is what a trace reader splits
+    # the device time by (obs/phase.py TICK_SCOPES)
+    if "compact" in name or "health" in name or "lease" in name:
+        for scope in ("compact_outbox/scatter", "/prepare/", "/accept/"):
+            assert scope in hlo, f"{name}: no op carries {scope!r}"
     print("COMPILED", name, flush=True)
 print("ALL-COMPILED")
 '''

@@ -210,28 +210,20 @@ def _fused_compact_impl(state, kv: DeviceKVState, inbox: TickInbox,
     ([K] i32; rid 0 = empty slot — a fixed-size upload keeps the jit
     signature static).
     """
-    from ..ops.tick import _compact_outbox_impl, paxos_tick_impl
+    from ..ops.tick import (_compact_columns, _compact_outbox_impl,
+                            _exec_mask, paxos_tick_impl)
 
     kv = register_requests(kv, reg_rids, reg_ops, reg_keys, reg_vals)
     new_state, out = paxos_tick_impl(state, inbox, own_row, exec_budget,
                                      fast_elect=fast_elect)
     kv2, responses, miss = kv_apply(kv, out.exec_req, out.exec_count)
     packed = _compact_outbox_impl(out, exec_budget, lag_budget)
-    # responses ride a second scatter with the same ranks as the exec stream
-    R, W, G = out.exec_req.shape
-    ji = jnp.arange(W, dtype=I32)[None, :, None]
-    mask = ji < out.exec_count[:, None, :]
-    mf = mask.reshape(-1)
-    mi = mf.astype(I32)
-    rank = jnp.cumsum(mi) - mi
-    idx = jnp.where(mf, rank, exec_budget)
-    e_resp = jnp.zeros((exec_budget,), I32).at[idx].set(
-        responses.reshape(-1), mode="drop"
-    )
-    e_miss = jnp.zeros((exec_budget,), I32).at[idx].set(
-        miss.astype(I32).reshape(-1), mode="drop"
-    )
-    flat = jnp.concatenate([packed, e_resp, e_miss])
+    # responses ride the exec stream's compaction: same mask, same ranks
+    R, _, G = out.exec_req.shape
+    with jax.named_scope("compact_outbox"):
+        _, extras = _compact_columns(_exec_mask(out).reshape(-1),
+                                     [responses, miss], exec_budget)
+    flat = jnp.concatenate([packed, extras.reshape(-1)])
     # pack/unpack agreement enforced at trace time against the shared
     # layout descriptor (consumers slice via CompactLayout.kv_extras)
     from ..ops.tick import CompactLayout

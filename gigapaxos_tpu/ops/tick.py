@@ -161,6 +161,16 @@ def _lexmax(n, c, axis):
     return jnp.squeeze(nmax, axis=axis), cmax
 
 
+def _select_rows(x, idx):
+    """``take_along_axis(x, clip(idx, 0, R - 1), axis=0)`` for ``[R, G]``
+    operands as an R-way select over the static, small leading axis:
+    elementwise and bandwidth-bound, where XLA:TPU runs the gather element
+    by element (34 ms against 0.24 at R*G = 3M; PERF.md section 6)."""
+    R = x.shape[0]
+    sel = jnp.clip(idx, 0, R - 1)
+    return sum(jnp.where(sel == m, x[m][None, :], 0) for m in range(R))
+
+
 class LeaseState(NamedTuple):
     """Leader-lease columns (ISSUE 17): dense ``[G]`` lease state folded
     inside the fused tick, so grant/renew/expiry piggyback on the
@@ -996,9 +1006,7 @@ def _tick_phases(scope: _PhaseScopes, state, inbox: TickInbox, own_row: int,
     # refuses otherwise); NEG_INF (no eligible donor) fails this too since
     # exec watermarks are never negative
     d_ok = d_exec > post_exec
-    d_status = jnp.take_along_axis(
-        new_state.status, jnp.clip(d_id, 0, post_exec.shape[0] - 1), axis=0
-    )
+    d_status = _select_rows(new_state.status, d_id)
     outbox = TickOutbox(
         exec_req=jnp.where(al3, exec_req_out, NO_REQUEST),
         exec_stop=jnp.where(al3, exec_stop_out, False),
@@ -1201,11 +1209,21 @@ paxos_tick_packed_lease = jax.jit(
 # steady state the host only needs (a) the executed decision stream, whose
 # length the exec budget bounds, (b) which placed intake was taken (P bits
 # per (r, g)), (c) the rare laggards needing checkpoint transfer, and (d)
-# the decision counter.  The device compacts exactly that with an on-device
-# prefix-sum scatter (the TPU-native analog of the reference shipping
-# individual DECISION packets instead of whole acceptor state,
-# PaxosInstanceStateMachine.java:1755-1842), so the device->host transfer is
-# O(decisions), not O(state).
+# the decision counter.  The device compacts exactly that (the TPU-native
+# analog of the reference shipping individual DECISION packets instead of
+# whole acceptor state, PaxosInstanceStateMachine.java:1755-1842).
+#
+# What it costs (PERF.md sections 5 and 6, measured at 1M groups).  On the
+# device: while at most ``compact_blocks()`` entries were decided (8,192 of
+# the 12.6M offered positions, 1,024 laggards), O(K) scatter updates on top
+# of one bandwidth-bound pass over the masks — about 8 ms — through
+# :func:`_compact_columns`' block-sparse branch; above that the dense
+# prefix-sum scatter over the whole plane, which is O(R*W*G) per column
+# whatever was decided (about 330 ms).  The host mirrors which one ran in
+# ``compact_path_ticks_total``.  The transfer is bounded but not
+# O(decisions): the buffer is ``CompactLayout.total_plain`` words however
+# few decided — ``taken_bits`` (R*G words) plus four exec columns of
+# ``exec_budget`` (2G by default) words each, 46 MB a tick at 1M.
 # --------------------------------------------------------------------------
 
 
@@ -1237,63 +1255,123 @@ class CompactHostOutbox(NamedTuple):
     l_lexec: "np.ndarray"  # i32 — the laggard's own post-tick exec watermark
 
 
+#: the block-sparse compaction views a flat mask as rows of one lane row
+_BLOCK = 128
+#: the most hits (hence non-empty blocks) the sparse branch of
+#: :func:`_compact_columns` takes; above it the dense code runs.  Chosen on
+#: the chip (PERF.md section 6): the sparse branch costs what K*_BLOCK
+#: scatter updates cost whatever was decided, the dense one what the whole
+#: plane costs.
+_SPARSE_BLOCKS = 8192
+
+
+def compact_blocks(n: int, capacity: int) -> int:
+    """K of the block-sparse compaction of an ``n``-wide mask into
+    ``capacity`` slots; 0 where the plane is too narrow for it to pay (the
+    compaction is then the dense code alone).  A function of shapes only:
+    the device branches on it and the host mirrors it (:func:`compact_path`)."""
+    k = min(_SPARSE_BLOCKS, capacity)
+    return k if n > k * _BLOCK else 0
+
+
+def compact_path(n: int, capacity: int, count: int) -> str:
+    """Which branch :func:`_compact_columns` took for ``count`` hits."""
+    k = compact_blocks(n, capacity)
+    return "sparse" if k and count <= k else "dense"
+
+
+def _compact_columns(mask_flat, cols, capacity: int):
+    """Compact ``cols`` (each flattened to the mask's width) to the
+    positions where ``mask_flat`` holds, in flat order, zero-filled to
+    ``capacity``; hits past ``capacity`` are dropped.  Returns ``(count,
+    i32 [len(cols), capacity])``.
+
+    One algorithm at two widths, chosen on the device from the mask's
+    popcount.  XLA:TPU runs an element-granular scatter at about 4.6 ns per
+    *offered* update, kept or dropped, so the dense code costs the plane's
+    width per column whatever was decided.  With at most K hits at most K
+    blocks of ``_BLOCK`` are non-empty: the sparse branch row-gathers those
+    blocks of the mask, ranks inside the ``[K, _BLOCK]`` tile, scatters each
+    hit's *source position* to its output slot (the one K*_BLOCK-update
+    scatter) and then gathers K elements per column."""
+    n = mask_flat.shape[0]
+    mi = mask_flat.astype(I32)
+    count = jnp.sum(mi)
+    cols = [c.reshape(-1).astype(I32) for c in cols]
+    K = compact_blocks(n, capacity)
+
+    def dense():
+        rank = jnp.cumsum(mi) - mi
+        idx = jnp.where(mask_flat, rank, capacity)  # -> dropped
+        return jnp.stack([
+            jnp.zeros((capacity,), I32).at[idx].set(c, mode="drop")
+            for c in cols
+        ])
+
+    if not K:
+        return count, dense()
+
+    def sparse():
+        B = _BLOCK
+        nb = -(-n // B)
+        m2 = jnp.pad(mi, (0, nb * B - n)).reshape(nb, B)
+        cnt = jnp.sum(m2, axis=1)  # [nb] hits per block
+        off = jnp.cumsum(cnt) - cnt  # a block's first output slot
+        used = (cnt > 0).astype(I32)
+        ids = jnp.full((K,), nb, I32).at[
+            jnp.where(cnt > 0, jnp.cumsum(used) - used, K)
+        ].set(jnp.arange(nb, dtype=I32), mode="drop")  # non-empty blocks
+        idc = jnp.minimum(ids, nb - 1)
+        tile = jnp.where((ids < nb)[:, None], m2[idc], 0)  # [K, B] row gather
+        slot = off[idc][:, None] + jnp.cumsum(tile, axis=1) - tile
+        pos = idc[:, None] * B + jnp.arange(B, dtype=I32)[None, :]
+        src = jnp.full((K,), n, I32).at[
+            jnp.where(tile > 0, slot, K).reshape(-1)
+        ].set(pos.reshape(-1), mode="drop")  # [K] source of each output
+        hit = src < n
+        srcc = jnp.minimum(src, n - 1)
+        packed = jnp.stack([jnp.where(hit, c[srcc], 0) for c in cols])
+        return jnp.pad(packed, ((0, 0), (0, capacity - K)))
+
+    return count, jax.lax.cond(count <= K, sparse, dense)
+
+
+def _exec_mask(out: TickOutbox):
+    """[R, W, G] lanes of the outbox that hold an execution (post-cap)."""
+    ji = jnp.arange(out.exec_req.shape[1], dtype=I32)[None, :, None]
+    return ji < out.exec_count[:, None, :]
+
+
 @_scoped("compact_outbox")
 def _compact_outbox_impl(out: TickOutbox, exec_budget: int,
                          lag_budget: int) -> jnp.ndarray:
     R, W, G = out.exec_req.shape
     P = out.intake_taken.shape[1]
-    E, Lb = exec_budget, lag_budget
     ji = jnp.arange(W, dtype=I32)[None, :, None]
-    mask = ji < out.exec_count[:, None, :]  # [R, W, G] (post-cap)
-    mf = mask.reshape(-1)
-    mi = mf.astype(I32)
-    rank = jnp.cumsum(mi) - mi
-    idx = jnp.where(mf, rank, E)  # E -> dropped by mode="drop"
-
-    def scat(vals):
-        return jnp.zeros((E,), I32).at[idx].set(
-            vals.reshape(-1).astype(I32), mode="drop"
-        )
-
     slot = out.exec_base[:, None, :] + ji
     rep = jnp.broadcast_to(jnp.arange(R, dtype=I32)[:, None, None], (R, W, G))
     row = jnp.broadcast_to(jnp.arange(G, dtype=I32)[None, None, :], (R, W, G))
     meta = rep | (out.exec_stop.astype(I32) << 8)
-    n_exec = jnp.sum(mi)
+    n_exec, e_cols = _compact_columns(
+        _exec_mask(out).reshape(-1), [out.exec_req, meta, slot, row],
+        exec_budget)
     # intake: P bits per (r, g) — placed-and-taken; host knows what it placed
     pb = jnp.arange(P, dtype=I32)[None, :, None]
     taken_bits = jnp.sum(out.intake_taken.astype(I32) << pb, axis=1)  # [R,G]
     # laggards needing checkpoint transfer (lag >= W): compacted pair list
-    lmask = (out.lag >= W).reshape(-1)
-    li = lmask.astype(I32)
-    lrank = jnp.cumsum(li) - li
-    lidx = jnp.where(lmask, lrank, Lb)
     rep2 = jnp.broadcast_to(jnp.arange(R, dtype=I32)[:, None], (R, G))
     row2 = jnp.broadcast_to(jnp.arange(G, dtype=I32)[None, :], (R, G))
-
-    def lscat(vals):
-        return jnp.zeros((Lb,), I32).at[lidx].set(
-            vals.reshape(-1), mode="drop"
-        )
-
-    header = jnp.stack([
-        n_exec,
-        jnp.sum(out.decided_now),
-        jnp.sum(li),
-    ]).astype(I32)
+    lag_n, l_cols = _compact_columns(
+        (out.lag >= W).reshape(-1),
+        [rep2, row2, out.donor, out.donor_exec, out.donor_status,
+         out.exec_base + out.exec_count],  # last: laggard's post-tick exec
+        lag_budget)
+    header = jnp.stack([n_exec, jnp.sum(out.decided_now), lag_n]).astype(I32)
     return jnp.concatenate([
         header,
         taken_bits.reshape(-1),
-        scat(out.exec_req),
-        scat(meta),
-        scat(slot),
-        scat(row),
-        lscat(rep2),
-        lscat(row2),
-        lscat(out.donor),
-        lscat(out.donor_exec),
-        lscat(out.donor_status),
-        lscat(out.exec_base + out.exec_count),  # laggard's post-tick exec
+        e_cols.reshape(-1),
+        l_cols.reshape(-1),
     ])
 
 

@@ -41,6 +41,9 @@ consuming unchecked shard_map outputs downstream in the same jit returned
 wrong values.  Under jax 0.9.0 a fused tick + compaction gave the identical
 buffer on a 4-device virtual CPU mesh, for one and two replica shards; the
 two-dispatch structure stays until ROADMAP C1 folds the entry points.)
+The compaction is ``tk._compact_columns``, shared with the single-device
+programs: both its branches partition to the one-device buffer
+(tests/test_compact_sparse.py, on the virtual CPU mesh).
 Cost: one extra dispatch per tick; the outbox intermediate stays
 device-resident and sharded either way.
 """

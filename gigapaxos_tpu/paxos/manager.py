@@ -54,9 +54,9 @@ from .paystore import PayloadStore
 from ..ops.pallas_gather import check_lanes
 from ..ops.tick import (LP_ASN, LP_EPOCH, LP_HOLDER, LP_UNTIL, LP_WAIT,
                         CompactHostOutbox, HostOutbox, TickInbox,
-                        frontier_rows, health_clear_rows, init_health,
-                        lease_clear_rows, merge_compact_outbox, merge_health,
-                        merge_outbox, paxos_tick_compact,
+                        compact_path, frontier_rows, health_clear_rows,
+                        init_health, lease_clear_rows, merge_compact_outbox,
+                        merge_health, merge_outbox, paxos_tick_compact,
                         paxos_tick_compact_demand, paxos_tick_compact_lease,
                         paxos_tick_health, paxos_tick_mixed_compact,
                         paxos_tick_mixed_compact_lease,
@@ -484,6 +484,16 @@ class PaxosManager:
                      "placed->response held (commit)",
                 plane=spill_ns, stage=stage)
             for stage in ("queue", "commit"))
+        #: which branch the device's compaction took for each list, one
+        #: increment per compaction; mirrored from the header this loop
+        #: reads anyway through the rule the device used (compact_path)
+        self._compact_path_c = {
+            (lst, path): _obs_registry().counter(
+                "compact_path_ticks_total",
+                help="outbox compactions by list and the branch the device "
+                     "took (block-sparse, or dense over the whole plane)",
+                plane=spill_ns, list=lst, path=path)
+            for lst in ("exec", "lag") for path in ("sparse", "dense")}
         # compiles and cache lookups inside the served path are metrics
         # from the first manager of the process on
         _compiles.install()
@@ -2356,12 +2366,15 @@ class PaxosManager:
                 co_r = unpack_compact(np.asarray(packed[1]), self.R,
                                       self.G_reg, self._exec_budget,
                                       self._lag_budget)
+                self._count_compact_paths(co_l, self.W, self.G)
+                self._count_compact_paths(co_r, 1, self.G_reg)
                 out = merge_compact_outbox(co_l, co_r, self.G)
                 flat = None
             else:
                 flat = np.asarray(packed)
                 out = unpack_compact(flat, self.R, self.G,
                                      self._exec_budget, self._lag_budget)
+                self._count_compact_paths(out, self.W, self.G)
             e_resp = e_miss = None
             if self._device_app:
                 # extras sliced through the shared layout descriptor —
@@ -2406,6 +2419,13 @@ class PaxosManager:
             self.pause_idle()
         pc.mark("sweep")
         return out
+
+    def _count_compact_paths(self, co, W: int, G: int) -> None:
+        """One compacted plane's two lists -> ``compact_path_ticks_total``."""
+        for lst, n, cap, count in (
+                ("exec", self.R * W * G, self._exec_budget, co.n_exec),
+                ("lag", self.R * G, self._lag_budget, co.lag_n)):
+            self._compact_path_c[lst, compact_path(n, cap, count)].inc()
 
     @_locked
     def drain_pipeline(self) -> None:

@@ -124,6 +124,8 @@ WIRING = {
     "tick_seconds": "gigapaxos_tpu/obs/phase.py",
     # where a request's time goes and what stalls a tick (ISSUE 26)
     "request_stage_seconds": "gigapaxos_tpu/paxos/manager.py",
+    # which branch the device's outbox compaction took (ISSUE 27)
+    "compact_path_ticks_total": "gigapaxos_tpu/paxos/manager.py",
     "jit_compile_seconds": "gigapaxos_tpu/obs/compiles.py",
     "compile_cache_lookups_total": "gigapaxos_tpu/obs/compiles.py",
     "wal_fsync_seconds": "gigapaxos_tpu/wal/logger.py",

@@ -334,3 +334,57 @@ def test_a_driver_without_declared_runs_emits_no_annotation(fake_annotation):
     pc.touch()
     pc.end()
     assert fake_annotation.log == []
+
+
+# ------------------------------------------- which compaction branch ran
+def _counter(snap: dict, family: str, **labels) -> float:
+    want = {f"{k}={v}" for k, v in labels.items()}
+    return sum(val for key, val in snap.items()
+               if key.partition("{")[0] == family
+               and want <= set(key.partition("{")[2].rstrip("}").split(",")))
+
+
+@pytest.mark.parametrize("k_blocks", [None, 2])
+def test_compact_path_counter_rises_once_per_list_per_tick(monkeypatch,
+                                                           k_blocks):
+    """``compact_path_ticks_total`` mirrors, from the header alone, the rule
+    the device branched on: with the served K a test-sized plane is dense
+    from its shape; with K lowered to 2 a tick is ``sparse`` exactly while
+    ``n_exec <= K``."""
+    if k_blocks is not None:
+        monkeypatch.setattr(tk, "_SPARSE_BLOCKS", k_blocks)
+    cfg = GigapaxosTpuConfig()
+    cfg.paxos.max_groups = 128
+    cfg.paxos.compact_outbox = True
+    cfg.paxos.pipeline_ticks = False
+    plane = f"t_compact_path_{k_blocks}"
+    m = PaxosManager(cfg, 3, [KVApp() for _ in range(3)], spill_ns=plane)
+    names = [f"g{i}" for i in range(4)]
+    for name in names:
+        m.create_paxos_instance(name, [0, 1, 2])
+    want = {(lst, path): 0 for lst in ("exec", "lag")
+            for path in ("sparse", "dense")}
+    reg = registry()
+    snap0 = reg.snapshot()
+    ticks = 8
+    for t in range(ticks):
+        if t == 1:  # one tick decides four requests: 12 executions > K = 2
+            for name in names:
+                m.propose(name, b"PUT k v", lambda *a: None)
+        out = m.tick()
+        k = tk.compact_blocks(m.R * m.W * m.G, m._exec_budget)
+        want["exec", "sparse" if k and out.n_exec <= k else "dense"] += 1
+        kl = tk.compact_blocks(m.R * m.G, m._lag_budget)
+        want["lag", "sparse" if kl and out.lag_n <= kl else "dense"] += 1
+    snap1 = reg.snapshot()
+    got = {key: _counter(snap1, "compact_path_ticks_total", plane=plane,
+                         list=key[0], path=key[1])
+           - _counter(snap0, "compact_path_ticks_total", plane=plane,
+                      list=key[0], path=key[1]) for key in want}
+    assert got == want
+    assert sum(v for (lst, _), v in got.items() if lst == "exec") == ticks
+    assert sum(v for (lst, _), v in got.items() if lst == "lag") == ticks
+    if k_blocks is None:
+        assert got["exec", "sparse"] == got["lag", "sparse"] == 0
+    else:  # both branches were met
+        assert got["exec", "sparse"] > 0 and got["exec", "dense"] > 0

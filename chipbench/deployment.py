@@ -81,6 +81,33 @@ def populate(cluster, n: int) -> list:
     return names
 
 
+def warm_sweep_buckets(m, up_to: int) -> int:
+    """Compile, in set-up, every row-count bucket the payload sweep of plane
+    ``m`` can meet in a window.
+
+    Every 64 ticks ``PaxosManager._frontier_gather`` gathers the rows that
+    hold outstanding records through ``ops.tick.frontier_rows``, padded to a
+    power of two from 16 up: one program per bucket, compiled on first use on
+    the tick thread under the manager's lock.  The warm-up's sweep meets one
+    bucket; a window's sweep that meets its neighbour stalls the plane for
+    the compile (0.26 s from the persistent cache at 1M groups, 1 traced run
+    in 3: my chip runs, PR 29), inside the window.  This makes the same calls
+    on the plane's own frontier, so the program finds each bucket in its jit
+    cache.  Returns the number of buckets."""
+    import jax
+    from gigapaxos_tpu.ops import tick as tk
+
+    with m.lock:  # the tick donates the state it is given
+        fr = tk.sweep_frontier(m.state.exec_slot, m.state.member,
+                               m.alive.copy())
+        outs, k = [], 16
+        while k <= up_to:
+            outs.append(tk.frontier_rows(*fr, np.zeros(k, np.int32)))
+            k *= 2
+        jax.block_until_ready(outs)
+    return len(outs)
+
+
 def replica_tables(cluster, service: str) -> list:
     """The app state every replica holds for one service name."""
     return [dict(app.db.get(f"{service}#0", {}))

@@ -10,8 +10,10 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import faulthandler
+import gc
 import json
 import os
+import resource
 import shutil
 import sys
 import tempfile
@@ -33,6 +35,17 @@ TRACE_SLICE_S = 5.0
 WARMUP_MAX_S = 150.0
 #: lead between the last set-up step and the window's first due instant
 LEAD_S = 0.05
+#: the payload sweep's row buckets are compiled in set-up up to this many rows
+#: (``deployment.warm_sweep_buckets``): the outstanding records of 8 s of
+#: backlog at 1,000 req/s; a run that holds more has stalled for other reasons
+SWEEP_ROWS_MAX = 8192
+#: tick counts on whose multiples a plane does periodic work, for the window
+#: diagnostics only: the payload sweep (``PaxosManager._sweep_every``) and
+#: ``pause_idle`` (``tick_num % 256`` in ``PaxosManager._complete_tick``)
+PERIODIC_TICKS = (64, 256)
+#: the two planes of a Mode A node, data then control, as the program's own
+#: labels name them
+PLANES = ("ar", "rc")
 
 
 _T0 = time.monotonic()
@@ -128,7 +141,7 @@ def warm_up(cell, gen, seed: int, m, client, names: list, actives: list):
 
 def _install_recorders(cluster) -> list:
     recorders = []
-    for plane, m in (("ar", cluster.manager), ("rc", cluster.rc_manager)):
+    for plane, m in zip(PLANES, (cluster.manager, cluster.rc_manager)):
         inner = getattr(m, "_pc", None)
         if inner is None or not hasattr(inner, "mark"):
             continue
@@ -170,11 +183,18 @@ class _TickTimeline:
     """Diagnostics under ``CHIPBENCH_KEEP=<dir>``: both planes' tick numbers
     sampled every 5 ms through the window and the drain, saved with every
     request's due, sent and done instants, for a look by hand at which ticks
-    carried which requests.  Not part of any metric."""
+    carried which requests, and the instant and length of every collection of
+    the collector's oldest generation (a ``gc.callbacks`` entry that reads the
+    clock; it changes nothing the collector does).  Not part of any metric."""
 
-    def __init__(self, cluster):
-        self._managers = (cluster.manager, cluster.rc_manager)
+    def __init__(self, managers: tuple):
+        self._managers = managers
         self._rows: list = []
+        #: (start, seconds) of every collection of the oldest
+        #: generation: it stops every thread of the process while it runs
+        self.collections: list = []
+        self._gc_t = 0.0
+        gc.callbacks.append(self._on_gc)
         self._stop = threading.Event()
         self._thread = threading.Thread(target=self._sample, daemon=True,
                                         name="chipbench-timeline")
@@ -185,13 +205,107 @@ class _TickTimeline:
             self._rows.append((time.monotonic(),
                                *(m.tick_num for m in self._managers)))
 
-    def save(self, path: str, window) -> None:
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if info["generation"] < 2:
+            return
+        if phase == "start":
+            self._gc_t = time.monotonic()
+        else:
+            self.collections.append((self._gc_t, time.monotonic() - self._gc_t))
+
+    def stop(self) -> list:
+        """End the sampling; the rows (time, data tick, control tick)."""
         self._stop.set()
         self._thread.join()
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        return self._rows
+
+    def save(self, path: str, window) -> None:
+        self.stop()
         os.makedirs(os.path.dirname(path), exist_ok=True)
         np.savez_compressed(path, ticks=np.array(self._rows), t0=window.t0,
+                            collections=np.array(self.collections),
                             due=window.due, sent=window.sent, done=window.done,
                             status=window.status, entry=window.sched.entry)
+
+
+def window_diagnostics(ticks0: tuple, ticks1: tuple, window_s: float,
+                       gc0: list, gc1: list,
+                       every: tuple = PERIODIC_TICKS) -> dict:
+    """Where a run's window lay on each plane's tick count, for stderr and
+    the sets' ``.jsonl`` (never a metric): the tick numbers at its two ends
+    (data plane, control plane), the period over it (window / ticks
+    completed: ``readers/tick_period``'s arithmetic on the managers' own
+    counts), how many multiples of each of ``every`` fell inside (``first <
+    t <= last``: the program does periodic work on such ticks), and from
+    ``gc.get_stats()`` at both ends the collections of each generation."""
+    out: dict = {"gc_collections": [b["collections"] - a["collections"]
+                                    for a, b in zip(gc0, gc1)]}
+    for plane, first, last in zip(PLANES, ticks0, ticks1):
+        out[plane] = {"tick0": int(first), "tick1": int(last),
+                      "period_ms": 1e3 * window_s / (last - first)
+                      if last > first else None}
+        for n in every:
+            out[plane][f"multiples_of_{n}"] = int(last // n - first // n)
+    return out
+
+
+def host_usage() -> dict:
+    """This process's ``getrusage`` counters, for ``host_in_window``."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return {"user_s": ru.ru_utime, "sys_s": ru.ru_stime,
+            "minor_faults": ru.ru_minflt, "major_faults": ru.ru_majflt,
+            "waits": ru.ru_nvcsw, "preempted": ru.ru_nivcsw}
+
+
+def host_in_window(u0: dict, u1: dict) -> dict:
+    """What the host did for this process between two ``host_usage()``
+    readings: CPU seconds in user and kernel mode (all threads), page faults,
+    voluntary and involuntary context switches (the second count rises where
+    other work takes the cores; the chip tool's sandbox reports the CPU
+    seconds and zeros).  For the question why one process of a seed ticks
+    some per cent slower than the next; never a metric."""
+    return {k: (round(u1[k] - u0[k], 3) if k.endswith("_s") else u1[k] - u0[k])
+            for k in u0}
+
+
+def compiles_in_window(snap0: dict, snap1: dict) -> dict:
+    """What compiled between the two registry snapshots, traced run or not:
+    the observations of ``jit_compile_seconds`` (trace, lower and backend
+    stages together; a persistent-cache hit still traces and lowers) and
+    their seconds.  ``compile_free_pct`` reads the same in a traced run."""
+    from .readers import histogram_mean
+
+    shim = Run(None, {}, None, 0.0, snap0, snap1, None)
+    w = histogram_mean.window(shim, "jit_compile_seconds")
+    return {"n": int(w[0]), "s": float(w[1])} if w else {"n": 0, "s": 0.0}
+
+
+def timeline_diagnostics(rows: list, collections: list, t0: float,
+                         seconds: float) -> dict:
+    """What only the ``CHIPBENCH_KEEP`` timeline shows of the window ``t0``
+    to ``t0 + seconds``: per plane the longest and the median gap between two
+    ticks, how many gaps were over 1.5 medians with the time they held beyond
+    one, and where the longest lay; and the time the collections of the
+    oldest generation took (each stops every thread of the process)."""
+    took = [1e3 * d for t, d in collections if t0 <= t <= t0 + seconds]
+    out: dict = {"gc_oldest_ms": {"n": len(took), "sum": float(sum(took)),
+                                  "longest": float(max(took, default=0.0))}}
+    a = np.asarray(rows, dtype=np.float64).reshape(-1, 1 + len(PLANES))
+    a = a[(a[:, 0] >= t0) & (a[:, 0] <= t0 + seconds)]
+    for i, plane in enumerate(PLANES):
+        at = a[1:, 0][np.diff(a[:, 1 + i]) > 0]  # instants a tick ended
+        gaps = np.diff(at) * 1e3
+        if not gaps.size:
+            continue
+        med = float(np.median(gaps))
+        long = gaps[gaps > 1.5 * med]
+        out[plane] = {"longest_gap_ms": float(gaps.max()),
+                      "median_gap_ms": med, "long_gaps": int(long.size),
+                      "long_gaps_excess_ms": float((long - med).sum()),
+                      "longest_gap_at_s": float(at[int(gaps.argmax())] - t0)}
+    return out
 
 
 def _collect_writes(loads: list, names: list) -> tuple:
@@ -284,6 +398,10 @@ def run(args, t_start: float) -> dict:
         client = ReconfigurableAppClient(cfg.nodes)
 
         warm = warm_up(cell, gen, args.seed, m, client, names, actives)
+        t = time.monotonic()
+        n = deployment.warm_sweep_buckets(m, SWEEP_ROWS_MAX)
+        note(f"sweep: {n} row buckets up to {SWEEP_ROWS_MAX} compiled in "
+             f"{time.monotonic() - t:.2f}s")
         align_planes(cluster)
 
         # ---- the window
@@ -293,21 +411,26 @@ def run(args, t_start: float) -> dict:
         if args.trace:
             recorders = _install_recorders(cluster)
         reg = registry()
+        usage0 = host_usage()
         t0 = time.monotonic() + LEAD_S
         setup_s = t0 - t_start
         sender = threading.Thread(target=window.offer, args=(client, t0),
                                   name="chipbench-generator")
         time.sleep(max(0.0, t0 - time.monotonic()))
+        planes = (m, cluster.rc_manager)
         snap0, t_snap0 = reg.snapshot(), time.monotonic()
+        ticks0, gc0 = tuple(p.tick_num for p in planes), gc.get_stats()
         sender.start()
         keep = os.environ.get("CHIPBENCH_KEEP")
-        timeline = _TickTimeline(cluster) if keep else None
+        timeline = _TickTimeline(planes) if keep else None
         sync_perf_ns = None
         trace_dir = os.path.join(run_dir, "trace")
         if args.trace:
             sync_perf_ns = _trace_slice(t0, args.seconds, trace_dir)
         time.sleep(max(0.0, t0 + args.seconds - time.monotonic()))
         snap1, t_snap1 = reg.snapshot(), time.monotonic()
+        ticks1, gc1 = tuple(p.tick_num for p in planes), gc.get_stats()
+        usage1 = host_usage()
         sender.join()
         answered = window.wait_replies(client.default_deadline_s)
         if timeline is not None:
@@ -319,6 +442,15 @@ def run(args, t_start: float) -> dict:
                                np.ones(len(window.due), bool), args.seconds)
         note(f"window: {e2e['attempted']} due, {e2e['by_status']}, all "
              f"answered: {answered}; data-plane tick {m.tick_num}")
+        diag = window_diagnostics(ticks0, ticks1, t_snap1 - t_snap0, gc0, gc1)
+        diag["compiles"] = compiles_in_window(snap0, snap1)
+        diag["host"] = host_in_window(usage0, usage1)
+        if timeline is not None:
+            kept = timeline_diagnostics(timeline.stop(), timeline.collections,
+                                        t0, args.seconds)
+            for key, value in kept.items():
+                diag.setdefault(key, {}).update(value)
+        note("diag: " + json.dumps(diag))
 
         problems = check(cell, cluster, client, [warm, window], names,
                          actives, args.seed)
@@ -337,6 +469,10 @@ def run(args, t_start: float) -> dict:
                                      t_snap1 - t_snap0, snap0, snap1, None),
                          trace_dir, [r for _, r in recorders], sync_perf_ns,
                          m, rehearsal)
+        # what `correct` compared, each number beside its limit; last in the line
+        result["compared"] = {
+            "wrong_answers": {"value": len(problems), "limit": 0},
+            "requests_due": {"value": e2e["attempted"], "limit": ">0"}}
         return result
     finally:
         if client is not None:
@@ -408,5 +544,8 @@ def main(argv, t_start: float) -> int:
     faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
     result = run(args, t_start)
     faulthandler.cancel_dump_traceback_later()
+    for name, c in result["compared"].items():
+        print(f"compared: {name} {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0

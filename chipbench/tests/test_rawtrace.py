@@ -165,9 +165,12 @@ def test_the_scopes_split_the_programs_time_and_add_up_to_it(recorded):
     compact = spec.layer_metric("compact_device_ms")["args"]
     protocol = spec.layer_metric("protocol_device_ms")["args"]
     assert compact["module"] == protocol["module"] == TICK
-    n, c_ms, c_beside, c_none, bare = trace_scope_ms.split_ms(recorded, **compact)
-    n2, p_ms, p_beside, p_none, _ = trace_scope_ms.split_ms(recorded, **protocol)
+    n, c_ms, c_beside, c_none, bare, held = trace_scope_ms.split_ms(
+        recorded, **compact)
+    n2, p_ms, p_beside, p_none, _, _ = trace_scope_ms.split_ms(
+        recorded, **protocol)
     assert n == n2 == 2                      # the edges are left out
+    assert held == 0                         # PR 26's program had no conditional
     assert c_beside == p_ms and p_beside == c_ms and c_none == p_none
     whole = [d for _, _, d in recorded.modules["/device:TPU:0"][1:-1]]
     program_ms = sum(whole) / len(whole) / 1e6
@@ -181,6 +184,39 @@ def test_the_scopes_split_the_programs_time_and_add_up_to_it(recorded):
     assert c_none == pytest.approx(8.62, abs=0.01)
     assert max(bare, key=bare.get).startswith("%reduce-window")
     assert trace_scope_ms.split_ms(recorded, "^jit_other", ["x"], []) is None
+
+
+def test_an_op_that_holds_other_ops_is_counted_in_no_sum():
+    """PR 27's tick program: the trace lists each ``conditional`` beside the
+    ops of the branch it ran (ISSUE 29).  Only leaves are counted, so the
+    three parts add up to the execution again and the scopes' own sums are
+    what they were."""
+    plane = "/device:TPU:0"
+    mods = [("jit__paxos_tick(1)", t, 100.0) for t in (0.0, 100.0, 200.0, 300.0)]
+
+    def ops(with_conditional: bool) -> list:
+        out = []
+        for t0 in (100.0, 200.0):        # the two whole executions
+            out += [("%fusion.1", "jit(f)/tally/max:", t0, 30.0),
+                    ("%copy.2", "", t0 + 30.0, 10.0)]
+            if with_conditional:
+                out.append(("%cond.8.clone", "", t0 + 40.0, 50.0))
+            out += [("%fusion.3", "jit(f)/compact_outbox/scatter:", t0 + 41.0, 40.0),
+                    ("%copy.4", "", t0 + 81.0, 8.0),
+                    ("%fusion.5", "jit(f)/tally/sum:", t0 + 90.0, 10.0)]
+        return out
+
+    args = dict(module=TICK, scopes=["compact_outbox"], beside=["tally"])
+    plain = trace_scope_ms.split_ms(
+        rawtrace.RawTrace({plane: ops(False)}, {plane: mods}, []), **args)
+    held = trace_scope_ms.split_ms(
+        rawtrace.RawTrace({plane: ops(True)}, {plane: mods}, []), **args)
+    n, inside, beside, none, bare, holders = held
+    assert (n, holders) == (2, pytest.approx(50e-6)) and plain[5] == 0
+    assert held[:5] == plain[:5]         # what the reader returns did not move
+    assert inside == pytest.approx(40e-6) and beside == pytest.approx(40e-6)
+    assert none == pytest.approx(18e-6) and set(bare) == {"%copy.2", "%copy.4"}
+    assert inside + beside + none == pytest.approx(98e-6)   # of a 100 ns program
 
 
 def test_the_two_metric_files_cover_the_programs_vocabulary():

@@ -30,8 +30,25 @@ def test_rehearsal_prints_the_contracts_line(tmp_path, trace):
                GPTPU_PALLAS_INTERPRET="1")
     assert out.returncode == 0, out.stderr[-3000:]
     line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "compared"]
     assert line["correct"] is True
+    assert line["compared"] == {
+        "wrong_answers": {"value": 0, "limit": 0},
+        "requests_due": {"value": 3000, "limit": ">0"}}
+    assert out.stderr.strip().splitlines()[-2:] == [
+        "compared: wrong_answers 0 (limit 0)",
+        "compared: requests_due 3000 (limit >0)"]
+    from chipbench import measure
+
+    diag = measure.diag_of(out.stderr)   # the line measure.py copies to a set
+    assert diag["ar"]["tick1"] > diag["ar"]["tick0"] > 0
+    assert diag["rc"]["period_ms"] > 0 and len(diag["gc_collections"]) == 3
+    # nothing compiles inside the window (the sweep's row buckets were warmed
+    # in set-up), and the host's counters over it are there
+    assert diag["compiles"] == {"n": 0, "s": 0.0}
+    assert diag["host"]["user_s"] > 0 and diag["host"]["minor_faults"] >= 0
+    assert "sweep: 10 row buckets up to 8192 compiled" in out.stderr
     assert line["attempted"] == 3000 and line["failed"] == 0
     assert set(line["device"]) == {"platform", "kind", "count",
                                    "memory_peak_bytes"}
@@ -61,3 +78,50 @@ def test_a_cpu_without_the_switch_prints_no_result(tmp_path):
     assert "not a TPU" in out.stderr
     out = _run(REHEARSE + ["--seconds", "1"], tmp_path)
     assert out.returncode != 0 and out.stdout.strip() == ""
+
+
+#: the run with the served path broken underneath: ``KVApp.execute`` is what
+#: produces every answer and every stored value of the timed path
+BROKEN = """
+import sys, time
+T = time.monotonic()
+sys.path.insert(0, ".")
+from gigapaxos_tpu.models import replicable
+plain = replicable.KVApp.execute
+first = []
+def execute(self, name, request, request_id):
+    out = plain(self, name, request, request_id)
+    first.append(self) if not first else None
+    if request.startswith(b"PUT") and hash(request) % 50 == 0:
+        if FAULT == "reply":
+            return b"KO"                   # the answer, altered where produced
+        if FAULT == "stored" and self is first[0]:
+            self.db[name]["k"] = "altered"  # one replica holds another value
+        if FAULT == "lost":
+            del self.db[name]["k"]          # acknowledged, held by nobody
+    return out
+replicable.KVApp.execute = execute
+from chipbench import harness
+sys.exit(harness.main(sys.argv[1:], T))
+"""
+
+
+@pytest.mark.parametrize("fault", ["reply", "stored", "lost"])
+def test_a_run_whose_served_path_is_broken_is_not_correct(tmp_path, fault):
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith(("GPTPU_", "CHIPBENCH_"))}
+    env.update(JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path), PYTHONHASHSEED="0",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               CHIPBENCH_REHEARSAL="1", GPTPU_PALLAS="1",
+               GPTPU_PALLAS_INTERPRET="1")
+    out = subprocess.run(
+        [sys.executable, "-c", BROKEN.replace("FAULT", repr(fault)), *REHEARSE,
+         "--seed", "3000000012", "--seconds", "2", "--trace", "0"],
+        cwd=ROOT, env=env, text=True, capture_output=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    assert line["correct"] is False and line["attempted"] == 2000
+    wrong = line["compared"]["wrong_answers"]
+    assert wrong["limit"] == 0 and wrong["value"] >= 10
+    assert f"compared: wrong_answers {wrong['value']} (limit 0)" in out.stderr
+    assert "WRONG: " in out.stderr

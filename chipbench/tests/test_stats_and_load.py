@@ -112,3 +112,111 @@ def test_offer_stops_when_told_and_wait_gives_up_at_the_deadline():
     assert not ld.wait_replies(0.05)
     for t in client.timers:
         t.cancel()
+
+
+# ------------------------------------------- the two spread rules of measure.py
+@pytest.mark.parametrize("values, spread, why", [
+    # one far-off run is left out: 100..104 is the side's range
+    ([100.0, 101.0, 102.0, 103.0, 104.0, 150.0], 4.0 / 102.5, "far-off run"),
+    # the same run on the low side
+    ([50.0, 100.0, 101.0, 102.0, 103.0, 104.0], 4.0 / 101.5, "far-off low run"),
+    # two runs share the far end: leaving one out does not narrow the range
+    ([100.0, 100.0, 102.0, 103.0, 105.0, 105.0], 5.0 / 102.5, "no narrowing"),
+    # both ends as far from the median: the one whose absence narrows more
+    ([90.0, 95.0, 100.0, 101.0, 102.0, 111.0], 12.0 / 100.5, "tie"),
+    ([500.0, 520.0], 20.0 / 510.0, "two values: nothing is left out"),
+    ([512.3], 0.0, "one value"),
+    ([], 0.0, "no value"),
+    # ledger PR 28, the parent's six runs of commit_p95_ms: a range of 42.9 ms
+    # over a median of 698.2 ms reads 6.1%, past the bound of 6%
+    ([670.0, 690.0, 697.4, 699.0, 712.9, 760.0], 42.9 / 698.2, "ledger PR 28"),
+])
+def test_driver_spread_leaves_out_the_run_farthest_from_the_median(
+        values, spread, why):
+    from chipbench import measure
+
+    assert measure.driver_spread(values) == pytest.approx(spread), why
+    assert measure.driver_spread(values[::-1]) == pytest.approx(spread), why
+    if why == "ledger PR 28":
+        ratio = measure.driver_spread(values) / 0.06
+        assert round(100 * measure.driver_spread(values), 1) == 6.1
+        assert ratio > 1 and measure.verdict(ratio).startswith("UNRESOLVED")
+        # the quartile rule reads the same six runs differently
+        assert measure.spread(values) != pytest.approx(spread)
+
+
+@pytest.mark.parametrize("values, spread, why", [
+    # the far-off run is left out, then the quartiles of the other five
+    ([100.0, 101.0, 102.0, 103.0, 104.0, 150.0], 3.0 / 102.5, "far-off run"),
+    ([50.0, 100.0, 101.0, 102.0, 103.0, 104.0], 3.0 / 101.5, "far-off low run"),
+    ([500.0, 520.0], 30.0 / 510.0, "two values: the quartile rule as it is"),
+    ([512.3], 0.0, "one value"),
+    # the check that refused PR 29 read 31.4628 ms of a median 517.809 ms
+    # against a bound of 6%: over half of it
+    ([500.0, 500.0, 504.1552, 531.4628, 531.4628, 600.0], 31.4628 / 517.809,
+     "PR 29's refusal"),
+])
+def test_check_spread_is_the_quartile_rule_without_the_farthest_run(
+        values, spread, why):
+    from chipbench import measure
+
+    assert measure.check_spread(values) == pytest.approx(spread), why
+    assert measure.check_spread(values[::-1]) == pytest.approx(spread), why
+    if why == "PR 29's refusal":
+        assert measure.check_spread(values) / 0.06 > 0.5
+
+
+def test_measure_reports_both_rules_against_the_benchmarks_bounds(capsys):
+    from chipbench import measure
+
+    bound_of = measure.bounds()
+    assert set(bound_of) >= {"commit_p50_ms", "commit_p95_ms", "goodput_ops",
+                             "setup_s"}
+    p95 = [670.0, 690.0, 697.4, 699.0, 712.9, 760.0]
+    lines = [{"metrics": {"commit_p95_ms": {"value": v, "unit": "ms"},
+                          "extra": {"value": 1.0, "unit": "x"}}} for v in p95]
+    med, spr, drv, n, chk = measure.summarise(lines)["commit_p95_ms"]
+    assert chk == pytest.approx(measure.check_spread(p95))
+    assert (med, n) == (pytest.approx(698.2), 6)
+    assert spr == pytest.approx(measure.spread(p95))
+    assert drv == pytest.approx(42.9 / 698.2)
+    measure.report("set", lines, {"commit_p95_ms": 0.06})
+    out = capsys.readouterr().out.splitlines()
+    assert "driver_spread 6.14%" in out[0] and "bound 6%" in out[0]
+    assert "driver_spread/bound 1.02 (UNRESOLVED" in out[0]
+    assert "bound" not in out[1]          # a metric with no bound: spreads only
+    assert measure.verdict(0.5) == "ok" and "half" in measure.verdict(0.51)
+    assert measure.diag_of("x\n[ 1.0s] diag: {\"ar\": {\"tick0\": 3}}\ny") == {
+        "ar": {"tick0": 3}}
+    assert measure.diag_of("nothing here") is None
+
+
+def test_window_diagnostics_counts_the_periodic_ticks_and_the_gaps():
+    from chipbench import harness
+
+    gen = [{"collections": 10}, {"collections": 4}, {"collections": 1}]
+    end = [{"collections": 90}, {"collections": 11}, {"collections": 3}]
+    d = harness.window_diagnostics((250, 60), (350, 130), 20.0, gen, end)
+    assert d["ar"] == {"tick0": 250, "tick1": 350, "period_ms": 200.0,
+                       "multiples_of_64": 2, "multiples_of_256": 1}
+    assert d["rc"]["multiples_of_64"] == 2 and d["rc"]["multiples_of_256"] == 0
+    assert d["gc_collections"] == [80, 7, 2]
+    idle = harness.window_diagnostics((5, 5), (5, 9), 1.0, gen, gen)
+    assert idle["ar"]["period_ms"] is None and idle["rc"]["period_ms"] == 250.0
+    # a timeline sampled every 10 ms: the data plane ticks every 100 ms but
+    # for one gap of 400 ms, the control plane never; of the oldest
+    # generation's collections only those that began inside the window count
+    t = np.arange(0.0, 3.0, 0.01)
+    ends = np.array([0.1, 0.2, 0.3, 0.4, 0.8, 0.9, 1.0, 1.1, 1.2, 1.3])
+    rows = [(10.0 + x, int((ends <= x + 1e-9).sum()), 0) for x in t]
+    k = harness.timeline_diagnostics(
+        rows, [(9.5, 0.2), (10.5, 0.15), (11.5, 0.05), (12.5, 0.3)], 10.0, 2.0)
+    assert k["ar"] == {"longest_gap_ms": pytest.approx(400.0),
+                       "median_gap_ms": pytest.approx(100.0), "long_gaps": 1,
+                       "long_gaps_excess_ms": pytest.approx(300.0),
+                       "longest_gap_at_s": pytest.approx(0.4)}
+    assert "rc" not in k
+    assert k["gc_oldest_ms"] == {"n": 2, "sum": pytest.approx(200.0),
+                                 "longest": pytest.approx(150.0)}
+    assert harness.timeline_diagnostics([], [], 10.0, 2.0) == {
+        "gc_oldest_ms": {"n": 0, "sum": 0.0, "longest": 0.0}}

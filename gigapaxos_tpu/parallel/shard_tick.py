@@ -26,31 +26,52 @@ the (replica, groups) mesh:
   which exchanges a tiny [W, R] count block (see ``group_axis`` in
   ``paxos_tick_impl``).
 * With ``replica_shards == 1`` (the v5e-4 deployment shape: 4 chips on the
-  groups axis) the gathers degenerate to no-ops and the program is pure
+  groups axis) the gathers degenerate to no-ops and the tick program is pure
   data-parallel with zero collectives in the hot phases.
 
-Outbox pack / compaction stays OUTSIDE the shard_map (global-view GSPMD):
-the compact prefix-scatter is a global cumsum over all groups, and keeping
-it global means ``CompactLayout`` / ``unpack_compact`` and the whole host
-loop are byte-compatible with the single-device path.  It runs as a SECOND
-jit dispatch, not fused into the tick program: the tick's outputs cross the
-dispatch boundary as ordinary committed sharded arrays, and the GSPMD
-pack/compact program over them is verified bit-identical in
-tests/test_sharding_stack.py.  (The split dates from a jax release on which
+A tick of a sharded plane is TWO dispatches (three with the placement fold;
+the manager counts them in ``mesh_dispatches_total{plane,program}``), and the
+jitted functions carry the names a trace finds them by:
+
+``jit_mesh_paxos_tick``
+    the ``shard_map`` above.  Its ops keep the tick body's
+    ``jax.named_scope`` names (``obs/phase.py`` TICK_SCOPES) behind a
+    ``shard_map`` component: ``jit(mesh_paxos_tick)/shard_map/accept/...``.
+``jit_mesh_compact_outbox``
+    outbox compaction, OUTSIDE the shard_map as a global-view GSPMD program
+    over the sharded outbox: the compact prefix-scatter ranks executions
+    across ALL groups, and keeping it global means ``CompactLayout`` /
+    ``unpack_compact`` and the whole host loop are byte-compatible with the
+    single-device path.  It is ``tk._compact_columns``, shared with the
+    single-device programs: both its branches partition to the one-device
+    buffer (tests/test_compact_sparse.py, on the virtual CPU mesh; through
+    the manager in tests/test_obs_request_stages.py).
+``jit_mesh_demand_fold``
+    the placement plane's demand EWMA, only with ``cfg.placement.enabled``.
+
+The one-device programs' names (``jit__paxos_tick*``) match none of these,
+so a metric that reads them reads nothing on a mesh, and the other way round.
+
+What the two-dispatch structure costs was measured on a v5e-4 host at 1M
+groups (PERF.md sections 5 and 6, ``probe-1m-mesh4-open1k``): the tick
+program is the cheap half, and the compaction is most of the device time of
+a tick, because the partitioner all-gathers its full-width ``[R, W, G]``
+operands onto every chip before the rank (ROADMAP A8).  The host pays more
+than the device: the ``dispatch`` phase commits a numpy ``[R, P, G]`` inbox
+to four devices' layout every tick.  The tick's outputs cross the dispatch
+boundary as ordinary committed sharded arrays and stay device-resident.
+
+Why two programs and not one: the split dates from a jax release on which
 consuming unchecked shard_map outputs downstream in the same jit returned
 wrong values.  Under jax 0.9.0 a fused tick + compaction gave the identical
 buffer on a 4-device virtual CPU mesh, for one and two replica shards; the
-two-dispatch structure stays until ROADMAP C1 folds the entry points.)
-The compaction is ``tk._compact_columns``, shared with the single-device
-programs: both its branches partition to the one-device buffer
-(tests/test_compact_sparse.py, on the virtual CPU mesh).
-Cost: one extra dispatch per tick; the outbox intermediate stays
-device-resident and sharded either way.
+structure stays until ROADMAP C1 folds the entry points, and fusing would
+not remove the all-gathers.  The demand fold is still a program of its own
+for a fault that jax 0.9.0 has not been shown free of (see
+``make_shardmap_tick_compact``).
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import numpy as np
@@ -69,6 +90,11 @@ _REPLICA_LED = tuple(
     f for f, spec in _STATE_SPECS.items()
     if len(spec) and spec[0] == REPLICA_AXIS
 )
+
+#: the programs a sharded plane's tick may enqueue, as the manager counts them
+#: (``mesh_dispatches_total{program=}``) and as the trace names them:
+#: ``jit_mesh_paxos_tick``, ``jit_mesh_compact_outbox``, ``jit_mesh_demand_fold``
+MESH_PROGRAMS = ("tick", "compact", "fold")
 
 _RWG = P(REPLICA_AXIS, None, GROUPS_AXIS)
 _RG = P(REPLICA_AXIS, GROUPS_AXIS)
@@ -112,7 +138,7 @@ def shard_tick_body(mesh: Mesh, own_row: int = -1, exec_budget: int = 0):
     gs = mesh.shape[GROUPS_AXIS]
     group_axis = GROUPS_AXIS if gs > 1 else None
 
-    def body(state, inbox):
+    def mesh_paxos_tick(state, inbox):
         if rs > 1:
             def ag(x):
                 return jax.lax.all_gather(x, REPLICA_AXIS, axis=0, tiled=True)
@@ -146,7 +172,7 @@ def shard_tick_body(mesh: Mesh, own_row: int = -1, exec_budget: int = 0):
         return new, out
 
     return jax.shard_map(
-        body,
+        mesh_paxos_tick,
         mesh=mesh,
         in_specs=(PaxosState(**_STATE_SPECS), TickInbox(**_INBOX_SPECS)),
         out_specs=(PaxosState(**_STATE_SPECS), TickOutbox(**_OUTBOX_SPECS)),
@@ -180,6 +206,17 @@ def fetch_host_outbox(out: TickOutbox) -> "tk.HostOutbox":
     return tk.HostOutbox(*(np.asarray(f) for f in out))
 
 
+def make_mesh_compact(exec_budget: int, lag_budget: int):
+    """The jitted global-view compaction of a sharded TickOutbox (donated):
+    the second dispatch of a mesh tick, ``jit_mesh_compact_outbox`` in a
+    trace."""
+    def mesh_compact_outbox(out):
+        return tk._compact_outbox_impl(out, exec_budget=exec_budget,
+                                       lag_budget=lag_budget)
+
+    return jax.jit(mesh_compact_outbox, donate_argnums=(0,))
+
+
 def make_shardmap_tick_compact(mesh: Mesh, own_row: int, exec_budget: int,
                                lag_budget: int, demand_decay=None):
     """shard_map tick + budgeted on-device compaction (O(budget) transfer).
@@ -200,15 +237,8 @@ def make_shardmap_tick_compact(mesh: Mesh, own_row: int, exec_budget: int,
     ``fn(state, inbox, demand) -> (state, flat, new_demand)``.
     """
     tick = make_shardmap_tick(mesh, own_row, exec_budget)
+    compact = make_mesh_compact(exec_budget, lag_budget)
     if demand_decay is None:
-        compact = jax.jit(
-            functools.partial(
-                tk._compact_outbox_impl,
-                exec_budget=exec_budget, lag_budget=lag_budget,
-            ),
-            donate_argnums=(0,),
-        )
-
         def fn(state, inbox):
             state, out = tick(state, inbox)
             return state, compact(out)
@@ -216,6 +246,7 @@ def make_shardmap_tick_compact(mesh: Mesh, own_row: int, exec_budget: int,
         return fn
 
     decay = float(demand_decay)
+
     # the fold is a SEPARATE dispatch from the compaction, not fused: adding
     # the P(groups)-sharded demand operand/output to the compact jit changes
     # the partitioner's sharding assignment and the flat buffer comes back
@@ -224,18 +255,10 @@ def make_shardmap_tick_compact(mesh: Mesh, own_row: int, exec_budget: int,
     # fusion).  The fold is elementwise over two P(groups) arrays — no
     # reductions for the partitioner to mangle — and it reads
     # ``decided_now`` BEFORE the compact dispatch donates the outbox.
-    compact = jax.jit(
-        functools.partial(
-            tk._compact_outbox_impl,
-            exec_budget=exec_budget, lag_budget=lag_budget,
-        ),
-        donate_argnums=(0,),
-    )
-
-    def _fold(decided_now, demand):
+    def mesh_demand_fold(decided_now, demand):
         return decay * demand + decided_now.astype(demand.dtype)
 
-    fold = jax.jit(_fold, donate_argnums=(1,))
+    fold = jax.jit(mesh_demand_fold, donate_argnums=(1,))
 
     def fn3(state, inbox, demand):
         state, out = tick(state, inbox)

@@ -34,6 +34,19 @@ log = logging.getLogger(__name__)
 FATAL_HANDLER: Optional[Callable[[BaseException], None]] = None
 
 
+#: an idle plane's probe ticks take at most this share of its time.  A tick
+#: costs what the plane is wide, not what it carries (100 ms of host and
+#: device at 1M groups), and the planes of one process share the interpreter
+#: and the device: an idle plane probing every ``idle_sleep_s`` ran flat out
+#: there and took from the busy one what the start-up's race gave it
+#: (PERF.md section 6, PR 30).  Work still starts a tick within
+#: ``idle_sleep_s``: the wait polls ``pending_count()`` at that period.
+IDLE_DUTY = 0.25
+#: and the longest an idle plane sleeps between probes, whatever a tick cost
+#: (its first one compiles): timers that count ticks keep moving
+IDLE_WAIT_MAX_S = 0.5
+
+
 class PlaneDown(RuntimeError):
     """A plane did not come up: its first tick raised, or never finished
     within the start-up timeout."""
@@ -113,6 +126,19 @@ class TickDriver:
         if drain is not None:
             drain()
 
+    def _idle_wait(self, tick_s: float) -> None:
+        """Sleep between an idle plane's probe ticks: ``idle_sleep_s`` at
+        least, and as long as keeps the probes to ``IDLE_DUTY`` of the
+        plane's time given what the last one cost; a kick or newly pending
+        work ends it at once."""
+        until = time.monotonic() + min(
+            IDLE_WAIT_MAX_S, tick_s * (1.0 / IDLE_DUTY - 1.0))
+        while not self._kick.wait(timeout=self.idle_sleep_s):
+            if (time.monotonic() >= until
+                    or self.manager.pending_count() > 0):
+                break
+        self._kick.clear()
+
     def _run(self) -> None:
         drain = self.drain_ticks
         lock = getattr(self.manager, "lock", None)
@@ -129,6 +155,7 @@ class TickDriver:
                 if gap > 0:
                     time.sleep(gap)  # coalesce: let requests accumulate
                 last = time.monotonic()
+            t_tick = time.monotonic()
             try:
                 self.manager.tick()
             except Exception as e:
@@ -147,6 +174,7 @@ class TickDriver:
                 if handler is not None:
                     handler(e)
                 return
+            tick_s = time.monotonic() - t_tick
             if self.first_tick_s is None:
                 self.first_tick_s = time.monotonic() - started
             self._first_tick.set()
@@ -170,8 +198,7 @@ class TickDriver:
                 # decided_now needs a device sync; only check when draining
                 drain -= 1
                 if drain <= 0:
-                    self._kick.wait(timeout=self.idle_sleep_s)
-                    self._kick.clear()
+                    self._idle_wait(tick_s)
                     drain = 1  # idle wake: one probe tick, drain more if busy
             else:
                 drain = self.drain_ticks

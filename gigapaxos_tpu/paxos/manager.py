@@ -448,6 +448,9 @@ class PaxosManager:
         # zeroed lazily at the next build instead of reallocating R*P*G
         self._in_req = np.zeros((self.R, self.P, self.G_total), np.int32)
         self._in_stp = np.zeros((self.R, self.P, self.G_total), bool)
+        #: the two copies of them that ticks are handed in turn, made at the
+        #: first build (see _build_inbox)
+        self._in_handed: list = []
         self._placed: list = []
         #: pipelined mode: (outbox, placed) of the last dispatched tick,
         #: consumed at the start of the next (SURVEY §2.2 item 3)
@@ -494,6 +497,19 @@ class PaxosManager:
                      "took (block-sparse, or dense over the whole plane)",
                 plane=spill_ns, list=lst, path=path)
             for lst in ("exec", "lag") for path in ("sparse", "dense")}
+        #: programs a sharded plane's ticks enqueued (parallel/shard_tick.py):
+        #: one tick is two dispatches, three with the placement fold
+        self._mesh_dispatch_c = {}
+        if self.mesh is not None:
+            from ..parallel.shard_tick import MESH_PROGRAMS
+
+            self._mesh_dispatch_c = {
+                prog: _obs_registry().counter(
+                    "mesh_dispatches_total",
+                    help="programs enqueued by the ticks of a plane whose "
+                         "state is sharded over a device mesh",
+                    plane=spill_ns, program=prog)
+                for prog in MESH_PROGRAMS}
         # compiles and cache lookups inside the served path are metrics
         # from the first manager of the process on
         _compiles.install()
@@ -1965,10 +1981,23 @@ class PaxosManager:
                 placed.append((row, take))
         self._placed = placed
         self._place_bulk(req, stp, placed)
-        # hand the jit fresh copies (the staging buffers get mutated next
-        # tick; a zero-copy dispatch aliasing them would race the async
-        # step); the WAL reads inbox.alive without a device round-trip
-        return TickInbox(req.copy(), stp.copy(), self.alive.copy())
+        # hand the jit copies (the staging buffers get mutated next tick; a
+        # zero-copy dispatch aliasing them would race the async step); the
+        # WAL reads inbox.alive without a device round-trip.  Two copies
+        # taken in turn, not fresh ones: at most one tick is in flight when
+        # the next is built (_pending_out), so the copy handed out two
+        # builds ago has been consumed, and a fresh [R, P, G] array is
+        # memory the kernel pages in anew every tick: most of this phase
+        # at 1M groups, and what a process pays for a page differs two- to
+        # threefold between processes (PERF.md section 6, PR 30).
+        if not self._in_handed:
+            self._in_handed = [(np.empty_like(req), np.empty_like(stp))
+                               for _ in range(2)]
+        out_req, out_stp = self._in_handed[0]
+        self._in_handed.reverse()
+        np.copyto(out_req, req)
+        np.copyto(out_stp, stp)
+        return TickInbox(out_req, out_stp, self.alive.copy())
 
     def _place_bulk(self, req, stp, placed) -> None:
         """Vectorized placement of the bulk queue into the staging arrays:
@@ -2208,10 +2237,14 @@ class PaxosManager:
                                             self._demand_dev)
                 )
                 self._placement.adopt_device(self._demand_dev)
+                self._mesh_dispatch_c["fold"].inc()
             else:
                 self.state, packed = self._mesh_tick_compact(self.state, inbox)
+            self._mesh_dispatch_c["tick"].inc()
+            self._mesh_dispatch_c["compact"].inc()
         elif self._mesh_tick is not None:
             self.state, packed = self._mesh_tick(self.state, inbox)
+            self._mesh_dispatch_c["tick"].inc()
         elif self._use_compact:
             if self._lease is not None and self.rstate is not None:
                 # lease twin of the mixed compact tick: both planes fold
@@ -3102,6 +3135,13 @@ class PaxosManager:
         n = sum(len(q) for q in self._queues.values()) + len(self._staged)
         n += int(self._bulk_leftover.size)
         n += sum(len(c) for c in self._bulk_chunks)
-        if self._pending_out is not None:
-            n += 1  # a pipelined outbox still needs a tick to complete
+        if self._pending_out is not None and (
+                self.outstanding or self._held_callbacks
+                or (self.bulk is not None and self.bulk.n_live)):
+            # a pipelined outbox somebody waits on needs a tick to complete.
+            # Every tick leaves one behind, so counting it whatever it holds
+            # kept a pipelined plane "busy" for good: the driver never
+            # backed off, and the full-width control plane ticked flat out
+            # beside the data plane (PERF.md section 6, PR 30)
+            n += 1
         return n

@@ -92,6 +92,38 @@ def test_tick_scopes_are_the_scopes_of_the_tick_programs():
     assert len(banners) == 9, banners
 
 
+def test_the_mesh_programs_run_the_scoped_code_under_their_own_names():
+    """parallel/shard_tick.py, statically: a sharded plane's tick is the
+    scoped tick body inside the ``shard_map`` and the scoped compaction as
+    the second program, so every op of both carries a TICK_SCOPES name; it
+    opens no scope of its own (a reader would not know it); the jitted
+    functions carry the names the trace readers match
+    (``jit_mesh_paxos_tick``, ``jit_mesh_compact_outbox``), which the
+    one-device programs' pattern ``^jit__?paxos_tick`` must not; and the
+    manager counts one dispatch per program it enqueues, under the
+    vocabulary the module declares."""
+    src = _src("gigapaxos_tpu/parallel/shard_tick.py")
+    assert "tk.paxos_tick_impl(" in src and "tk._compact_outbox_impl(" in src
+    assert "named_scope(" not in src and "_scoped(" not in src
+    tick = _src("gigapaxos_tpu/ops/tick.py")
+    assert re.search(r'@_scoped\("compact_outbox"\)\ndef _compact_outbox_impl',
+                     tick)
+    named = re.findall(r"^\s*def (mesh_[a-z_]+)\(", src, re.M)
+    assert named == ["mesh_paxos_tick", "mesh_compact_outbox",
+                     "mesh_demand_fold"]
+    for fn in named:
+        assert re.search(rf"(jax\.jit|jax\.shard_map)\(\s*{fn}\b", src), fn
+        assert not re.match(r"^jit__?paxos_tick", f"jit_{fn}")
+    from gigapaxos_tpu.parallel.shard_tick import MESH_PROGRAMS
+
+    assert MESH_PROGRAMS == ("tick", "compact", "fold")
+    counted = re.findall(r'_mesh_dispatch_c\["([a-z]+)"\]\.inc\(\)',
+                         _src(DRIVER_FILES["modea"]))
+    assert set(counted) == set(MESH_PROGRAMS)
+    # the full-outbox mesh branch dispatches the tick alone
+    assert sorted(counted) == ["compact", "fold", "tick", "tick"]
+
+
 def test_wal_fsync_goes_through_instrumented_sync_only():
     """Every durability point must flow through ``_sync`` (timed +
     stall-counted); a bare ``journal.sync()`` anywhere else is an
@@ -126,6 +158,8 @@ WIRING = {
     "request_stage_seconds": "gigapaxos_tpu/paxos/manager.py",
     # which branch the device's outbox compaction took (ISSUE 27)
     "compact_path_ticks_total": "gigapaxos_tpu/paxos/manager.py",
+    # how many programs a sharded plane's tick enqueued (ISSUE 30)
+    "mesh_dispatches_total": "gigapaxos_tpu/paxos/manager.py",
     "jit_compile_seconds": "gigapaxos_tpu/obs/compiles.py",
     "compile_cache_lookups_total": "gigapaxos_tpu/obs/compiles.py",
     "wal_fsync_seconds": "gigapaxos_tpu/wal/logger.py",

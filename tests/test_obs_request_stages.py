@@ -8,6 +8,7 @@ so every assertion here is on the difference of two snapshots, on a plane
 label of the test's own where a manager is built directly.
 """
 
+import re
 import threading
 
 import jax
@@ -251,6 +252,46 @@ def test_each_tick_scope_is_in_the_lowered_programs_op_metadata(lowered,
     assert f')/{scope}/' in text, scope
 
 
+#: the scopes of the two programs a sharded plane's tick dispatches
+#: (parallel/shard_tick.py): the tick body's, and the compaction's
+MESH_SCOPES = {"mesh_paxos_tick": TICK_SCOPES[:TICK_SCOPES.index("lease_fold")],
+               "mesh_compact_outbox": ("compact_outbox",)}
+
+
+@pytest.fixture(scope="module")
+def mesh_compiled():
+    """The two mesh programs compiled for four virtual CPU devices: program
+    -> optimized HLO text, whose ``op_name`` is what a device trace shows."""
+    from gigapaxos_tpu.parallel import mesh as pmesh, shard_tick as stk
+
+    R, W, P, G, Lb = 3, 4, 4, 512, 16
+    mesh = pmesh.make_mesh(jax.devices()[:4], replica_shards=1)
+    state = st.init_state(R, G, W, shardings=pmesh.state_shardings(mesh))
+    inbox = tk.make_inbox(R, G, P)
+    tick = stk.make_shardmap_tick(mesh, -1, 2 * G)
+    out = jax.eval_shape(tick, state, inbox)[1]
+    return {
+        "mesh_paxos_tick": tick.lower(state, inbox).compile().as_text(),
+        "mesh_compact_outbox": stk.make_mesh_compact(2 * G, Lb).lower(
+            out).compile().as_text()}
+
+
+@pytest.mark.parametrize("program,scope", [
+    (program, scope) for program, scopes in MESH_SCOPES.items()
+    for scope in scopes])
+def test_each_mesh_program_has_its_name_and_keeps_the_tick_scopes(
+        mesh_compiled, program, scope):
+    """The trace readers find the mesh's programs by name
+    (``jit_mesh_paxos_tick``, ``jit_mesh_compact_outbox``: not the one-device
+    programs' ``jit__paxos_tick*``) and split their ops by the same scopes,
+    which survive inside the ``shard_map`` body and under GSPMD."""
+    text = mesh_compiled[program]
+    assert text.startswith(f"HloModule jit_{program},"), text[:80]
+    assert not re.match(r"HloModule jit__?paxos_tick", text)
+    inside = "/shard_map" if program == "mesh_paxos_tick" else ""
+    assert f'op_name="jit({program}){inside}/{scope}/' in text, scope
+
+
 # --------------------------------------------------------- trace annotations
 class FakeAnnotation:
     """Stands in for ``jax.profiler.TraceAnnotation``: no profiler session,
@@ -344,21 +385,28 @@ def _counter(snap: dict, family: str, **labels) -> float:
                and want <= set(key.partition("{")[2].rstrip("}").split(",")))
 
 
+@pytest.mark.parametrize("mesh_devices", [0, 4])
 @pytest.mark.parametrize("k_blocks", [None, 2])
 def test_compact_path_counter_rises_once_per_list_per_tick(monkeypatch,
-                                                           k_blocks):
+                                                           k_blocks,
+                                                           mesh_devices):
     """``compact_path_ticks_total`` mirrors, from the header alone, the rule
     the device branched on: with the served K a test-sized plane is dense
     from its shape; with K lowered to 2 a tick is ``sparse`` exactly while
-    ``n_exec <= K``."""
+    ``n_exec <= K``.  The same through the manager with the group axis
+    sharded over four devices, where the compaction is the mesh tick's
+    second dispatch: both branches, and the answers of the one-device run."""
     if k_blocks is not None:
         monkeypatch.setattr(tk, "_SPARSE_BLOCKS", k_blocks)
     cfg = GigapaxosTpuConfig()
-    cfg.paxos.max_groups = 128
+    cfg.paxos.max_groups = 512 if mesh_devices else 128
     cfg.paxos.compact_outbox = True
     cfg.paxos.pipeline_ticks = False
-    plane = f"t_compact_path_{k_blocks}"
-    m = PaxosManager(cfg, 3, [KVApp() for _ in range(3)], spill_ns=plane)
+    cfg.paxos.mesh_devices = mesh_devices
+    plane = f"t_compact_path_{k_blocks}_{mesh_devices}"
+    apps = [KVApp() for _ in range(3)]
+    m = PaxosManager(cfg, 3, apps, spill_ns=plane)
+    assert (m.mesh is not None) == bool(mesh_devices)
     names = [f"g{i}" for i in range(4)]
     for name in names:
         m.create_paxos_instance(name, [0, 1, 2])
@@ -388,3 +436,8 @@ def test_compact_path_counter_rises_once_per_list_per_tick(monkeypatch,
         assert got["exec", "sparse"] == got["lag", "sparse"] == 0
     else:  # both branches were met
         assert got["exec", "sparse"] > 0 and got["exec", "dense"] > 0
+    # whichever branch compacted them, the four requests were executed
+    assert [a.db for a in apps] == [{name: {"k": "v"} for name in names}] * 3
+    programs = {prog: _counter(snap1, "mesh_dispatches_total", plane=plane,
+                               program=prog) for prog in ("tick", "compact")}
+    assert programs == dict.fromkeys(programs, ticks if mesh_devices else 0)

@@ -93,6 +93,127 @@ def test_driver_stop_drains_pending():
         wal.close()
 
 
+def test_idle_pipelined_plane_is_not_pending():
+    """Every pipelined tick leaves an outbox behind for the next one to
+    complete.  It counts as pending work only while somebody waits on it:
+    counted always, it kept an idle plane "busy" for good and its driver
+    never backed off."""
+    with tempfile.TemporaryDirectory() as tmp:
+        m, wal, _ = make_manager(tmp)
+        m.run_ticks(3)
+        assert m._pending_out is not None
+        assert m.pending_count() == 0
+        got = []
+        m.propose("svc", b"PUT a 1", lambda rid, r: got.append(r))
+        seen = []
+        for _ in range(8):
+            if got:
+                break
+            seen.append(m.pending_count())
+            m.tick()
+        assert got == [b"OK"] and seen and all(n > 0 for n in seen), seen
+        m.tick()
+        assert m.pending_count() == 0
+        wal.close()
+
+
+@pytest.mark.parametrize("pipeline", [True, False])
+def test_inbox_copies_are_handed_in_turn_and_outlive_their_tick(pipeline):
+    """``_build_inbox`` hands the tick one of two resident copies of the
+    staging arrays, not a fresh one: the copy a tick got stays as it was
+    through the next build (its program may still be reading it), never
+    aliases the staging arrays, and comes back two builds later."""
+    import numpy as np
+
+    with tempfile.TemporaryDirectory() as tmp:
+        m, wal, _ = make_manager(tmp, pipeline=pipeline)
+        got = []
+        m.propose("svc", b"PUT a 1", lambda rid, r: got.append(r))
+        a = m._build_inbox()
+        a_req = np.array(a.req)
+        assert a_req.any() and not np.shares_memory(a.req, m._in_req)
+        m.propose("svc", b"PUT b 2", lambda rid, r: got.append(r))
+        b = m._build_inbox()
+        assert not np.shares_memory(a.req, b.req)
+        assert not np.shares_memory(a.stop, b.stop)
+        assert (np.asarray(a.req) == a_req).all()  # untouched by the build
+        c = m._build_inbox()
+        assert np.shares_memory(c.req, a.req)
+        assert np.shares_memory(c.stop, a.stop)
+        assert (np.asarray(c.req) == m._in_req).all()
+        wal.close()
+    # and the served path on them answers as before
+    with tempfile.TemporaryDirectory() as tmp:
+        m, wal, apps = make_manager(tmp, pipeline=pipeline)
+        got = {}
+        rids = []
+        for i in range(12):
+            rids.append(m.propose("svc", f"PUT k{i} v{i}".encode(),
+                                  lambda rid, r: got.__setitem__(rid, r)))
+            m.tick()
+        m.run_ticks(6)
+        m.drain_pipeline()
+        assert all(got.get(rid) == b"OK" for rid in rids)
+        assert m.stats["executions"] == 12 * 3
+        wal.close()
+
+
+class _SlowIdlePlane:
+    """What TickDriver needs of a manager, with a tick that costs
+    ``tick_s`` whatever it carries (a plane of a million groups)."""
+
+    def __init__(self, tick_s):
+        self.tick_s = tick_s
+        self.work = 0
+        self.ticks = []  # (start instant, work taken)
+        self.cfg = None
+
+    def tick(self):
+        import time
+
+        taken, self.work = self.work, 0
+        self.ticks.append((time.monotonic(), taken))
+        time.sleep(self.tick_s)
+
+    def pending_count(self):
+        return self.work
+
+
+@pytest.mark.parametrize("tick_s, idle_sleep_s", [(0.03, 0.002),
+                                                  (0.002, 0.02)])
+def test_idle_driver_backs_off_to_its_duty_and_wakes_for_work(tick_s,
+                                                              idle_sleep_s):
+    """An idle plane's probe ticks take at most ``IDLE_DUTY`` of its time
+    (and come no oftener than ``idle_sleep_s``); pending work starts a
+    tick within the polling period, not after the back-off."""
+    import time
+
+    from gigapaxos_tpu.paxos import driver as drv
+
+    plane = _SlowIdlePlane(tick_s)
+    d = TickDriver(plane, idle_sleep_s=idle_sleep_s, drain_ticks=1).start()
+    try:
+        assert d.wait_ready(10)
+        time.sleep(0.2)  # past the drain
+        n0, t0 = len(plane.ticks), time.monotonic()
+        time.sleep(1.0)
+        n = len(plane.ticks) - n0
+        period = max(idle_sleep_s + tick_s, tick_s / drv.IDLE_DUTY)
+        assert 1 <= n <= (time.monotonic() - t0) / period + 1, (n, period)
+        plane.work = 7
+        asked = time.monotonic()
+        deadline = asked + 5
+        while plane.work and time.monotonic() < deadline:
+            time.sleep(0.001)
+        started, taken = plane.ticks[-1]
+        assert taken == 7
+        # within a probe in flight plus a few polls (a loaded test host)
+        assert started - asked < tick_s + 10 * idle_sleep_s + 0.05, \
+            started - asked
+    finally:
+        d.stop()
+
+
 def test_sync_due_tick_still_returns_outbox():
     """A tick whose top-of-tick laggard sync drains the pipeline must hand
     the drained outbox to the caller, not swallow it: callers polling

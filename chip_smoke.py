@@ -334,16 +334,28 @@ def _wave(log: Log, cluster, what: str, rows, payloads,
         if sum(b[0] for b in batches) >= n:
             done.set()
 
-    tick0, t0 = m.tick_num, time.monotonic()
+    def sides() -> dict:
+        """The plane's dispatched ticks by where their outbox was completed
+        (``pipeline_ticks`` is on: a tick may hold it for the next call)."""
+        from gigapaxos_tpu.obs.metrics import registry
+
+        snap = registry().snapshot()  # the cluster's data plane is "ar"
+        return {mode: snap.get(
+            "tick_completions_total{mode=%s,plane=ar}" % mode, 0)
+            for mode in ("same_call", "held")}
+
+    tick0, t0, sides0 = m.tick_num, time.monotonic(), sides()
     rids = m.propose_bulk(rows, payloads, batch_sink=sink)
     check((rids > 0).all(), f"{what}: {int((rids <= 0).sum())} of {n} "
           f"requests not admitted")
     cluster.driver.kick()
     check(done.wait(timeout_s), f"{what}: {sum(b[0] for b in batches)} of "
           f"{n} completed within {timeout_s:.0f}s")
+    took = {mode: int(v - sides0[mode]) for mode, v in sides().items()}
     log(f"{what}: {n:,} requests admitted at tick {tick0}, completed in "
         f"{time.monotonic() - t0:.2f}s by tick {m.tick_num}, in "
-        f"{len(batches)} completion batch(es) (size, tick) {batches[:4]}")
+        f"{len(batches)} completion batch(es) (size, tick) {batches[:4]}; "
+        f"ticks since by where their outbox was completed: {took}")
     # completions fire once per entry replica; all from one tick's pass =
     # decided and executed by one tick, hence admitted by one
     ticks = {t for _, t in batches}

@@ -71,9 +71,13 @@ class PaxosTuning:
     spill_dir: str = ""
     spill_cache: int = 4096
     # Pipelined ticks (SURVEY §2.2 item 3, the BatchedLogger/RequestBatcher
-    # stage overlap): process tick N-1's decision stream (host app
-    # execution) while the device computes tick N and the WAL drains.
-    # Costs one tick of response latency; checkpoints drain synchronously.
+    # stage overlap): a tick MAY HOLD its outbox for the next call, which
+    # then processes tick N-1's decision stream (host app execution) while
+    # the device computes tick N and the WAL drains.  Holding costs one
+    # tick of response latency and buys ticks per second, so a tick holds
+    # only when its inbox left work behind that another tick has to place;
+    # otherwise it completes its outbox itself, as with the option off.
+    # Checkpoints drain synchronously.
     pipeline_ticks: bool = False
     # Compacted outbox: the device prefix-sum-compacts the executed
     # decision stream to O(decisions) instead of shipping the full

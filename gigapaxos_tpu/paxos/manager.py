@@ -30,6 +30,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -452,12 +453,18 @@ class PaxosManager:
         #: first build (see _build_inbox)
         self._in_handed: list = []
         self._placed: list = []
-        #: pipelined mode: (outbox, placed) of the last dispatched tick,
-        #: consumed at the start of the next (SURVEY §2.2 item 3)
+        #: pipelined mode: what _complete_tick needs of a dispatched tick
+        #: whose outbox was held, consumed by the next call (SURVEY §2.2
+        #: item 3); None after a tick completed in its own call
         self._pending_out = None
-        #: completed outbox stashed by drain_pipeline() for the next tick()
-        #: to return (sync-due ticks must not swallow an outbox)
-        self._drained_out = None
+        #: outboxes completed without being returned (by drain_pipeline(),
+        #: or ahead of a newer one in the same call), oldest first, for the
+        #: next tick() calls that complete none: a caller polling tick()
+        #: misses none
+        self._unreturned = collections.deque()
+        #: whether the last _build_inbox left work behind that only another
+        #: tick can place: what a pipelined tick holds its outbox for
+        self._backlog = False
         #: lock-free propose staging (drained at each tick; deque append/
         #: popleft are thread-safe) + a tiny rid-assignment lock that never
         #: contends with the tick
@@ -487,6 +494,22 @@ class PaxosManager:
                      "placed->response held (commit)",
                 plane=spill_ns, stage=stage)
             for stage in ("queue", "commit"))
+        #: dispatched ticks by where their outbox was completed: in the call
+        #: that dispatched them, or held for the next (pipeline_ticks)
+        self._completions_c = {
+            mode: _obs_registry().counter(
+                "tick_completions_total",
+                help="dispatched ticks by the call that completed their "
+                     "outbox: the one that dispatched them, or the next",
+                plane=spill_ns, mode=mode)
+            for mode in ("same_call", "held")}
+        #: the part of "tally" that is blocked until the program's outbox is
+        #: ready, before the pull
+        self._device_wait_h = _obs_registry().histogram(
+            "tick_device_wait_seconds",
+            help="a tick's completion blocked until its outbox is ready on "
+                 "the device, before the pull",
+            plane=spill_ns)
         #: which branch the device's compaction took for each list, one
         #: increment per compaction; mirrored from the header this loop
         #: reads anyway through the rule the device used (compact_path)
@@ -1947,6 +1970,7 @@ class PaxosManager:
             self._bulk_placed = None
         now = time.perf_counter()  # one read for all of this tick's placements
         placed = []
+        backlog = False
         for row, q in self._queues.items():
             used = collections.Counter()
             take = []
@@ -1979,8 +2003,12 @@ class PaxosManager:
                     self.reqtrace.event(rid, "placed", tick=self.tick_num)
             if take:
                 placed.append((row, take))
+            if q and len(take) == self.P:
+                backlog = True  # more for this row than one tick takes
         self._placed = placed
         self._place_bulk(req, stp, placed)
+        self._backlog = bool(backlog or self._bulk_leftover.size
+                             or self._bulk_chunks)
         # hand the jit copies (the staging buffers get mutated next tick; a
         # zero-copy dispatch aliasing them would race the async step); the
         # WAL reads inbox.alive without a device round-trip.  Two copies
@@ -2174,8 +2202,16 @@ class PaxosManager:
     @_locked
     def tick(self):
         """One manager step.  Returns the tick's :class:`HostOutbox` (full
-        mode) / :class:`CompactHostOutbox` (compact mode); in pipelined mode
-        the return is the PREVIOUS tick's outbox (None on the first)."""
+        mode) / :class:`CompactHostOutbox` (compact mode).
+
+        Under ``pipeline_ticks`` a tick MAY HOLD its outbox for the next
+        call: it does when its inbox left work behind that only another
+        tick can place (``_build_inbox``), and the next call then completes
+        it behind its own dispatch; otherwise it completes it here, as with
+        the option off.  The return is the newest outbox this call
+        completed; a call that completed none (it held its own and the one
+        before held none) returns the oldest outbox completed earlier and
+        not yet returned, or None."""
         pc = self._pc
         pc.begin()
         if self.overload is not None:
@@ -2193,6 +2229,12 @@ class PaxosManager:
             reg = self._take_kv_uploads()
         inbox = self._build_inbox()
         pc.mark("intake")
+        # Holding this tick's outbox for the next call overlaps the device
+        # with the next inbox's build (a period of max(host, device), not
+        # their sum) and costs every request in it one period.  Ticks per
+        # second matter only to work that is waiting for a tick, so a tick
+        # holds only when its inbox could not place all there was.
+        hold = self.cfg.paxos.pipeline_ticks and self._backlog
         placed = self._placed
         bulk_placed = self._bulk_placed
         lease_pack = None
@@ -2311,10 +2353,11 @@ class PaxosManager:
             packed = (pk_l, pk_r)
         else:
             self.state, packed = paxos_tick_packed(self.state, inbox, -1)
-        # Device sweep frontier: computed ONLY at the dispatch whose
-        # completion is scheduled to run _sweep_outstanding (1 in 64 ticks),
-        # from THIS tick's post-state — it travels with the packed outbox so
-        # the sweep consumes amin/base exactly as of the tick it completes.
+        # Device sweep frontier: computed ONLY at the dispatch of a tick
+        # whose completion runs _sweep_outstanding (1 in 64 ticks, by the
+        # tick's own number: whichever call completes it), from THIS tick's
+        # post-state — it travels with the packed outbox so the sweep
+        # consumes amin/base exactly as of the tick it completes.
         # The O(rows) frontier_rows gather is dispatched HERE too, right
         # behind sweep_frontier and before the next tick program enters the
         # stream: the rows holding records are host state already known at
@@ -2322,10 +2365,8 @@ class PaxosManager:
         # CPU contend with) the next tick's O(G) program — the one device
         # round-trip this plane exists to avoid.  By completion the [rows]
         # results are long finished and the sweep is memcpy + O(records).
-        # A drain that completes off-schedule just finds frontier=None and
-        # falls back to the host reductions (correct, only slower).
         frontier = None
-        done_at = self.tick_num + (2 if self.cfg.paxos.pipeline_ticks else 1)
+        done_at = self.tick_num + 1
         # mixed planes skip the device frontier: its [G]-indexed gathers
         # clip composite register rows onto log row G-1.  The host sweep
         # fallback reads the composite watermark via _dev_exec_np().
@@ -2338,53 +2379,61 @@ class PaxosManager:
             if fr is not None:
                 frontier = self._frontier_gather(fr)
         pc.mark("dispatch")
+        this = (packed, placed, bulk_placed, frontier, lease_pack,
+                health_pack, done_at)
         if self.wal is not None:
             self.wal.log_inbox(self.tick_num, inbox)
         pc.mark("wal_fsync")
         self.tick_num += 1
-        if self.cfg.paxos.pipeline_ticks:
-            # deferred unpack: _pending_out holds the still-on-device packed
-            # buffer; the blocking device->host sync for tick N happens at
-            # tick N+1's completion, so the device computes N while the host
-            # builds N+1's inbox and the WAL fsyncs — ingest N+1 / device N
-            # / app-exec N-1 genuinely concurrent (SURVEY §2.2 item 3; the
-            # round-3 version unpacked eagerly, which blocked the host on
-            # the device before any overlap could happen)
-            if self._pending_out is not None:
-                prev = self._pending_out
-                self._pending_out = None  # before completing: _complete_tick
-                # may reach drain_pipeline (pause_idle) — must not re-enter
-                out = self._complete_tick(*prev)
-            else:
-                # nothing pending this tick — but drain_pipeline (laggard
-                # sync, checkpoint) may have completed the previous tick's
-                # outbox moments ago; hand that stashed result out instead
-                # of dropping it, so callers polling tick() never miss a
-                # completed outbox on sync-due ticks
-                out, self._drained_out = self._drained_out, None
-            self._pending_out = (packed, placed, bulk_placed, frontier,
-                                 lease_pack, health_pack)
+        self._completions_c["held" if hold else "same_call"].inc()
+        done = []
+        if self._pending_out is not None:
+            # the previous call held its outbox: the device computed that
+            # tick while the host built this one's inbox and the WAL synced
+            # (SURVEY §2.2 item 3).  Cleared before completing: _complete_tick
+            # may reach drain_pipeline (pause_idle) — must not re-enter
+            prev, self._pending_out = self._pending_out, None
+            done.append(self._complete_tick(*prev))
+        if hold:
+            # deferred unpack: the blocking device->host sync for this tick
+            # happens in the next call
+            self._pending_out = this
             # a due checkpoint must cover on-host effects of every tick the
             # device state contains — drain the one-tick pipeline first
             if self.wal is not None and self.wal.checkpoint_due():
                 self.drain_pipeline()
         else:
-            out = self._complete_tick(packed, placed, bulk_placed, frontier,
-                                      lease_pack, health_pack)
+            done.append(self._complete_tick(*this))
+        if done:
+            out = done.pop()
+            self._unreturned.extend(done)
+        else:
+            # nothing completed in this call — but drain_pipeline (laggard
+            # sync, checkpoint) or a call that completed two may have left
+            # an outbox nobody was handed; hand the oldest out instead of
+            # dropping it, so callers polling tick() never miss one
+            out = self._unreturned.popleft() if self._unreturned else None
         if self.wal is not None:
             self.wal.maybe_checkpoint()
         pc.end()
         return out
 
-    def _complete_tick(self, packed, placed: list, bulk_placed=None,
-                       frontier=None, lease_pack=None, health_pack=None):
+    def _complete_tick(self, packed, placed: list, bulk_placed, frontier,
+                       lease_pack, health_pack, done_at: int):
         """Consume one tick's outbox (unpacking = the device sync point):
         requeue rejected intake, execute the ordered decision stream,
-        release durable callbacks, periodic GC."""
+        release durable callbacks, periodic GC.  ``done_at``: the tick's own
+        number + 1, which is what the periodic work goes by: each tick runs
+        it once, whichever call completes it."""
         pc = self._pc
         # re-arm without observing: drain_pipeline completes a deferred tick
         # outside tick(), and cross-call idle time must not land in "tally"
         pc.touch()
+        # the wait for the program, apart from the pull that follows it
+        # (both are "tally"); the interpreter lock is free meanwhile
+        t0 = time.perf_counter()
+        jax.block_until_ready(packed)
+        self._device_wait_h.observe(time.perf_counter() - t0)
         if lease_pack is not None:
             self._adopt_lease_pack(lease_pack)
         if health_pack is not None:
@@ -2442,11 +2491,11 @@ class PaxosManager:
         pc.mark("execute")
         self._flush_callbacks()
         pc.mark("egress")
-        if self.tick_num % self._sweep_every == 0:
+        if done_at % self._sweep_every == 0:
             self._sweep_outstanding(frontier)
         if (
             self.cfg.paxos.deactivation_ticks > 0
-            and self.tick_num % 256 == 0
+            and done_at % 256 == 0
             and len(self.rows) > 0
         ):
             self.pause_idle()
@@ -2464,13 +2513,12 @@ class PaxosManager:
     def drain_pipeline(self) -> None:
         """Synchronously finish the pending pipelined outbox (no-op when
         nothing is pending or pipelining is off).  The completed outbox is
-        stashed for the next tick() to return — draining (laggard sync, due
+        queued for a later tick() to return — draining (laggard sync, due
         checkpoint) must not make a tick's outbox vanish from the caller's
         point of view."""
         if self._pending_out is not None:
-            prev = self._pending_out
-            self._pending_out = None
-            self._drained_out = self._complete_tick(*prev)
+            prev, self._pending_out = self._pending_out, None
+            self._unreturned.append(self._complete_tick(*prev))
 
     def _flush_callbacks(self) -> None:
         """Release client responses only once the WAL covering their tick is
@@ -3138,10 +3186,8 @@ class PaxosManager:
         if self._pending_out is not None and (
                 self.outstanding or self._held_callbacks
                 or (self.bulk is not None and self.bulk.n_live)):
-            # a pipelined outbox somebody waits on needs a tick to complete.
-            # Every tick leaves one behind, so counting it whatever it holds
-            # kept a pipelined plane "busy" for good: the driver never
-            # backed off, and the full-width control plane ticked flat out
-            # beside the data plane (PERF.md section 6, PR 30)
+            # a held outbox somebody waits on needs a tick to complete (the
+            # backlog it was held for may be gone by now: a stop failed
+            # the queue); one that nobody waits on keeps no driver busy
             n += 1
         return n

@@ -260,16 +260,24 @@ def test_lease_config_gates():
 
 # --------------------------------------------------------- mode A chaos soak
 
-@pytest.mark.parametrize("seed", [11, 12, 13])
-def test_lease_soak_mode_a_linearizable(seed):
+@pytest.mark.parametrize("seed,pipeline", [(11, False), (12, False),
+                                           (13, False), (11, True),
+                                           (12, True), (13, True)])
+def test_lease_soak_mode_a_linearizable(seed, pipeline):
     """Randomized holder crash/revive with skew injection on the shared
     device plane: every read that returns must be linearizable against
     the closed-loop monotone writer (floor = acked at invocation, ceiling
-    = issued at response)."""
+    = issued at response).  Pipelined: nobody drains between ticks, and
+    every thirteenth tick another name takes more writes at one entry
+    replica than three ticks place, so the lease packs ride held and
+    same-call completions in turn and across the changes of side."""
     horizon, margin = 12, 4
-    m = PaxosManager(mk_cfg(horizon=horizon, margin=margin, compact=True),
+    m = PaxosManager(mk_cfg(horizon=horizon, margin=margin, compact=True,
+                            pipeline=pipeline),
                      3, [KVApp() for _ in range(3)])
     m.create_paxos_instance("svc", [0, 1, 2])
+    m.create_paxos_instance("busy", [0, 1, 2])
+    sides = []  # per tick: was its outbox held for the next call
     rng = np.random.default_rng(seed)
     state = {"acked": 0, "issued": 0, "outstanding": None}
     failures = []
@@ -316,14 +324,21 @@ def test_lease_soak_mode_a_linearizable(seed):
             write()
         if t % 2 == 0:
             read(t)
+        if pipeline and t % 13 == 6:
+            for i in range(3 * m.P):
+                m.propose("busy", f"PUT b{i} x".encode(), entry=0)
         m.tick()
-        m.drain_pipeline()
+        sides.append(m._pending_out is not None)
+        if not pipeline:
+            m.drain_pipeline()
     if down is not None:
         m.set_alive(down[0], True)
     pump(m, 60)
     assert not failures, failures[:5]
     assert state["acked"] > 20
     assert m.stats["local_reads"] > 0
+    if pipeline:
+        assert sum(a != b for a, b in zip(sides, sides[1:])) >= 30, sides
 
 
 # --------------------------------------------------------- mode B chaos soak

@@ -12,7 +12,9 @@ held to ``chipbench/reference.py`` (``RefKV``, ``check_run``: what decides a
 run's ``correct`` on the chip); the mapping may change no answer; a write
 dropped on one replica fails the check; the journal written under the mesh
 replays to the same tables after a restart; and the mesh's ticks count their
-dispatches.
+dispatches.  Both configurations set ``pipeline_ticks``: every tick whose
+inbox left nothing behind completes its outbox in the call that dispatched
+it (ISSUE 31), on both mappings.
 """
 
 import copy
@@ -81,11 +83,12 @@ def offer(client, actives: list, ops: list) -> list:
     return got
 
 
-def _dispatches(snap: dict, plane: str) -> dict:
+def _by_label(snap: dict, family: str, plane: str, label: str) -> dict:
+    """{value of ``label``: count} over a counter family's series of a plane."""
     out = {}
     for key, val in snap.items():
-        if key.startswith("mesh_dispatches_total{") and f"plane={plane}" in key:
-            out[key.partition("program=")[2].rstrip("}").split(",")[0]] = val
+        if key.startswith(family + "{") and f"plane={plane}" in key:
+            out[key.partition(label + "=")[2].rstrip("}").split(",")[0]] = val
     return out
 
 
@@ -103,18 +106,28 @@ class Served:
     problems_dropped_write: list  # ... with one write dropped on one replica
     ticks: dict                  # plane -> ticks dispatched while counted
     dispatches: dict             # plane -> {program: count} over those ticks
+    completions: dict            # plane -> {mode: count} over those ticks
     restarted_tables: dict       # name -> [one dict per replica], replayed
     restarted_get: dict          # name -> value a GET gave after the restart
     replayed_tick: int           # the data plane's tick number after replay
 
 
 def _plane_counts(cluster) -> tuple:
-    ticks, counts = {}, {}
+    ticks, counts, modes = {}, {}, {}
     for plane, m in (("ar", cluster.manager), ("rc", cluster.rc_manager)):
         with m.lock:  # a tick counts its dispatches under this lock
             ticks[plane] = m.tick_num
-            counts[plane] = _dispatches(registry().snapshot(), plane)
-    return ticks, counts
+            snap = registry().snapshot()
+            counts[plane] = _by_label(snap, "mesh_dispatches_total", plane,
+                                      "program")
+            modes[plane] = _by_label(snap, "tick_completions_total", plane,
+                                     "mode")
+    return ticks, counts, modes
+
+
+def _rose(after: dict, before: dict) -> dict:
+    return {p: {k: n - before[p].get(k, 0) for k, n in after[p].items()}
+            for p in after}
 
 
 def serve(mapping: str, run_dir: str) -> Served:
@@ -127,7 +140,7 @@ def serve(mapping: str, run_dir: str) -> Served:
     client = None
     try:
         m = cluster.manager
-        ticks0, counts0 = _plane_counts(cluster)
+        ticks0, counts0, modes0 = _plane_counts(cluster)
         names = deployment.populate(cluster, int(config["populate_groups"]))
         actives = list(cfg.nodes.active_ids())
         client = ReconfigurableAppClient(cfg.nodes)
@@ -166,7 +179,7 @@ def serve(mapping: str, run_dir: str) -> Served:
                                      readback, KEY)
         victim[KEY] = dropped
         tables = {name: copy.deepcopy(tables_of(name)) for name in touched}
-        ticks1, counts1 = _plane_counts(cluster)
+        ticks1, counts1, modes1 = _plane_counts(cluster)
         served = Served(
             devices=len(m.state.exec_slot.sharding.device_set),
             rc_devices=len(
@@ -174,9 +187,8 @@ def serve(mapping: str, run_dir: str) -> Served:
             replies=replies, writes=writes, tables=tables, readback=readback,
             problems=problems, problems_dropped_write=faulty,
             ticks={p: ticks1[p] - ticks0[p] for p in ticks1},
-            dispatches={p: {prog: n - counts0[p].get(prog, 0)
-                            for prog, n in counts1[p].items()}
-                        for p in counts1},
+            dispatches=_rose(counts1, counts0),
+            completions=_rose(modes1, modes0),
             restarted_tables={}, restarted_get={}, replayed_tick=0)
     finally:
         if client is not None:
@@ -274,11 +286,32 @@ def test_a_mesh_tick_counts_its_two_dispatches(runs):
     placement plane is off); a one-device plane counts nothing."""
     mesh, one = runs["mesh4"], runs["one"]
     for plane in ("ar", "rc"):
-        assert mesh.ticks[plane] > 20
+        # enough ticks for the equality below to say something.  (It was
+        # "> 20" while every reply took two calls; answered by the call
+        # that dispatched it, the same schedule takes 19 data-plane ticks
+        # and 15 control-plane ones, and an idle probe waits for its own
+        # program.)
+        assert mesh.ticks[plane] > 10, mesh.ticks
         assert mesh.dispatches[plane] == {"tick": mesh.ticks[plane],
                                           "compact": mesh.ticks[plane],
                                           "fold": 0}
         assert not any(one.dispatches[plane].values())
+
+
+@pytest.mark.parametrize("mapping", list(CONFIGS))
+def test_the_served_ticks_complete_in_the_call_that_dispatched_them(runs,
+                                                                    mapping):
+    """``tick_completions_total{plane,mode}`` counts every dispatched tick
+    once, and on this schedule (nobody waiting for the device; of the 400
+    concurrent PUTs a handful of names draw more than P, which holds the
+    tick that could not place them all) the same-call side took the rest:
+    the answers above are the same-call path's, under ``pipeline_ticks``."""
+    served = runs[mapping]
+    for plane in ("ar", "rc"):
+        modes = served.completions[plane]
+        assert sum(modes.values()) == served.ticks[plane]
+        assert modes["held"] <= 3, modes
+        assert modes["same_call"] >= 0.8 * served.ticks[plane], modes
 
 
 def test_the_four_chip_configuration_is_the_one_chip_one_but_for_its_mapping():

@@ -124,6 +124,47 @@ def test_the_mesh_programs_run_the_scoped_code_under_their_own_names():
     assert sorted(counted) == ["compact", "fold", "tick", "tick"]
 
 
+def test_tick_completion_families_carry_their_labels():
+    """``tick_completions_total{plane,mode=same_call|held}`` rises once per
+    dispatched tick with the side it took, whether ``pipeline_ticks`` is on
+    or off (off: every tick is ``same_call``);
+    ``tick_device_wait_seconds{plane}`` is observed once per completion,
+    inside the ``tally`` phase and before the pull: the benchmark's
+    ``device_wait_ms`` reads its mean."""
+    import json
+
+    from gigapaxos_tpu.config import GigapaxosTpuConfig
+    from gigapaxos_tpu.models.replicable import KVApp
+    from gigapaxos_tpu.obs.metrics import registry
+    from gigapaxos_tpu.paxos.manager import PaxosManager
+
+    src = _src(DRIVER_FILES["modea"])
+    assert re.search(r'_completions_c\["held" if hold else "same_call"\]'
+                     r'\.inc\(\)', src)
+    body = src[src.index("def _complete_tick"):src.index(
+        "def _count_compact_paths")]
+    wait = body.index("self._device_wait_h.observe(")
+    assert body.index("jax.block_until_ready(packed)") < wait
+    assert wait < body.index("np.asarray(packed") < body.index(
+        'pc.mark("tally")')
+
+    plane = "t_completion_labels"
+    m = PaxosManager(GigapaxosTpuConfig(), 3, [KVApp() for _ in range(3)],
+                     spill_ns=plane)
+    m.create_paxos_instance("svc", [0, 1, 2])
+    m.run_ticks(3)
+    snap = registry().snapshot()
+    assert snap[f"tick_completions_total{{mode=same_call,plane={plane}}}"] == 3
+    assert snap[f"tick_completions_total{{mode=held,plane={plane}}}"] == 0
+    assert snap[f"tick_device_wait_seconds{{plane={plane}}}"]["count"] == 3
+    with open(os.path.join(ROOT, "chipbench", "layer_metrics",
+                           "device_wait_ms.json")) as f:
+        metric = json.load(f)
+    assert metric["reader"] == "histogram_mean"
+    assert metric["args"] == {"family": "tick_device_wait_seconds",
+                              "labels": {"plane": "ar"}}
+
+
 def test_wal_fsync_goes_through_instrumented_sync_only():
     """Every durability point must flow through ``_sync`` (timed +
     stall-counted); a bare ``journal.sync()`` anywhere else is an
@@ -160,6 +201,10 @@ WIRING = {
     "compact_path_ticks_total": "gigapaxos_tpu/paxos/manager.py",
     # how many programs a sharded plane's tick enqueued (ISSUE 30)
     "mesh_dispatches_total": "gigapaxos_tpu/paxos/manager.py",
+    # which call completed a pipelined tick's outbox, and how long the
+    # completion was blocked for the program before the pull (ISSUE 31)
+    "tick_completions_total": "gigapaxos_tpu/paxos/manager.py",
+    "tick_device_wait_seconds": "gigapaxos_tpu/paxos/manager.py",
     "jit_compile_seconds": "gigapaxos_tpu/obs/compiles.py",
     "compile_cache_lookups_total": "gigapaxos_tpu/obs/compiles.py",
     "wal_fsync_seconds": "gigapaxos_tpu/wal/logger.py",

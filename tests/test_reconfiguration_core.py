@@ -129,13 +129,14 @@ def test_coordinator_create_request_epoch_bump_and_final_state():
 
 
 def test_get_final_state_serves_from_undrained_pipeline():
-    """Pipelined manager: the tick that decides the epoch stop leaves the
-    stop (and the epoch's final writes) in the pending outbox until the
-    NEXT tick completes it.  get_final_state must drain that pipeline under
-    the manager lock and serve the complete final state immediately — not
-    answer from the host's one-tick-stale view (None here; worse, a
-    checkpoint missing the final writes once watermarks and host state
-    skew).  Regression for the drain added to
+    """Pipelined manager on the held side (another name's backlog keeps
+    every tick's outbox for the next call): the tick that decides the epoch
+    stop leaves the stop (and the epoch's final writes) in the pending
+    outbox until the NEXT tick completes it.  get_final_state must drain
+    that pipeline under the manager lock and serve the complete final state
+    immediately — not answer from the host's one-tick-stale view (None
+    here; worse, a checkpoint missing the final writes once watermarks and
+    host state skew).  Regression for the drain added to
     reconfiguration/coordinator.py:get_final_state."""
     import pytest as _pytest
 
@@ -145,12 +146,18 @@ def test_get_final_state_serves_from_undrained_pipeline():
     nodes = [f"AR{i}" for i in range(3)]
     coord = PaxosReplicaCoordinator(mgr, nodes)
     assert coord.create_replica_group("svc", 0, b"", nodes)
+    assert mgr.create_paxos_instance("busy", [0, 1, 2])
     got = []
     coord.coordinate_request("svc", 0, b"PUT k v0",
                              lambda r, resp: got.append(resp))
     mgr.run_ticks(4)
     mgr.drain_pipeline()
     assert got == [b"OK"]
+
+    # more than P a tick for one name at one entry replica, for longer
+    # than the stop takes: every tick below holds its outbox
+    for i in range(12 * mgr.P):
+        mgr.propose("busy", f"PUT b{i} x".encode(), entry=0)
 
     # final write is device-decided (one tick), but its completion —
     # execution + host bookkeeping — still sits in the pipeline when the
@@ -165,7 +172,8 @@ def test_get_final_state_serves_from_undrained_pipeline():
     pname = "svc#0"
     for _ in range(8):
         mgr.tick()
-        if mgr._pending_out is not None and not mgr.is_stopped(pname):
+        assert mgr._pending_out is not None  # the backlog holds it
+        if not mgr.is_stopped(pname):
             # the decisive window: whatever this tick decided (eventually
             # the stop) is still in the pending outbox.  Once the stop is
             # device-decided, get_final_state must serve from HERE.

@@ -73,11 +73,18 @@ def _mixed_manager(cfg, d, apps, ckpt=16):
 
 
 # six seeds, both dispatch modes — the acceptance bar is zero violations
-@pytest.mark.parametrize("seed,compact", [(3, False), (11, True), (29, False),
-                                          (57, True), (101, False),
-                                          (211, True)])
-def test_register_random_crash_recover(tmp_path, seed, compact):
+# (a pipelined tick holds its outbox only when its inbox left work behind,
+# which this trickle does about once a run; ``bursts`` adds, every eleventh
+# tick, more writes to one group at one entry replica than three ticks
+# place, log and register groups in turn, so those runs change sides both
+# ways a dozen times under the same crashes and checkpoints)
+@pytest.mark.parametrize("seed,compact,bursts", [
+    (3, False, False), (11, True, False), (29, False, False),
+    (57, True, False), (101, False, False), (211, True, False),
+    (3, True, True), (57, False, True), (211, True, True)])
+def test_register_random_crash_recover(tmp_path, seed, compact, bursts):
     rng = np.random.default_rng(seed)
+    sides = []  # per tick: was its outbox held for the next call
     cfg = mk_cfg(compact)
     d = os.path.join(str(tmp_path), "wal")
     apps = [LedgerKVApp() for _ in range(3)]
@@ -114,13 +121,20 @@ def test_register_random_crash_recover(tmp_path, seed, compact):
         sent += 1
         k, v = f"t{sent}", f"tv{t}"
         m.propose(g, f"PUT {k} {v}".encode(), mk_cb(sent, g, k, v))
+        if bursts and t % 11 == 5:
+            for i in range(3 * m.P):
+                m.propose(groups[t % len(groups)],
+                          f"PUT burst{i} x".encode(), None, False, 0)
         m.tick()
+        sides.append(m._pending_out is not None)
     for r in range(3):
         m.set_alive(r, True)
     for _ in range(60):
         m.tick()
     m.drain_pipeline()
     assert m.stats["executions"] > 0
+    if bursts:
+        assert sum(a != b for a, b in zip(sides, sides[1:])) >= 12, sides
     acked_groups = {gkv[0] for gkv in committed.values()}
     assert acked_groups & set(REG_GROUPS), "no register decision ever acked"
     m.wal.close()

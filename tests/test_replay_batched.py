@@ -4,6 +4,7 @@ re-logged journal bytes across all dispatch modes, the mixed register
 plane and the lease plane; torn-tail/scribble verdict parity of the
 bounded-memory (meta_only) scanner; overflow fallback correctness."""
 
+import copy
 import shutil
 
 import numpy as np
@@ -162,6 +163,31 @@ def test_batched_replay_bit_identity(tmp_path, mode):
     m_ref.wal.close()
     m_bat.wal.close()
     assert journal_bytes(a) == journal_bytes(b)
+
+
+@pytest.mark.parametrize("mode", ["full_pipe", "compact_pipe"])
+def test_replay_does_not_depend_on_the_side_a_tick_took(tmp_path, mode):
+    """Six writes a round to each name, against P = 4: every other tick's
+    inbox leaves some behind and holds its outbox, the ticks between
+    complete their own.  The journal records what was placed, so both
+    replay arms rebuild the plane as it stood, tables included."""
+    a = tmp_path / "a"
+    a.mkdir()
+    cfg, apps, m = mk(a, **MODES[mode])
+    sides = []
+    tick = m.tick
+    m.tick = lambda: (tick(), sides.append(m._pending_out is not None))[0]
+    drive(m, per_round=6)
+    assert 6 > m.P and sum(a != b for a, b in zip(sides, sides[1:])) >= 10
+    m.drain_pipeline()
+    tables = [copy.deepcopy(app.db) for app in apps]
+    m.wal.close()  # crash
+
+    m_ref, m_bat, b = recover_both(tmp_path, cfg, a)
+    assert_identical(m_ref, m_bat)
+    assert [app.db for app in m_bat.apps] == tables
+    m_ref.wal.close()
+    m_bat.wal.close()
 
 
 def test_batched_replay_mixed_register_plane(tmp_path):

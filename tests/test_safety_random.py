@@ -127,10 +127,13 @@ def test_noop_decisions_allowed():
         # (assertions inside run_random cover S1-S3)
 
 
-@pytest.mark.parametrize("seed,compact", [(7, False), (13, False),
-                                           (32, False), (128, False),
-                                           (7, True), (128, True)])
-def test_manager_random_crash_recover_pipelined(tmp_path, seed, compact):
+@pytest.mark.parametrize("seed,compact,bursts", [
+    (7, False, False), (13, False, False), (32, False, False),
+    (128, False, False), (7, True, False), (128, True, False),
+    (7, False, True), (13, True, True), (32, False, True),
+    (128, True, True)])
+def test_manager_random_crash_recover_pipelined(tmp_path, seed, compact,
+                                                bursts):
     """Manager-level randomized safety with PIPELINED ticks + WAL: random
     request arrivals, random replica crash/recover (majority kept alive),
     periodic checkpoints (which drain the pipeline), then a full process
@@ -145,7 +148,13 @@ def test_manager_random_crash_recover_pipelined(tmp_path, seed, compact):
     revival, 32 = the sweep rotation bound off-by-one at slot == base-W,
     128 = the sweep judging "everyone passed" from DEVICE exec, which
     includes the in-flight pipelined tick — dropping the payload of the
-    very delivery that advanced it (the _host_exec watermark fix)."""
+    very delivery that advanced it (the _host_exec watermark fix).
+
+    A pipelined tick holds its outbox only when its inbox left work behind
+    (ISSUE 31), which this trickle of arrivals does once or twice a run;
+    ``bursts`` adds, every eleventh tick, more writes to one name at one
+    entry replica than three ticks place, so the run changes sides a dozen
+    times, in both directions, under the same crashes and checkpoints."""
     import os
 
     from gigapaxos_tpu.config import GigapaxosTpuConfig
@@ -167,6 +176,7 @@ def test_manager_random_crash_recover_pipelined(tmp_path, seed, compact):
 
     committed = {}  # rid -> (group, key, value) for responses RELEASED
     sent = 0
+    sides = []  # per tick: was its outbox held for the next call
 
     def mk_cb(rid, g, k, v):
         def cb(_rid, resp):
@@ -194,13 +204,21 @@ def test_manager_random_crash_recover_pipelined(tmp_path, seed, compact):
         sent += 1
         k, v = f"t{sent}", f"tv{t}"
         m.propose(f"g{g}", f"PUT {k} {v}".encode(), mk_cb(sent, g, k, v))
+        if bursts and t % 11 == 5:
+            for i in range(3 * m.P):
+                m.propose(f"g{t % 4}", f"PUT burst{i} x".encode(),
+                          None, False, 0)
         m.tick()
+        sides.append(m._pending_out is not None)
     for r in range(3):
         m.set_alive(r, True)
     for _ in range(60):
         m.tick()
     m.drain_pipeline()
     assert m.stats["executions"] > 0
+    if bursts:
+        assert sum(a != b for a, b in zip(sides, sides[1:])) >= 12, sides
+        assert 20 <= sum(sides) <= 100, sides
     wal.close()
 
     # crash everything; recover and check every released response is present

@@ -102,18 +102,26 @@ def _gen_inbox_fn(g_total: int, p: int = 1):
     return jax.jit(gen)
 
 
+def _mixed_tick(s, r, inbox):
+    """One full-outbox tick of both planes through the served entry."""
+    from gigapaxos_tpu.ops.tick import (TickParams, TickPlanes,
+                                        paxos_tick_planes)
+
+    (s, r, *_), packs = paxos_tick_planes(TickPlanes(s, r), inbox,
+                                          TickParams())
+    return s, r, packs.out, packs.rout
+
+
 def bench_dense_mixed_alloc(g_log: int, g_reg: int) -> dict:
     """>= 4M mixed-mode groups as dense arrays on CPU: allocate, create,
     one mixed tick — the committed-bytes statement of the tentpole."""
-    from gigapaxos_tpu.ops.tick import paxos_tick_mixed_packed
-
     t0 = time.perf_counter()
     s, r = _mixed_planes(g_log, g_reg)
     alloc_s = time.perf_counter() - t0
     total = state_nbytes(s) + state_nbytes(r)
     gen = _gen_inbox_fn(g_log + g_reg)
     t0 = time.perf_counter()
-    s, r, pk_l, pk_r = paxos_tick_mixed_packed(s, r, gen(jnp.int32(1)), -1, 0)
+    s, r, pk_l, pk_r = _mixed_tick(s, r, gen(jnp.int32(1)))
     jax.block_until_ready(pk_r)
     tick_s = time.perf_counter() - t0
     out = {
@@ -132,8 +140,6 @@ def bench_dense_mixed_alloc(g_log: int, g_reg: int) -> dict:
 def bench_dec_per_s_mixed(g_log: int, g_reg: int, ticks: int = 10) -> dict:
     """Sustained mixed-kernel decisions/s: both planes stepped in one
     donated jit per tick, decisions counted from replica-0 exec deltas."""
-    from gigapaxos_tpu.ops.tick import paxos_tick_mixed_packed
-
     s, r = _mixed_planes(g_log, g_reg)
     gen = _gen_inbox_fn(g_log + g_reg)
 
@@ -142,14 +148,13 @@ def bench_dec_per_s_mixed(g_log: int, g_reg: int, ticks: int = 10) -> dict:
 
     g_total = g_log + g_reg
     for i in range(3):  # compile + fill the self-proposal pipeline
-        s, r, pk_l, pk_r = paxos_tick_mixed_packed(
-            s, r, gen(jnp.int32(1 + i * g_total)), -1, 0)
+        s, r, pk_l, pk_r = _mixed_tick(s, r, gen(jnp.int32(1 + i * g_total)))
     jax.block_until_ready(pk_r)
     base = exec_sum(s, r)
     t0 = time.perf_counter()
     for i in range(ticks):
-        s, r, pk_l, pk_r = paxos_tick_mixed_packed(
-            s, r, gen(jnp.int32(1 + (3 + i) * g_total)), -1, 0)
+        s, r, pk_l, pk_r = _mixed_tick(
+            s, r, gen(jnp.int32(1 + (3 + i) * g_total)))
     jax.block_until_ready(pk_r)
     dt = time.perf_counter() - t0
     decs = exec_sum(s, r) - base

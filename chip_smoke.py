@@ -413,38 +413,21 @@ def kernels_in(fn, *args) -> dict:
 
 
 def tick_program(m):
-    """The jitted tick ``PaxosManager.tick`` dispatches for this manager's
-    configuration, with arguments shaped like the state it holds.  Mirrors
-    the dispatch ladder for the configurations the smoke builds (ROADMAP C1
-    folds the ladder into one entry; this table folds with it)."""
+    """The jitted tick ``PaxosManager.tick`` dispatches for this manager,
+    with arguments shaped like the state it holds: the manager says which
+    (``PaxosManager.tick_program``).  A mesh tick is two dispatches behind
+    one callable; the kernels are in the first, the shard_map tick."""
     from gigapaxos_tpu.ops import tick as tk
 
     inbox = tk.TickInbox(
         np.zeros((m.R, m.P, m.G_total), np.int32),
         np.zeros((m.R, m.P, m.G_total), bool), np.ones(m.R, bool))
-    E, Lb = m._exec_budget, m._lag_budget
     check(m._use_compact, "the smoke builds compact-outbox managers only")
-    if m._health is not None:
-        return tk.paxos_tick_health, (
-            m.state, m.rstate, m._lease, m._rlease, m._health, m._rhealth,
-            inbox, -1, E, Lb, m._lease_horizon, True, m._health_wedge,
-            m._health_shift, m._health_topk)
-    if m._device_app:
-        from gigapaxos_tpu.models.device_kv import fused_compact
-
-        reg = [np.zeros(m._kv_reg_budget, np.int32)] * 4
-        return fused_compact, (m.state, m.kv, inbox, *reg, -1, E, Lb)
     if m.mesh is not None:
         from gigapaxos_tpu.parallel.shard_tick import make_shardmap_tick
 
-        return make_shardmap_tick(m.mesh, -1, E), (m.state, inbox)
-    if m._lease is not None:
-        return tk.paxos_tick_compact_lease, (
-            m.state, m._lease, inbox, -1, E, Lb, m._lease_horizon)
-    if m.rstate is not None:
-        return tk.paxos_tick_mixed_compact, (
-            m.state, m.rstate, inbox, -1, E, Lb)
-    return tk.paxos_tick_compact, (m.state, inbox, -1, E, Lb)
+        return make_shardmap_tick(m.mesh, -1, m._exec_budget), (m.state, inbox)
+    return m.tick_program(inbox)
 
 
 def prove_device_path(log: Log, m, what: str, on_chip: bool) -> dict:

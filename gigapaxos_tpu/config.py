@@ -200,8 +200,9 @@ class PaxosTuning:
     # quiescent (executed frontier == accepted frontier).  Lease state is
     # dense [G] device columns folded inside the fused tick; time is the
     # tick clock itself, so lease decisions replay deterministically from
-    # the WAL.  Default off — the lease-off build runs the literal
-    # pre-lease tick program, bit for bit (the register_groups=0 pattern).
+    # the WAL.  Default off — a lease-off build hands the tick no lease
+    # columns and the fold is not in its program (the register_groups=0
+    # pattern).
     read_leases: bool = False
     # Lease horizon in ticks: a grant/renewal is valid for this many ticks.
     lease_ticks: int = 64
@@ -215,8 +216,8 @@ class PaxosTuning:
     # inside the fused tick, reduced on device into log2 histograms +
     # scalar gauges + top-K anomaly columns (one O(K) host pull per tick).
     # Observation-only: the fold never feeds back into consensus, and with
-    # the flag off the tick programs are the literal pre-health functions,
-    # bit for bit (the read_leases=off pattern).
+    # the flag off it is not in the tick's program (the read_leases=off
+    # pattern).
     group_health: bool = False
     # Top-K rows shipped per criterion (stuckest / churniest / hottest).
     health_topk: int = 8

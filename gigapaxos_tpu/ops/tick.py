@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -199,8 +199,8 @@ class LeaseState(NamedTuple):
     margin: jnp.ndarray
 
 
-#: lease_pack row indices (the [5, G] per-plane host summary emitted by the
-#: lease tick variants — ONE device->host pull per plane per tick)
+#: lease_pack row indices (the [5, G] per-plane host summary a tick over
+#: lease columns emits — ONE device->host pull per plane per tick)
 LP_HOLDER, LP_EPOCH, LP_UNTIL, LP_ASN, LP_WAIT = range(5)
 LP_ROWS = 5
 
@@ -416,7 +416,7 @@ def paxos_tick_impl(state, inbox: TickInbox, own_row: int = -1,
     the window-arithmetic dwrite guard keeps them from being overwritten,
     and a full window throttles intake — so the cap is lossless
     backpressure, not drop.  This is what makes a *bounded* compacted
-    outbox transfer safe (see :func:`paxos_tick_compact_impl`): the host
+    outbox transfer safe (see :func:`_compact_outbox_impl`): the host
     never needs more than ``exec_budget`` execution records per tick.
 
     own_row: -1 for Mode A (all rows authoritative: the whole replica set is
@@ -1166,41 +1166,6 @@ def unpack_outbox(flat, R: int, P: int, W: int, G: int) -> HostOutbox:
     )
 
 
-def _paxos_tick_packed_impl(state, inbox: TickInbox, own_row: int = -1,
-                            exec_budget: int = 0, fast_elect: bool = False):
-    state, out = paxos_tick_impl(state, inbox, own_row, exec_budget,
-                                 fast_elect=fast_elect)
-    return state, pack_outbox_impl(out)
-
-
-#: fused tick + outbox pack: one dispatch, one device->host buffer.
-#: exec_budget matters even on this full-outbox path: WAL replay of a run
-#: that ticked with a budget must evolve state identically.
-paxos_tick_packed = jax.jit(
-    _paxos_tick_packed_impl, donate_argnums=(0,), static_argnums=(2, 3, 4)
-)
-
-
-def _paxos_tick_packed_lease_impl(state, lease: LeaseState, inbox: TickInbox,
-                                  own_row: int = -1, exec_budget: int = 0,
-                                  lease_horizon: int = 0,
-                                  fast_elect: bool = False):
-    state, out, lease, lp = paxos_tick_impl(
-        state, inbox, own_row, exec_budget, fast_elect=fast_elect,
-        lease=lease, lease_horizon=lease_horizon)
-    return state, lease, pack_outbox_impl(out), lp
-
-
-#: lease twin of paxos_tick_packed: same tick + the lease fold, returning
-#: the new LeaseState and the [5, G] lease_pack host summary.  A build with
-#: read_leases off never calls this — the lease-off program is the literal
-#: pre-lease function above, bit for bit.
-paxos_tick_packed_lease = jax.jit(
-    _paxos_tick_packed_lease_impl, donate_argnums=(0, 1),
-    static_argnums=(3, 4, 5, 6),
-)
-
-
 # --------------------------------------------------------------------------
 # Compacted outbox: the bounded-transfer tick for the at-scale host path.
 #
@@ -1375,40 +1340,6 @@ def _compact_outbox_impl(out: TickOutbox, exec_budget: int,
     ])
 
 
-def _paxos_tick_compact_impl(state, inbox: TickInbox, own_row: int,
-                             exec_budget: int, lag_budget: int,
-                             fast_elect: bool = False):
-    state, out = paxos_tick_impl(state, inbox, own_row, exec_budget,
-                                 fast_elect=fast_elect)
-    return state, _compact_outbox_impl(out, exec_budget, lag_budget)
-
-
-#: fused tick + budgeted on-device compaction: one dispatch, one
-#: O(budget) device->host buffer
-paxos_tick_compact = jax.jit(
-    _paxos_tick_compact_impl, donate_argnums=(0,), static_argnums=(2, 3, 4, 5)
-)
-
-
-def _paxos_tick_compact_lease_impl(state, lease: LeaseState,
-                                   inbox: TickInbox, own_row: int,
-                                   exec_budget: int, lag_budget: int,
-                                   lease_horizon: int,
-                                   fast_elect: bool = False):
-    state, out, lease, lp = paxos_tick_impl(
-        state, inbox, own_row, exec_budget, fast_elect=fast_elect,
-        lease=lease, lease_horizon=lease_horizon)
-    return state, lease, _compact_outbox_impl(out, exec_budget, lag_budget), lp
-
-
-#: lease twin of paxos_tick_compact (the at-scale path): the O(budget)
-#: compact buffer plus the O(G) lease_pack — still one dispatch, two pulls.
-paxos_tick_compact_lease = jax.jit(
-    _paxos_tick_compact_lease_impl, donate_argnums=(0, 1),
-    static_argnums=(3, 4, 5, 6, 7),
-)
-
-
 class CompactLayout:
     """THE single source of truth for the compacted-outbox flat buffer:
     every offset any consumer needs, computed in one place.
@@ -1540,34 +1471,6 @@ def _frontier_rows_impl(amin, base, live, rows):
 frontier_rows = jax.jit(_frontier_rows_impl)
 
 
-def _paxos_tick_compact_demand_impl(state, inbox: TickInbox, demand,
-                                    own_row: int, exec_budget: int,
-                                    lag_budget: int, decay: float,
-                                    fast_elect: bool = False):
-    """Single-device twin of shard_tick's demand-folding compact tick:
-    tick + compaction + placement demand EWMA in ONE program.
-
-    The fold consumes per-row INTAKE (sum of ``intake_taken`` over entry
-    and p slots — exactly the ``taken_bits`` popcount the host fold used to
-    compute in an O(G*P) numpy loop per tick), so the host-visible demand
-    samples are bit-identical to the old host fold.  Fusing is safe here
-    precisely because there is no mesh: the GSPMD same-jit miscompile that
-    forces the mesh path's fold into a separate dispatch does not exist in
-    a single-device program, and the flat compact buffer stays
-    byte-identical."""
-    state, out = paxos_tick_impl(state, inbox, own_row, exec_budget,
-                                 fast_elect=fast_elect)
-    per_row = jnp.sum(out.intake_taken.astype(demand.dtype), axis=(0, 1))
-    new_demand = decay * demand + per_row
-    return state, _compact_outbox_impl(out, exec_budget, lag_budget), new_demand
-
-
-paxos_tick_compact_demand = jax.jit(
-    _paxos_tick_compact_demand_impl, donate_argnums=(0, 2),
-    static_argnums=(3, 4, 5, 6, 7),
-)
-
-
 def make_inbox(n_replicas: int, n_groups: int, per_tick: int) -> TickInbox:
     """An empty inbox template (host fills rows it has traffic for)."""
     return TickInbox(
@@ -1600,91 +1503,6 @@ def _split_inbox(inbox: TickInbox, g_log: int):
         TickInbox(inbox.req[:, :, g_log:], inbox.stop[:, :, g_log:],
                   inbox.alive),
     )
-
-
-def _paxos_tick_mixed_packed_impl(state, rstate, inbox: TickInbox,
-                                  own_row: int = -1, exec_budget: int = 0):
-    """Fused mixed tick, full (packed) outbox per plane."""
-    g_log = state.exec_slot.shape[1]
-    ib_l, ib_r = _split_inbox(inbox, g_log)
-    state, out_l = paxos_tick_impl(state, ib_l, own_row, exec_budget)
-    rstate, out_r = paxos_tick_impl(rstate, ib_r, own_row, exec_budget)
-    return state, rstate, pack_outbox_impl(out_l), pack_outbox_impl(out_r)
-
-
-paxos_tick_mixed_packed = jax.jit(
-    _paxos_tick_mixed_packed_impl, donate_argnums=(0, 1),
-    static_argnums=(3, 4),
-)
-
-
-def _paxos_tick_mixed_packed_lease_impl(state, rstate, lease, rlease,
-                                        inbox: TickInbox, own_row: int = -1,
-                                        exec_budget: int = 0,
-                                        lease_horizon: int = 0):
-    """Lease twin of the mixed packed tick: each plane folds its own
-    LeaseState (register groups are first-class lease targets — their W=1
-    quiescence test is exactly the same frontier comparison)."""
-    g_log = state.exec_slot.shape[1]
-    ib_l, ib_r = _split_inbox(inbox, g_log)
-    state, out_l, lease, lp_l = paxos_tick_impl(
-        state, ib_l, own_row, exec_budget, lease=lease,
-        lease_horizon=lease_horizon)
-    rstate, out_r, rlease, lp_r = paxos_tick_impl(
-        rstate, ib_r, own_row, exec_budget, lease=rlease,
-        lease_horizon=lease_horizon)
-    return (state, rstate, lease, rlease,
-            pack_outbox_impl(out_l), pack_outbox_impl(out_r), lp_l, lp_r)
-
-
-paxos_tick_mixed_packed_lease = jax.jit(
-    _paxos_tick_mixed_packed_lease_impl, donate_argnums=(0, 1, 2, 3),
-    static_argnums=(5, 6, 7),
-)
-
-
-def _paxos_tick_mixed_compact_impl(state, rstate, inbox: TickInbox,
-                                   own_row: int, exec_budget: int,
-                                   lag_budget: int):
-    """Fused mixed tick, budgeted compact outbox per plane.  The register
-    plane's compaction flags laggards at lag >= 1 for free: the lag
-    threshold inside _compact_outbox_impl is the plane's own W."""
-    g_log = state.exec_slot.shape[1]
-    ib_l, ib_r = _split_inbox(inbox, g_log)
-    state, out_l = paxos_tick_impl(state, ib_l, own_row, exec_budget)
-    rstate, out_r = paxos_tick_impl(rstate, ib_r, own_row, exec_budget)
-    return (state, rstate,
-            _compact_outbox_impl(out_l, exec_budget, lag_budget),
-            _compact_outbox_impl(out_r, exec_budget, lag_budget))
-
-
-paxos_tick_mixed_compact = jax.jit(
-    _paxos_tick_mixed_compact_impl, donate_argnums=(0, 1),
-    static_argnums=(3, 4, 5),
-)
-
-
-def _paxos_tick_mixed_compact_lease_impl(state, rstate, lease, rlease,
-                                         inbox: TickInbox, own_row: int,
-                                         exec_budget: int, lag_budget: int,
-                                         lease_horizon: int):
-    g_log = state.exec_slot.shape[1]
-    ib_l, ib_r = _split_inbox(inbox, g_log)
-    state, out_l, lease, lp_l = paxos_tick_impl(
-        state, ib_l, own_row, exec_budget, lease=lease,
-        lease_horizon=lease_horizon)
-    rstate, out_r, rlease, lp_r = paxos_tick_impl(
-        rstate, ib_r, own_row, exec_budget, lease=rlease,
-        lease_horizon=lease_horizon)
-    return (state, rstate, lease, rlease,
-            _compact_outbox_impl(out_l, exec_budget, lag_budget),
-            _compact_outbox_impl(out_r, exec_budget, lag_budget), lp_l, lp_r)
-
-
-paxos_tick_mixed_compact_lease = jax.jit(
-    _paxos_tick_mixed_compact_lease_impl, donate_argnums=(0, 1, 2, 3),
-    static_argnums=(5, 6, 7, 8),
-)
 
 
 def merge_outbox(out_l: HostOutbox, out_r: HostOutbox) -> HostOutbox:
@@ -1746,6 +1564,151 @@ def merge_compact_outbox(co_l: CompactHostOutbox, co_r: CompactHostOutbox,
 
 
 # --------------------------------------------------------------------------
+# The served tick: ONE program over whatever planes a manager holds.
+#
+# A deployment holds the log plane and, by configuration, a register plane
+# (W=1), lease columns per plane, health columns per plane and the placement
+# demand array.  Absent members are None: an empty pytree, so they flatten
+# to no operands and their folds are never traced.  The program a log-plane
+# -only compact deployment compiles is `paxos_tick_impl` followed by
+# `_compact_outbox_impl`, op for op.
+# --------------------------------------------------------------------------
+
+
+class TickPlanes(NamedTuple):
+    """The device state one tick evolves; every member but ``state`` may be
+    None.  ``demand`` is the [G_log] f32 placement EWMA (log plane only:
+    register rows never migrate shards)."""
+
+    state: Any
+    rstate: Any = None
+    lease: Any = None
+    rlease: Any = None
+    health: Any = None
+    rhealth: Any = None
+    demand: Any = None
+
+
+class TickParams(NamedTuple):
+    """The static half of a served tick (hashable: one compile per value).
+    ``exec_budget`` caps executions per tick (0 = unlimited) and, with
+    ``compact``, sizes the compact buffer's exec columns; without
+    ``compact`` the packs are the full outbox (:func:`pack_outbox_impl`)."""
+
+    own_row: int = -1
+    exec_budget: int = 0
+    lag_budget: int = 0
+    compact: bool = False
+    lease_horizon: int = 0
+    wedge_ticks: int = 32
+    health_decay_shift: int = 6
+    health_topk: int = 8
+    demand_decay: float = 0.0
+
+
+class TickPacks(NamedTuple):
+    """What one served tick hands the host, None where a plane is absent.
+    ``out``/``rout``: the flat outbox per plane (compact or full);
+    ``*lease_pack``: [LP_ROWS, G]; ``*health_pack``: see ``HealthLayout``
+    (top-K clamped to ``min(health_topk, G_plane)``, as the host unpacks
+    it in ``PaxosManager._adopt_health_pack``)."""
+
+    out: Any
+    rout: Any = None
+    lease_pack: Any = None
+    rlease_pack: Any = None
+    health_pack: Any = None
+    rhealth_pack: Any = None
+
+
+def one_or_pair(log, reg):
+    """A per-plane result as the host's completion takes it: the log
+    plane's alone, or a (log, register) pair with a register plane."""
+    return log if reg is None else (log, reg)
+
+
+def _tick_step(planes: TickPlanes, inbox: TickInbox, params: TickParams,
+               compact_budget: int):
+    """One tick of every plane held -> ``(TickPlanes, TickPacks)``.  The
+    composite inbox splits at the static plane boundary; each plane runs
+    :func:`paxos_tick_impl` with its own lease and health columns, then
+    packs its outbox.  ``compact_budget``: width of the compact exec
+    columns (the served tick's is ``exec_budget``; replay's is wider)."""
+
+    def plane(st, ib, le, he):
+        res = paxos_tick_impl(
+            st, ib, params.own_row, params.exec_budget, lease=le,
+            lease_horizon=params.lease_horizon, health=he,
+            wedge_ticks=params.wedge_ticks,
+            health_decay_shift=params.health_decay_shift,
+            health_topk=min(params.health_topk, st.exec_slot.shape[1]))
+        st, out, res = res[0], res[1], res[2:]
+        lp = hp = None
+        if le is not None:
+            le, lp, res = res[0], res[1], res[2:]
+        if he is not None:
+            he, hp = res
+        return st, le, he, out, lp, hp
+
+    def pack(out):
+        if params.compact:
+            return _compact_outbox_impl(out, compact_budget,
+                                        params.lag_budget)
+        return pack_outbox_impl(out)
+
+    ib_l, ib_r = inbox, None
+    if planes.rstate is not None:
+        ib_l, ib_r = _split_inbox(inbox, planes.state.exec_slot.shape[1])
+    state, lease, health, out, lp_l, hp_l = plane(
+        planes.state, ib_l, planes.lease, planes.health)
+    demand = planes.demand
+    if demand is not None:
+        # per-row INTAKE (intake_taken summed over entry and p): what the
+        # host fold counts from ``taken_bits``, so the samples match it
+        demand = params.demand_decay * demand + jnp.sum(
+            out.intake_taken.astype(demand.dtype), axis=(0, 1))
+    pk_l = pack(out)
+    rstate = rlease = rhealth = pk_r = lp_r = hp_r = None
+    if planes.rstate is not None:
+        # the register plane's compaction flags laggards at lag >= 1 for
+        # free: the threshold inside _compact_outbox_impl is the plane's W
+        rstate, rlease, rhealth, out_r, lp_r, hp_r = plane(
+            planes.rstate, ib_r, planes.rlease, planes.rhealth)
+        pk_r = pack(out_r)
+    return (TickPlanes(state, rstate, lease, rlease, health, rhealth, demand),
+            TickPacks(pk_l, pk_r, lp_l, lp_r, hp_l, hp_r))
+
+
+def _paxos_tick_planes_impl(planes: TickPlanes, inbox: TickInbox,
+                            params: TickParams):
+    return _tick_step(planes, inbox, params, params.exec_budget)
+
+
+#: THE served one-device tick: one dispatch, the planes donated.  The
+#: chipbench finds its executions in a trace by the program name
+#: (``^jit__?paxos_tick``): keep the impl's name in that pattern and out of
+#: ``parallel/shard_tick.MESH_PROGRAMS``.
+paxos_tick_planes = jax.jit(_paxos_tick_planes_impl, donate_argnums=(0,),
+                            static_argnums=(2,))
+
+
+def _paxos_tick_compact_impl(state, inbox: TickInbox, own_row: int,
+                             exec_budget: int, lag_budget: int):
+    planes, packs = _paxos_tick_planes_impl(
+        TickPlanes(state), inbox,
+        TickParams(own_row, exec_budget, lag_budget, compact=True))
+    return planes.state, packs.out
+
+
+#: A NAME ONLY, dispatched by no manager: ``chipbench/deployment.py`` traces
+#: it for the harness's kernel cross-check and the benchmark's files are
+#: not this repo's PRs' to edit (ROADMAP C13: point
+#: ``deployment.tick_program`` at ``paxos_tick_planes``, then delete this).
+paxos_tick_compact = jax.jit(_paxos_tick_compact_impl, donate_argnums=(0,),
+                             static_argnums=(2, 3, 4))
+
+
+# --------------------------------------------------------------------------
 # Batched WAL replay (ISSUE 19): lax.scan over the tick axis.
 #
 # Journal replay re-runs the SAME fused tick body as the live run, but the
@@ -1779,103 +1742,40 @@ def _coo_inbox(x, R: int, P: int, g_total: int) -> TickInbox:
     return TickInbox(req, stop, x["alive"])
 
 
-def _replay_scan_impl(state, xs, P: int, exec_budget: int,
-                      scat_budget: int, lag_budget: int):
-    R, G = state.exec_slot.shape
+def _replay_scan_impl(planes: TickPlanes, xs, P: int, params: TickParams,
+                      scat_budget: int):
+    R, g_log = planes.state.exec_slot.shape
+    g_reg = 0 if planes.rstate is None else planes.rstate.exec_slot.shape[1]
 
-    def body(st, x):
-        st, out = paxos_tick_impl(st, _coo_inbox(x, R, P, G), -1,
-                                  exec_budget)
-        return st, _compact_outbox_impl(out, scat_budget, lag_budget)
-
-    return jax.lax.scan(body, state, xs)
-
-
-#: K journaled ticks in one device program; returns (state, packs[K, total])
-replay_scan_ticks = jax.jit(_replay_scan_impl, static_argnums=(2, 3, 4, 5))
-
-
-def _replay_scan_lease_impl(state, lease, xs, P: int, exec_budget: int,
-                            scat_budget: int, lag_budget: int,
-                            lease_horizon: int):
-    R, G = state.exec_slot.shape
-    lp0 = jnp.zeros((5, G), I32)
+    def lp0(le, g):
+        return None if le is None else jnp.zeros((LP_ROWS, g), I32)
 
     def body(carry, x):
-        st, ls, _ = carry
-        st, out, ls, lp = paxos_tick_impl(
-            st, _coo_inbox(x, R, P, G), -1, exec_budget, lease=ls,
-            lease_horizon=lease_horizon)
-        packed = _compact_outbox_impl(out, scat_budget, lag_budget)
-        return (st, ls, lp), (packed, jnp.sum(lp[LP_WAIT]).astype(I32))
+        pl, _ = carry
+        pl, pk = _tick_step(pl, _coo_inbox(x, R, P, g_log + g_reg), params,
+                            scat_budget)
+        packed = (pk.out if pk.rout is None
+                  else jnp.concatenate([pk.out, pk.rout]))
+        lps = [lp for lp in (pk.lease_pack, pk.rlease_pack) if lp is not None]
+        waits = (sum(jnp.sum(lp[LP_WAIT]) for lp in lps).astype(I32)
+                 if lps else None)
+        return (pl, (pk.lease_pack, pk.rlease_pack)), (packed, waits)
 
-    (state, lease, lp_last), (packs, waits) = jax.lax.scan(
-        body, (state, lease, lp0), xs)
-    return state, lease, packs, lp_last, waits
-
-
-#: lease twin: also returns the FINAL tick's lease pack (the host mirror
-#: only ever holds the latest pack) and per-tick wait sums for metrics
-replay_scan_ticks_lease = jax.jit(
-    _replay_scan_lease_impl, static_argnums=(3, 4, 5, 6, 7))
+    (planes, lp_last), (packs, waits) = jax.lax.scan(
+        body, (planes, (lp0(planes.lease, g_log), lp0(planes.rlease, g_reg))),
+        xs)
+    return planes, packs, lp_last, waits
 
 
-def _replay_scan_mixed_impl(state, rstate, xs, P: int, exec_budget: int,
-                            scat_budget: int, lag_budget: int):
-    R, g_log = state.exec_slot.shape
-    g_total = g_log + rstate.exec_slot.shape[1]
-
-    def body(carry, x):
-        st, rst = carry
-        ib_l, ib_r = _split_inbox(_coo_inbox(x, R, P, g_total), g_log)
-        st, out_l = paxos_tick_impl(st, ib_l, -1, exec_budget)
-        rst, out_r = paxos_tick_impl(rst, ib_r, -1, exec_budget)
-        return (st, rst), jnp.concatenate([
-            _compact_outbox_impl(out_l, scat_budget, lag_budget),
-            _compact_outbox_impl(out_r, scat_budget, lag_budget),
-        ])
-
-    (state, rstate), packs = jax.lax.scan(body, (state, rstate), xs)
-    return state, rstate, packs
-
-
-#: mixed-plane twin: per tick the two planes' compact buffers ride one
-#: [total_l + total_r] row (host slices via CompactLayout per plane)
-replay_scan_ticks_mixed = jax.jit(
-    _replay_scan_mixed_impl, static_argnums=(3, 4, 5, 6))
-
-
-def _replay_scan_mixed_lease_impl(state, rstate, lease, rlease, xs, P: int,
-                                  exec_budget: int, scat_budget: int,
-                                  lag_budget: int, lease_horizon: int):
-    R, g_log = state.exec_slot.shape
-    g_reg = rstate.exec_slot.shape[1]
-    g_total = g_log + g_reg
-    lp0 = (jnp.zeros((5, g_log), I32), jnp.zeros((5, g_reg), I32))
-
-    def body(carry, x):
-        st, rst, ls, rls, _ = carry
-        ib_l, ib_r = _split_inbox(_coo_inbox(x, R, P, g_total), g_log)
-        st, out_l, ls, lp_l = paxos_tick_impl(
-            st, ib_l, -1, exec_budget, lease=ls,
-            lease_horizon=lease_horizon)
-        rst, out_r, rls, lp_r = paxos_tick_impl(
-            rst, ib_r, -1, exec_budget, lease=rls,
-            lease_horizon=lease_horizon)
-        packed = jnp.concatenate([
-            _compact_outbox_impl(out_l, scat_budget, lag_budget),
-            _compact_outbox_impl(out_r, scat_budget, lag_budget),
-        ])
-        waits = (jnp.sum(lp_l[LP_WAIT]) + jnp.sum(lp_r[LP_WAIT])).astype(I32)
-        return (st, rst, ls, rls, (lp_l, lp_r)), (packed, waits)
-
-    (state, rstate, lease, rlease, lp_last), (packs, waits) = jax.lax.scan(
-        body, (state, rstate, lease, rlease, lp0), xs)
-    return state, rstate, lease, rlease, packs, lp_last, waits
-
-
-replay_scan_ticks_mixed_lease = jax.jit(
-    _replay_scan_mixed_lease_impl, static_argnums=(5, 6, 7, 8, 9))
+#: K journaled ticks of whatever planes are held in one device program:
+#: ``(planes, packs[K, total], (lease_pack, rlease_pack), waits[K])``.
+#: ``params.compact`` must be set; the exec columns are ``scat_budget`` wide.
+#: With a register plane the two compact buffers ride one
+#: ``[total_l + total_r]`` row (the host slices per plane via
+#: ``CompactLayout``).  The lease packs are the FINAL tick's (the host
+#: mirror only ever holds the latest) and ``waits`` the per-tick fence
+#: counts for the metric; both None without a lease.
+replay_scan_ticks = jax.jit(_replay_scan_impl, static_argnums=(2, 3, 4))
 
 
 # --------------------------------------------------------------------------
@@ -1914,12 +1814,9 @@ replay_scatter_rows = jax.jit(_scatter_rows_impl)
 
 # --------------------------------------------------------------------------
 # Group-health plane (ISSUE 18): the host side of the health fold above —
-# the flat health_pack layout, its unpack, the composite-plane merge, and
-# the single generic health tick entry point that covers every dispatch
-# combination (compact/packed x lease/plain x mixed/single) without a
-# twin-per-combination explosion.  Health-off builds never import any of
-# this into their dispatch: the off program is the literal pre-health
-# function, bit for bit.
+# the flat health_pack layout, its unpack and the composite-plane merge.
+# A build without health hands the served tick no health columns, and the
+# fold is not in its program.
 # --------------------------------------------------------------------------
 
 
@@ -2012,66 +1909,3 @@ def merge_health(hv_l: HealthView, hv_r: HealthView, g_log: int,
         churn_val=cv, churn_row=cr,
         heat_val=hv, heat_row=hr,
     )
-
-
-def _paxos_tick_health_impl(state, rstate, lease, rlease, health, rhealth,
-                            inbox: TickInbox, own_row: int, exec_budget: int,
-                            lag_budget: int, lease_horizon: int,
-                            compact: bool, wedge_ticks: int,
-                            decay_shift: int, topk: int):
-    """The one health-build tick program: ticks the log plane (and the
-    register plane when ``rstate`` is present), folds lease columns when
-    present, folds health columns per plane, and packs the outbox compact
-    or full per the static ``compact`` flag.  Absent planes/folds pass
-    None and collapse out of the traced program (the empty-pytree
-    property), so one jit covers the whole dispatch tree the non-health
-    manager spells out explicitly.
-
-    Returns a fixed 12-tuple
-    ``(state, rstate, lease, rlease, health, rhealth,
-       out_l, out_r, lp_l, lp_r, hp_l, hp_r)``
-    with None in every absent position."""
-
-    def _plane(st, ib, le, he, k):
-        res = paxos_tick_impl(
-            st, ib, own_row, exec_budget, lease=le,
-            lease_horizon=lease_horizon, health=he, wedge_ticks=wedge_ticks,
-            health_decay_shift=decay_shift, health_topk=k,
-        )
-        st2, out = res[0], res[1]
-        i = 2
-        le2 = lp = he2 = hp = None
-        if le is not None:
-            le2, lp = res[i], res[i + 1]
-            i += 2
-        if he is not None:
-            he2, hp = res[i], res[i + 1]
-        pk = (_compact_outbox_impl(out, exec_budget, lag_budget)
-              if compact else pack_outbox_impl(out))
-        return st2, le2, he2, pk, lp, hp
-
-    g_log = state.exec_slot.shape[1]
-    if rstate is not None:
-        ib_l, ib_r = _split_inbox(inbox, g_log)
-        k_r = min(topk, rstate.exec_slot.shape[1])
-    else:
-        ib_l, ib_r = inbox, None
-        k_r = 0
-    k_l = min(topk, g_log)
-    state, lease, health, pk_l, lp_l, hp_l = _plane(
-        state, ib_l, lease, health, k_l)
-    pk_r = lp_r = hp_r = None
-    if rstate is not None:
-        rstate, rlease, rhealth, pk_r, lp_r, hp_r = _plane(
-            rstate, ib_r, rlease, rhealth, k_r)
-    return (state, rstate, lease, rlease, health, rhealth,
-            pk_l, pk_r, lp_l, lp_r, hp_l, hp_r)
-
-
-#: health twin covering every single-device dispatch combination.  Note
-#: the per-plane top-K is ``min(topk, G_plane)`` — the host unpacks with
-#: the same clamp (see ``PaxosManager._adopt_health_pack``).
-paxos_tick_health = jax.jit(
-    _paxos_tick_health_impl, donate_argnums=(0, 1, 2, 3, 4, 5),
-    static_argnums=(7, 8, 9, 10, 11, 12, 13, 14),
-)

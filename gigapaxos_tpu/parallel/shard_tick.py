@@ -49,8 +49,10 @@ jitted functions carry the names a trace finds them by:
 ``jit_mesh_demand_fold``
     the placement plane's demand EWMA, only with ``cfg.placement.enabled``.
 
-The one-device programs' names (``jit__paxos_tick*``) match none of these,
-so a metric that reads them reads nothing on a mesh, and the other way round.
+The one-device program's name (``jit__paxos_tick_planes_impl``, the one
+served entry ``ops.tick.paxos_tick_planes``; readers match
+``^jit__?paxos_tick``) matches none of these, so a metric that reads it
+reads nothing on a mesh, and the other way round.
 
 What the two-dispatch structure costs was measured on a v5e-4 host at 1M
 groups (PERF.md sections 5 and 6, ``probe-1m-mesh4-open1k``): the tick

@@ -54,16 +54,11 @@ from .bulkstore import BulkOverrun, BulkStore
 from .paystore import PayloadStore
 from ..ops.pallas_gather import check_lanes
 from ..ops.tick import (LP_ASN, LP_EPOCH, LP_HOLDER, LP_UNTIL, LP_WAIT,
-                        CompactHostOutbox, HostOutbox, TickInbox,
-                        compact_path, frontier_rows, health_clear_rows,
-                        init_health, lease_clear_rows, merge_compact_outbox,
-                        merge_health, merge_outbox, paxos_tick_compact,
-                        paxos_tick_compact_demand, paxos_tick_compact_lease,
-                        paxos_tick_health, paxos_tick_mixed_compact,
-                        paxos_tick_mixed_compact_lease,
-                        paxos_tick_mixed_packed,
-                        paxos_tick_mixed_packed_lease, paxos_tick_packed,
-                        paxos_tick_packed_lease, sweep_frontier,
+                        CompactHostOutbox, HostOutbox, TickInbox, TickParams,
+                        TickPlanes, compact_path, frontier_rows,
+                        health_clear_rows, init_health, lease_clear_rows,
+                        merge_compact_outbox, merge_health, merge_outbox,
+                        one_or_pair, paxos_tick_planes, sweep_frontier,
                         unpack_compact, unpack_health, unpack_outbox)
 
 
@@ -117,7 +112,7 @@ class PaxosManager:
         # in-place consensus registers.  Composite row space: [0, G) log
         # rows, [G, G_total) register rows — the row index IS the mode bit,
         # so every row-keyed host structure below is sized G_total and the
-        # two device planes stay separate jit inputs (mixed tick splits the
+        # two device planes stay separate jit inputs (the tick splits the
         # composite inbox at the static boundary).  G_reg == 0 keeps every
         # structure and code path bit-identical to pre-register builds.
         self.G_reg = cfg.paxos.register_groups
@@ -364,24 +359,22 @@ class PaxosManager:
             elif self._use_compact and not self._device_app \
                     and not self.G_reg and not cfg.paxos.read_leases \
                     and not cfg.paxos.group_health:
-                # single-device compact path: the intake-popcount fold runs
-                # fused inside paxos_tick_compact_demand (no mesh, so the
-                # GSPMD same-jit hazard doesn't apply) instead of the old
-                # O(G*P) host popcount per tick in _process_compact.
-                # Mixed planes keep the host fold: placement demand covers
-                # the LOG plane only (register rows never migrate shards).
-                # Lease builds keep the host fold too — the lease tick
-                # variants carry lease state instead of the demand array —
-                # as do health builds (the generic health twin has no
-                # demand formulation).
+                # one device, compact: the intake-popcount fold runs inside
+                # the served tick (``TickPlanes.demand``) instead of as an
+                # O(G*P) host popcount per tick in _process_compact.  The
+                # tick would fold it beside any other plane; register, lease
+                # and health builds keep the host fold only because moving
+                # them is a change of behaviour nobody has asked for yet
+                # (ROADMAP C1).  Demand covers the LOG plane only: register
+                # rows never migrate shards.
                 self._demand_dev = jnp.zeros(self.G, jnp.float32)
         # ---- leader-lease plane (ISSUE 17) ----
         # Dense [G]/[G_reg] lease columns folded inside the fused tick:
         # holder/epoch/until live on device (authoritative for the write
         # fence); the host keeps a per-tick [5, G_total] mirror
         # (_lease_np) + its own lockstep clock for the local-read validity
-        # check.  None when off — lease-off builds run the literal
-        # pre-lease tick programs, bit for bit.
+        # check.  None when off: absent from the tick's planes, and the
+        # fold from its program.
         self._lease = None
         self._rlease = None
         self._lease_np = None         # [5, G_total] lease_pack mirror
@@ -412,7 +405,7 @@ class PaxosManager:
         # tick; the host consumes only an O(K) health pack per tick (scalar
         # gauges + log2 histograms + top-K anomaly rows).  Observation-only:
         # nothing here feeds back into consensus, and with the flag off the
-        # tick programs are the literal pre-health functions, bit for bit.
+        # columns are None and the fold is not in the tick's program.
         self._health = None
         self._rhealth = None
         self._health_view = None      # HealthView as of last completed tick
@@ -2199,6 +2192,44 @@ class PaxosManager:
             if name:
                 self.sync_laggard(r_, name)
 
+    def tick_program(self, inbox, kv_reg=None):
+        """The program one tick of this manager dispatches and the arguments
+        it is called with, ``fn(*args)``: the one place that decides it,
+        from what the manager holds.  The device app has its fused program,
+        a mesh its sharded pair of dispatches behind one callable, every
+        other build the one served tick over the planes it holds.  Replay
+        (``wal/logger.py``) runs the same planes through the same entry;
+        ``chip_smoke.py`` asks here for the program to inspect."""
+        if self._device_app:
+            from ..models.device_kv import fused_compact
+
+            if kv_reg is None:
+                kv_reg = [np.zeros(self._kv_reg_budget, np.int32)] * 4
+            return fused_compact, (self.state, self.kv, inbox, *kv_reg, -1,
+                                   self._exec_budget, self._lag_budget)
+        if self.mesh is not None:
+            fn = self._mesh_tick_compact or self._mesh_tick
+            if self._demand_dev is not None:
+                return fn, (self.state, inbox, self._demand_dev)
+            return fn, (self.state, inbox)
+        return paxos_tick_planes, (
+            TickPlanes(self.state, self.rstate, self._lease, self._rlease,
+                       self._health, self._rhealth, self._demand_dev),
+            inbox, self.tick_params())
+
+    def tick_params(self) -> TickParams:
+        """The static half of this manager's served tick."""
+        return TickParams(
+            own_row=-1,
+            exec_budget=self._exec_budget if self._use_compact else 0,
+            lag_budget=self._lag_budget, compact=self._use_compact,
+            lease_horizon=self._lease_horizon,
+            wedge_ticks=self._health_wedge,
+            health_decay_shift=self._health_shift,
+            health_topk=self._health_topk,
+            demand_decay=(self._placement.decay
+                          if self._demand_dev is not None else 0.0))
+
     @_locked
     def tick(self):
         """One manager step.  Returns the tick's :class:`HostOutbox` (full
@@ -2223,6 +2254,7 @@ class PaxosManager:
                 + (self.bulk.n_live if self.bulk is not None else 0))
         self._run_due_laggard_syncs()
         pc.mark("repair")
+        reg = None
         if self._device_app:
             # descriptor upload rides the same fused program as the tick;
             # watermark must advance BEFORE the build so those rids place
@@ -2243,116 +2275,43 @@ class PaxosManager:
         # while the WAL appends+fsyncs this tick's record (SURVEY §2.2 item 3,
         # the BatchedLogger overlap, AbstractPaxosLogger.java:99-107).  Safe
         # because responses stay held until is_synced() (log-before-respond).
-        if self._health is not None:
-            # health builds: ONE generic jit covers every single-device
-            # combination (compact/packed x lease x mixed planes) — absent
-            # planes pass None and collapse out of the traced program.
-            # device_app and mesh raise at init, so they never reach here.
-            (self.state, self.rstate, self._lease, self._rlease,
-             self._health, self._rhealth, pk_l, pk_r, lp_l, lp_r,
-             hp_l, hp_r) = paxos_tick_health(
-                self.state, self.rstate, self._lease, self._rlease,
-                self._health, self._rhealth, inbox, -1,
-                self._exec_budget if self._use_compact else 0,
-                self._lag_budget, self._lease_horizon,
-                self._use_compact, self._health_wedge,
-                self._health_shift, self._health_topk)
-            packed = pk_l if pk_r is None else (pk_l, pk_r)
-            if lp_l is not None:
-                lease_pack = lp_l if lp_r is None else (lp_l, lp_r)
-            health_pack = hp_l if hp_r is None else (hp_l, hp_r)
-        elif self._device_app:
-            from ..models.device_kv import fused_compact
-
-            self.state, self.kv, packed = fused_compact(
-                self.state, self.kv, inbox, *reg, -1,
-                self._exec_budget, self._lag_budget,
-            )
-        elif self._mesh_tick_compact is not None:
+        fn, args = self.tick_program(inbox, reg)
+        res = fn(*args)
+        # Let go of the donated planes HERE, not when this call returns:
+        # the assignments below then drop the last references to them
+        # inside this phase.  Measured at 1M on the chip (PERF.md section
+        # 6, PR 32): that blocks about 12 ms (the program taking its
+        # inputs) which the wait at completion is then shorter by, and the
+        # whole tick is 6-16 ms shorter than when they are held until the
+        # outbox is done.
+        del args
+        if self._device_app:
+            self.state, self.kv, packed = res
+        elif self.mesh is not None:
             # numpy inbox: committed to the mesh layout by in_shardings on
             # entry, as is the state after any eager admin-op mutation
-            if self._demand_dev is not None:
+            self.state, packed, *demand = res
+            if demand:
                 # placement: the demand EWMA folds inside the compact
                 # dispatch (decided_now is donated away otherwise)
-                self.state, packed, self._demand_dev = (
-                    self._mesh_tick_compact(self.state, inbox,
-                                            self._demand_dev)
-                )
+                self._demand_dev, = demand
                 self._placement.adopt_device(self._demand_dev)
                 self._mesh_dispatch_c["fold"].inc()
-            else:
-                self.state, packed = self._mesh_tick_compact(self.state, inbox)
             self._mesh_dispatch_c["tick"].inc()
-            self._mesh_dispatch_c["compact"].inc()
-        elif self._mesh_tick is not None:
-            self.state, packed = self._mesh_tick(self.state, inbox)
-            self._mesh_dispatch_c["tick"].inc()
-        elif self._use_compact:
-            if self._lease is not None and self.rstate is not None:
-                # lease twin of the mixed compact tick: both planes fold
-                # their own lease columns; the [5, G] lease packs ride the
-                # pending tuple and are pulled at completion
-                (self.state, self.rstate, self._lease, self._rlease,
-                 flat_l, flat_r, lp_l, lp_r) = paxos_tick_mixed_compact_lease(
-                    self.state, self.rstate, self._lease, self._rlease,
-                    inbox, -1, self._exec_budget, self._lag_budget,
-                    self._lease_horizon,
-                )
-                packed = (flat_l, flat_r)
-                lease_pack = (lp_l, lp_r)
-            elif self._lease is not None:
-                self.state, self._lease, packed, lease_pack = (
-                    paxos_tick_compact_lease(
-                        self.state, self._lease, inbox, -1,
-                        self._exec_budget, self._lag_budget,
-                        self._lease_horizon,
-                    )
-                )
-            elif self.rstate is not None:
-                # mixed planes: one fused program splits the composite
-                # inbox at g_log, ticks both planes with their native W
-                # (log ring vs register), and compacts each — merged back
-                # into one composite outbox at completion
-                self.state, self.rstate, flat_l, flat_r = (
-                    paxos_tick_mixed_compact(
-                        self.state, self.rstate, inbox, -1,
-                        self._exec_budget, self._lag_budget,
-                    )
-                )
-                packed = (flat_l, flat_r)
-            elif self._demand_dev is not None:
-                # placement: the intake-demand EWMA folds on device inside
-                # the fused program (the mesh path's separate-dispatch twin
-                # lives in make_shardmap_tick_compact)
-                self.state, packed, self._demand_dev = (
-                    paxos_tick_compact_demand(
-                        self.state, inbox, self._demand_dev, -1,
-                        self._exec_budget, self._lag_budget,
-                        self._placement.decay,
-                    )
-                )
-                self._placement.adopt_device(self._demand_dev)
-            else:
-                self.state, packed = paxos_tick_compact(
-                    self.state, inbox, -1, self._exec_budget, self._lag_budget
-                )
-        elif self._lease is not None and self.rstate is not None:
-            (self.state, self.rstate, self._lease, self._rlease,
-             pk_l, pk_r, lp_l, lp_r) = paxos_tick_mixed_packed_lease(
-                self.state, self.rstate, self._lease, self._rlease,
-                inbox, -1, 0, self._lease_horizon)
-            packed = (pk_l, pk_r)
-            lease_pack = (lp_l, lp_r)
-        elif self._lease is not None:
-            self.state, self._lease, packed, lease_pack = (
-                paxos_tick_packed_lease(self.state, self._lease, inbox, -1,
-                                        0, self._lease_horizon))
-        elif self.rstate is not None:
-            self.state, self.rstate, pk_l, pk_r = paxos_tick_mixed_packed(
-                self.state, self.rstate, inbox, -1, 0)
-            packed = (pk_l, pk_r)
+            if self._mesh_tick_compact is not None:
+                self._mesh_dispatch_c["compact"].inc()
         else:
-            self.state, packed = paxos_tick_packed(self.state, inbox, -1)
+            planes, packs = res
+            (self.state, self.rstate, self._lease, self._rlease,
+             self._health, self._rhealth, demand) = planes
+            if demand is not None:
+                self._demand_dev = demand
+                self._placement.adopt_device(demand)
+            # a register plane makes each a (log, register) pair, pulled
+            # and merged into composite rows at completion
+            packed = one_or_pair(packs.out, packs.rout)
+            lease_pack = one_or_pair(packs.lease_pack, packs.rlease_pack)
+            health_pack = one_or_pair(packs.health_pack, packs.rhealth_pack)
         # Device sweep frontier: computed ONLY at the dispatch of a tick
         # whose completion runs _sweep_outstanding (1 in 64 ticks, by the
         # tick's own number: whichever call completes it), from THIS tick's

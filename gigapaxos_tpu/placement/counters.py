@@ -10,10 +10,10 @@ Two intake paths feed one facade:
   (``d' = decay*d + decided_now``) in a separate elementwise dispatch,
   ``P(GROUPS_AXIS)``-sharded (see the GSPMD note in
   ``parallel/shard_tick.py``); the single-device path fuses the equivalent
-  per-row intake fold (``sum(intake_taken)`` — what the host popcount used
-  to compute from ``taken_bits``) straight into the tick program
-  (``ops.tick.paxos_tick_compact_demand``), which no GSPMD hazard forbids
-  there.
+  per-row intake fold (``sum(intake_taken)`` — what the host popcount
+  computes from ``taken_bits``) straight into the served tick
+  (``ops.tick.paxos_tick_planes``, the ``demand`` member of its planes),
+  which no GSPMD hazard forbids there.
 * **Host fold** (full-outbox path, and the device-app compact path whose
   fused program predates the fold): the host sees per-row intake
   (``intake_taken`` sums, or ``taken_bits`` popcounts in compact mode), so

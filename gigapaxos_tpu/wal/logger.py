@@ -1282,7 +1282,7 @@ class _BatchedReplay:
 
     Buffers decoded OP_TICK records and, K at a time, flattens them into a
     :class:`~gigapaxos_tpu.wal.columnar.TickSlab`, ships the window as
-    padded COO columns through one ``replay_scan_ticks*`` program, then
+    padded COO columns through the one ``replay_scan_ticks`` program, then
     runs the host fold strictly in tick order over the per-tick compact
     rows.  The host ordering is the invariant that buys bit-identity with
     the reference arm: the device work for all K ticks is journal-
@@ -1313,7 +1313,7 @@ class _BatchedReplay:
         self.lease = m._lease is not None
         # state must evolve EXACTLY as the live run's did (same budget
         # semantics as the reference arm's tick closure)
-        self.exec_budget = m._exec_budget if m._use_compact else 0
+        self.params = m.tick_params()._replace(compact=True)
         self.scat = max(m._exec_budget, _REPLAY_SCAT_MIN)
         self.lagb = m._lag_budget
         self.g_log = m.G
@@ -1356,10 +1356,7 @@ class _BatchedReplay:
 
     def _run_window(self, chunk) -> None:
         from .columnar import build_tick_slab, coo_window
-        from ..ops.tick import (LP_HOLDER, replay_scan_ticks,
-                                replay_scan_ticks_lease,
-                                replay_scan_ticks_mixed,
-                                replay_scan_ticks_mixed_lease)
+        from ..ops.tick import LP_HOLDER, TickPlanes, replay_scan_ticks
 
         m = self.m
         K = len(chunk)
@@ -1382,23 +1379,9 @@ class _BatchedReplay:
             for t in range(K):
                 self._reference_tick(slab, t)
             return
-        rst = ls = rls = lp_last = waits = None
-        if self.mixed and self.lease:
-            (st, rst, ls, rls, packs, lp_last,
-             waits) = replay_scan_ticks_mixed_lease(
-                m.state, m.rstate, m._lease, m._rlease, xs, m.P,
-                self.exec_budget, self.scat, self.lagb, m._lease_horizon)
-        elif self.lease:
-            st, ls, packs, lp_last, waits = replay_scan_ticks_lease(
-                m.state, m._lease, xs, m.P, self.exec_budget, self.scat,
-                self.lagb, m._lease_horizon)
-        elif self.mixed:
-            st, rst, packs = replay_scan_ticks_mixed(
-                m.state, m.rstate, xs, m.P, self.exec_budget, self.scat,
-                self.lagb)
-        else:
-            st, packs = replay_scan_ticks(
-                m.state, xs, m.P, self.exec_budget, self.scat, self.lagb)
+        planes, packs, lp_last, waits = replay_scan_ticks(
+            TickPlanes(m.state, m.rstate, m._lease, m._rlease), xs, m.P,
+            self.params, self.scat)
         packs = np.asarray(packs)
         over = packs[:, 0] > self.scat
         if self.mixed:
@@ -1410,22 +1393,14 @@ class _BatchedReplay:
             for t in range(K):
                 self._reference_tick(slab, t)
             return
-        m.state = st
-        if rst is not None:
-            m.rstate = rst
-        if ls is not None:
-            m._lease = ls
-            if rls is not None:
-                m._rlease = rls
+        m.state, m.rstate, m._lease, m._rlease = planes[:4]
+        if self.lease:
             # the host mirror only ever holds the latest pack, so adopt
             # the FINAL tick's; the clock advances K in lockstep with the
             # device fold, and waits accumulate per tick (scan summed them)
-            if isinstance(lp_last, tuple):
-                lp = np.concatenate([np.asarray(lp_last[0]),
-                                     np.asarray(lp_last[1])], axis=1)
-            else:
-                lp = np.asarray(lp_last)
-            m._lease_np = lp.copy()
+            lp = np.concatenate([np.asarray(p) for p in lp_last
+                                 if p is not None], axis=1)
+            m._lease_np = lp
             m._lease_clock += K
             m._lease_gauge.set(int((lp[LP_HOLDER] >= 0).sum()))
             w = int(np.asarray(waits).sum())
@@ -1466,23 +1441,19 @@ class _BatchedReplay:
         caller re-runs the window record-at-a-time)."""
         import jax.numpy as jnp
 
-        from ..ops.tick import (replay_gather_rows, replay_scan_ticks,
-                                replay_scan_ticks_mixed,
-                                replay_scatter_rows)
+        from ..ops.tick import (TickPlanes, replay_gather_rows,
+                                replay_scan_ticks, replay_scatter_rows)
 
         m = self.m
         xs = dict(xs, g=sp.inv[xs["g"]])
         rows_l = jnp.asarray(sp.rows_l, jnp.int32)
         cst = replay_gather_rows(m.state, rows_l)
+        crst = None
         if self.mixed:
             rows_r = jnp.asarray(sp.rows_r, jnp.int32)
             crst = replay_gather_rows(m.rstate, rows_r)
-            st, rst, packs = replay_scan_ticks_mixed(
-                cst, crst, xs, m.P, self.exec_budget, self.scat,
-                self.lagb)
-        else:
-            st, packs = replay_scan_ticks(
-                cst, xs, m.P, self.exec_budget, self.scat, self.lagb)
+        (st, rst, *_), packs, _, _ = replay_scan_ticks(
+            TickPlanes(cst, crst), xs, m.P, self.params, self.scat)
         packs = np.asarray(packs)
         over = packs[:, 0] > self.scat
         if self.mixed:
@@ -1574,7 +1545,7 @@ def replay_journals_batched(m, log_dir, start_seq, make_record, new_buffers,
 
     Identical decode, payref resolution, staging and host fold as
     :func:`replay_journals`, but OP_TICK records are buffered and shipped
-    to the device K at a time through the ``replay_scan_ticks*`` programs
+    to the device K at a time through the ``replay_scan_ticks`` program
     — one dispatch and one ``[K, total]`` compact pull per window instead
     of one round trip per tick.  Admin ops are batch barriers: they
     mutate rows/state outside the tick body, so buffered ticks flush
@@ -1658,7 +1629,7 @@ def recover(cfg, n_replicas: int, apps, log_dir: str, native: bool = True,
     import jax.numpy as jnp
 
     from ..paxos.manager import PaxosManager, RequestRecord
-    from ..ops.tick import TickInbox, paxos_tick_packed, unpack_outbox
+    from ..ops.tick import TickInbox, unpack_outbox
 
     logger = PaxosLogger(
         log_dir, native=native,
@@ -1840,44 +1811,30 @@ def recover(cfg, n_replicas: int, apps, log_dir: str, native: bool = True,
             state, out = mesh_tick(state, inbox)
             return state, fetch_host_outbox(out)
     else:
+        from ..ops.tick import (TickPlanes, merge_outbox, one_or_pair,
+                                paxos_tick_planes)
+
+        # replay must evolve state EXACTLY as the live run did: the same
+        # entry over the same planes under the live run's exec budget, with
+        # the full outbox (which replay consumes) in place of the compact
+        # one.  The lease fold is a pure function of (state, inbox), so the
+        # lease columns re-evolve tick for tick; health and demand are
+        # observations and are not replayed.
+        params = m.tick_params()._replace(compact=False)
+
         def tick_host(state, inbox):
-            # replay must evolve state EXACTLY as the live run did, so the
-            # exec budget (if the live run used the compact path) applies
-            # here too even though replay consumes the full outbox — and a
-            # lease-era run replays through the lease tick variants, whose
-            # fold is a pure function of (state, inbox), so the lease
-            # columns re-evolve tick for tick
-            budget = m._exec_budget if m._use_compact else 0
-            if m._lease is not None and m.rstate is not None:
-                from ..ops.tick import (merge_outbox,
-                                        paxos_tick_mixed_packed_lease)
-
-                (state, m.rstate, m._lease, m._rlease, pk_l, pk_r,
-                 lp_l, lp_r) = paxos_tick_mixed_packed_lease(
-                    state, m.rstate, m._lease, m._rlease, inbox, -1,
-                    budget, m._lease_horizon)
-                m._adopt_lease_pack((lp_l, lp_r))
-                out_l = unpack_outbox(pk_l, m.R, m.P, m.W, m.G)
-                out_r = unpack_outbox(pk_r, m.R, m.P, 1, m.G_reg)
-                return state, merge_outbox(out_l, out_r)
-            if m._lease is not None:
-                from ..ops.tick import paxos_tick_packed_lease
-
-                state, m._lease, packed, lp = paxos_tick_packed_lease(
-                    state, m._lease, inbox, -1, budget, m._lease_horizon)
-                m._adopt_lease_pack(lp)
-                return state, unpack_outbox(packed, m.R, m.P, m.W, m.G)
-            if m.rstate is not None:
-                from ..ops.tick import (merge_outbox,
-                                        paxos_tick_mixed_packed)
-
-                state, m.rstate, pk_l, pk_r = paxos_tick_mixed_packed(
-                    state, m.rstate, inbox, -1, budget)
-                out_l = unpack_outbox(pk_l, m.R, m.P, m.W, m.G)
-                out_r = unpack_outbox(pk_r, m.R, m.P, 1, m.G_reg)
-                return state, merge_outbox(out_l, out_r)
-            state, packed = paxos_tick_packed(state, inbox, -1, budget)
-            return state, unpack_outbox(packed, m.R, m.P, m.W, m.G)
+            planes, packs = paxos_tick_planes(
+                TickPlanes(state, m.rstate, m._lease, m._rlease), inbox,
+                params)
+            state, m.rstate, m._lease, m._rlease = planes[:4]
+            if packs.lease_pack is not None:
+                m._adopt_lease_pack(
+                    one_or_pair(packs.lease_pack, packs.rlease_pack))
+            out = unpack_outbox(packs.out, m.R, m.P, m.W, m.G)
+            if packs.rout is not None:
+                out = merge_outbox(
+                    out, unpack_outbox(packs.rout, m.R, m.P, 1, m.G_reg))
+            return state, out
 
     def bulk_replay(m, bufs, bulk_rec):
         rids_b, be_b, bp_b, br_b, stop_b, payloads = bulk_rec

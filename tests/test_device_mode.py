@@ -252,8 +252,9 @@ def test_compact_layout_single_source_of_truth():
 
     from gigapaxos_tpu.models.device_kv import (OP_PUT, fused_compact,
                                                 init_kv, register_requests)
-    from gigapaxos_tpu.ops.tick import (CompactLayout, TickInbox,
-                                        paxos_tick_compact, unpack_compact)
+    from gigapaxos_tpu.ops.tick import (CompactLayout, TickInbox, TickParams,
+                                        TickPlanes, paxos_tick_planes,
+                                        unpack_compact)
     from gigapaxos_tpu.paxos import state as st
 
     R, G, W, E, Lb = 3, 8, 8, 64, 64
@@ -272,8 +273,10 @@ def test_compact_layout_single_source_of_truth():
     req[0, 0, 0] = 77
     inbox = TickInbox(jnp.asarray(req), jnp.zeros((R, 2, G), bool),
                       jnp.ones(R, bool))
-    s2, packed = paxos_tick_compact(s, inbox, -1, E, Lb)
-    assert np.asarray(packed).shape[0] == L.total_plain
+    _, packs = paxos_tick_planes(
+        TickPlanes(s), inbox,
+        TickParams(exec_budget=E, lag_budget=Lb, compact=True))
+    assert np.asarray(packs.out).shape[0] == L.total_plain
 
     # device-app buffer: total_device, and the response round-trips
     kv = init_kv(R, G, slots=8, table=1 << 16)

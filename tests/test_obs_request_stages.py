@@ -230,13 +230,17 @@ def lowered():
     def text(fn, *args):
         return fn.lower(*args).as_text(debug_info=True)
 
-    compact = text(tk.paxos_tick_compact, state, inbox, -1, E, Lb)
+    params = tk.TickParams(exec_budget=E, lag_budget=Lb, compact=True,
+                           lease_horizon=64)
+
+    def served(**planes):
+        return text(tk.paxos_tick_planes, tk.TickPlanes(state, **planes),
+                    inbox, params)
+
+    compact = served()
     texts = {scope: compact for scope in TICK_SCOPES}
-    texts["lease_fold"] = text(tk.paxos_tick_compact_lease, state, lease,
-                               inbox, -1, E, Lb, 64)
-    texts["health_fold"] = text(
-        tk.paxos_tick_health, state, None, None, None, health, None, inbox,
-        -1, E, Lb, 64, True, 32, 6, 8)
+    texts["lease_fold"] = served(lease=lease)
+    texts["health_fold"] = served(health=health)
     texts["sweep_frontier"] = text(tk.sweep_frontier, S((R, G)),
                                    S((R, G), jnp.bool_), S((R,), jnp.bool_))
     texts["frontier_rows"] = text(tk.frontier_rows, S((G,)), S((G,)),

@@ -111,6 +111,22 @@ def test_delete(cluster, client):
     assert client.request("gone", b"GET x") == b"NF"
 
 
+def _request_across_epochs(client, name: str, payload: bytes,
+                           deadline: float) -> bytes:
+    """``client.request``, asked again while the name is between epochs: the
+    client's own chase is four tries, which a migration on a box shared
+    with five other test workers can outlast (``TimeoutError: stopped``).
+    The payloads here are idempotent, as that method's docstring asks."""
+    import time
+
+    while True:
+        try:
+            return client.request(name, payload)
+        except TimeoutError:
+            if time.monotonic() >= deadline:
+                raise
+
+
 def test_demand_driven_migration(cluster, client):
     """RateBasedMigrationPolicy(migrate_after=25): enough requests must
     trigger a primary-RC-driven migration without any client involvement."""
@@ -118,19 +134,24 @@ def test_demand_driven_migration(cluster, client):
 
     assert client.create("hot")["ok"]
     before = set(client.request_actives("hot"))
+    # every wait below returns as soon as what it waits for holds; the
+    # deadline is sized for six xdist workers on one box, not an idle one
+    # (the migration itself is seconds of wall-clock protocol timers)
+    deadline = time.monotonic() + 120
     for i in range(40):
-        client.request("hot", f"PUT k{i} {i}".encode())
-    deadline = time.monotonic() + 20
+        # the migration starts inside this loop (at the 25th request)
+        _request_across_epochs(client, "hot", f"PUT k{i} {i}".encode(),
+                               deadline)
     after = before
     while time.monotonic() < deadline:
         after = set(client.request_actives("hot", force=True))
         if after != before:
             break
-        client.request("hot", b"GET k0")
+        _request_across_epochs(client, "hot", b"GET k0", deadline)
         time.sleep(0.25)
     assert after != before, "demand-driven migration never happened"
     # data survived
-    assert client.request("hot", b"GET k1") == b"1"
+    assert _request_across_epochs(client, "hot", b"GET k1", deadline) == b"1"
 
 
 def test_batched_creates(cluster, client):

@@ -72,19 +72,25 @@ m_state = shaped(jax.eval_shape(lambda: st.init_state(R, G, W)),
                  pmesh.state_shardings(mesh))
 m_inbox = shaped(inbox(G), pmesh.inbox_shardings(mesh))
 
+params = tk.TickParams(exec_budget=E, lag_budget=Lb, compact=True,
+                       lease_horizon=64)
+
+
+def served(g, **planes):
+    return (tk.TickPlanes(state, **planes), inbox(g), params)
+
+
 programs = [
     # (name, jitted fn, args, Pallas calls it must carry)
-    ("log-plane compact tick", tk.paxos_tick_compact,
+    ("log-plane compact tick", tk.paxos_tick_planes, served(G), 26),
+    ("the kept name the harness traces", tk.paxos_tick_compact,
      (state, inbox(G), -1, E, Lb), 26),
-    ("mixed log+register tick (W=4 and W=1)", tk.paxos_tick_mixed_compact,
-     (state, rstate, inbox(2 * G), -1, E, Lb), 52),
-    ("lease tick", tk.paxos_tick_compact_lease,
-     (state, lease, inbox(G), -1, E, Lb, 64), 26),
-    ("generic health tick", tk.paxos_tick_health,
-     (state, None, None, None, health, None, inbox(G), -1, E, Lb, 64, True,
-      32, 6, 8), 26),
+    ("mixed log+register tick (W=4 and W=1)", tk.paxos_tick_planes,
+     served(2 * G, rstate=rstate), 52),
+    ("lease tick", tk.paxos_tick_planes, served(G, lease=lease), 26),
+    ("health tick", tk.paxos_tick_planes, served(G, health=health), 26),
     ("replay scan, sparse window of 128 lanes", tk.replay_scan_ticks,
-     (narrow, xs, P, E, E, Lb), 26),
+     (tk.TickPlanes(narrow), xs, P, params, E), 26),
     ("shard_map tick over four chips", stk.make_shardmap_tick(mesh, -1, E),
      (m_state, m_inbox), 26),
 ]

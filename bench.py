@@ -88,7 +88,7 @@ def _profile_phases(R, G, W, P, reps=8, exec_budget=4096, lag_budget=1024):
     t_full, (post, out) = timed(p_full, state, inbox)
 
     p_pack = jax.jit(
-        lambda o: _compact_outbox_impl(o, exec_budget, lag_budget)
+        lambda o: _compact_outbox_impl(o, exec_budget, lag_budget).flat
     )
     t_pack, packed = timed(p_pack, out)
 

@@ -336,13 +336,17 @@ def _wave(log: Log, cluster, what: str, rows, payloads,
 
     def sides() -> dict:
         """The plane's dispatched ticks by where their outbox was completed
-        (``pipeline_ticks`` is on: a tick may hold it for the next call)."""
+        (``pipeline_ticks`` is on: a tick may hold it for the next call),
+        and its outbox buffers by what was pulled (the head, or the flat
+        buffer whole where a tick decided more than the head holds)."""
         from gigapaxos_tpu.obs.metrics import registry
 
         snap = registry().snapshot()  # the cluster's data plane is "ar"
-        return {mode: snap.get(
-            "tick_completions_total{mode=%s,plane=ar}" % mode, 0)
-            for mode in ("same_call", "held")}
+        return {key: snap.get(series % key, 0) for series, keys in (
+            ("tick_completions_total{mode=%s,plane=ar}",
+             ("same_call", "held")),
+            ("outbox_pulls_total{plane=ar,pull=%s}", ("head", "full")))
+            for key in keys}
 
     tick0, t0, sides0 = m.tick_num, time.monotonic(), sides()
     rids = m.propose_bulk(rows, payloads, batch_sink=sink)
@@ -355,7 +359,13 @@ def _wave(log: Log, cluster, what: str, rows, payloads,
     log(f"{what}: {n:,} requests admitted at tick {tick0}, completed in "
         f"{time.monotonic() - t0:.2f}s by tick {m.tick_num}, in "
         f"{len(batches)} completion batch(es) (size, tick) {batches[:4]}; "
-        f"ticks since by where their outbox was completed: {took}")
+        f"ticks since by where their outbox was completed, and outbox "
+        f"buffers by pull: {took}")
+    # one tick executes the wave on every replica: past the head, that
+    # tick's outbox is the one pull of the whole flat buffer
+    if m.R * n > m._compact_layout.head_exec:
+        check(took["full"] >= 1, f"{what}: {m.R * n:,} executions in one "
+              f"tick and no outbox pulled whole (pull=full): {took}")
     # completions fire once per entry replica; all from one tick's pass =
     # decided and executed by one tick, hence admitted by one
     ticks = {t for _, t in batches}

@@ -217,7 +217,8 @@ def _fused_compact_impl(state, kv: DeviceKVState, inbox: TickInbox,
     new_state, out = paxos_tick_impl(state, inbox, own_row, exec_budget,
                                      fast_elect=fast_elect)
     kv2, responses, miss = kv_apply(kv, out.exec_req, out.exec_count)
-    packed = _compact_outbox_impl(out, exec_budget, lag_budget)
+    # the extras ride the flat buffer, so the device app pulls that whole
+    packed = _compact_outbox_impl(out, exec_budget, lag_budget).flat
     # responses ride the exec stream's compaction: same mask, same ranks
     R, _, G = out.exec_req.shape
     with jax.named_scope("compact_outbox"):

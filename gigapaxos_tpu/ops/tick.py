@@ -42,7 +42,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import Any, NamedTuple
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -1185,10 +1185,16 @@ def unpack_outbox(flat, R: int, P: int, W: int, G: int) -> HostOutbox:
 # :func:`_compact_columns`' block-sparse branch; above that the dense
 # prefix-sum scatter over the whole plane, which is O(R*W*G) per column
 # whatever was decided (about 330 ms).  The host mirrors which one ran in
-# ``compact_path_ticks_total``.  The transfer is bounded but not
-# O(decisions): the buffer is ``CompactLayout.total_plain`` words however
-# few decided — ``taken_bits`` (R*G words) plus four exec columns of
-# ``exec_budget`` (2G by default) words each, 46 MB a tick at 1M.
+# ``compact_path_ticks_total``.  The flat buffer is bounded but not
+# O(decisions): ``CompactLayout.total_plain`` words however few decided —
+# ``taken_bits`` (R*G words) plus four exec columns of ``exec_budget`` (2G
+# by default) words each, 46 MB at 1M.  So the compaction also returns a
+# HEAD of it (``CompactLayout.total_head`` words, 1.7 MB at 1M: the
+# acceptance bits packed ``32 // P`` groups to a word and the first
+# ``head_exec`` entries of the exec columns), and the host pulls the head
+# alone unless its header says the tick decided more than it holds
+# (``outbox_pulls_total{pull=head|full}``); the flat buffer stays on the
+# device and costs no transfer while nobody pulls it.
 # --------------------------------------------------------------------------
 
 
@@ -1204,7 +1210,11 @@ class CompactHostOutbox(NamedTuple):
     n_exec: int
     decided_total: int
     lag_n: int            # total laggards (may exceed the recorded list)
-    taken_bits: "np.ndarray"  # i32 [R, G], bit p = inbox slot p was taken
+    #: i32 acceptance words AS PULLED; read them through :func:`taken_bit`
+    #: / :func:`taken_dense`.  From the flat buffer: [R, G], bit p of
+    #: (r, g) = inbox slot p was taken.  From the head: [R, Gw], see
+    #: ``taken_shift``.
+    taken_bits: "np.ndarray"
     e_rid: "np.ndarray"   # i32 [n_exec]
     e_rep: "np.ndarray"   # i32 [n_exec]
     e_row: "np.ndarray"   # i32 [n_exec]
@@ -1218,6 +1228,33 @@ class CompactHostOutbox(NamedTuple):
     l_dexec: "np.ndarray"  # i32 — donor's post-tick exec watermark
     l_dstat: "np.ndarray"  # i32 — donor's post-tick group status
     l_lexec: "np.ndarray"  # i32 — the laggard's own post-tick exec watermark
+    #: row g's bits sit in word ``g % Gw`` of its replica, shifted left by
+    #: ``(g // Gw) * taken_shift`` (Gw = ``taken_bits.shape[1]``): P for the
+    #: head's packed words, and of no account for the flat buffer's one
+    #: word per row (g // G is 0)
+    taken_shift: int = 0
+
+
+def taken_bit(co: CompactHostOutbox, entry, row, p):
+    """0/1: the tick took inbox slot ``p`` of ``row`` at replica ``entry``
+    (scalars or index arrays of one shape), whichever buffer ``co`` came
+    from."""
+    k, w = np.divmod(row, co.taken_bits.shape[1])
+    return (co.taken_bits[entry, w] >> (k * co.taken_shift + p)) & 1
+
+
+def taken_dense(co: CompactHostOutbox, G: int) -> "np.ndarray":
+    """The acceptance words one per row, i32 [R, G], as the flat buffer
+    carries them.  O(R*G) host work from a head: for the consumers that
+    need every row (the placement fold, a merge of two planes)."""
+    words = co.taken_bits
+    Gw = words.shape[1]
+    if Gw >= G:
+        return words[:, :G]
+    mask = (1 << co.taken_shift) - 1
+    return np.concatenate(
+        [(words >> (k * co.taken_shift)) & mask for k in range(-(-G // Gw))],
+        axis=1)[:, :G]
 
 
 #: the block-sparse compaction views a flat mask as rows of one lane row
@@ -1307,9 +1344,17 @@ def _exec_mask(out: TickOutbox):
     return ji < out.exec_count[:, None, :]
 
 
+class CompactPack(NamedTuple):
+    """One plane's compacted outbox on the device: the flat buffer and the
+    head the host pulls first (sections: :class:`CompactLayout`)."""
+
+    flat: Any
+    head: Any
+
+
 @_scoped("compact_outbox")
 def _compact_outbox_impl(out: TickOutbox, exec_budget: int,
-                         lag_budget: int) -> jnp.ndarray:
+                         lag_budget: int) -> CompactPack:
     R, W, G = out.exec_req.shape
     P = out.intake_taken.shape[1]
     ji = jnp.arange(W, dtype=I32)[None, :, None]
@@ -1332,35 +1377,62 @@ def _compact_outbox_impl(out: TickOutbox, exec_budget: int,
          out.exec_base + out.exec_count],  # last: laggard's post-tick exec
         lag_budget)
     header = jnp.stack([n_exec, jnp.sum(out.decided_now), lag_n]).astype(I32)
-    return jnp.concatenate([
+    flat = jnp.concatenate([
         header,
         taken_bits.reshape(-1),
         e_cols.reshape(-1),
         l_cols.reshape(-1),
     ])
+    # the head: row g's P bits go to word g % Gw at shift (g // Gw) * P, so
+    # packing is an OR of ``per`` contiguous [R, Gw] slices (no relayout);
+    # both branches of _compact_columns fill slots 0 .. n_exec-1 in flat
+    # order, so the first head_exec entries are the whole list up to there
+    L = CompactLayout(R, G, exec_budget, lag_budget, P)
+    tb = jnp.pad(taken_bits, ((0, 0), (0, L.per * L.Gw - G)))
+    words = tb[:, :L.Gw]
+    for k in range(1, L.per):
+        words = words | (tb[:, k * L.Gw:(k + 1) * L.Gw] << (k * P))
+    head = jnp.concatenate([
+        header,
+        words.reshape(-1),
+        e_cols[:, :L.head_exec].reshape(-1),
+        l_cols.reshape(-1),
+    ])
+    return CompactPack(flat, head)
 
 
 class CompactLayout:
-    """THE single source of truth for the compacted-outbox flat buffer:
-    every offset any consumer needs, computed in one place.
+    """THE single source of truth for the compacted-outbox buffers: every
+    offset any consumer needs, computed in one place.
 
     Producers (:func:`_compact_outbox_impl` and the device-app
     ``fused_compact``, which appends its per-execution extras) emit
     sections in exactly this order; consumers (:func:`unpack_compact`,
-    ``PaxosManager._complete_tick``, WAL device-app replay) slice through
-    this object only — one field added to the packed buffer is one edit
-    here, not silent corruption in a hand-computed twin offset.
+    :func:`unpack_head`, ``PaxosManager._complete_tick``, WAL device-app
+    replay) slice through this object only — one field added to a packed
+    buffer is one edit here, not silent corruption in a hand-computed twin
+    offset.
 
-    Section order: header[3] | taken_bits[R*G] | e_rid[E] | e_meta[E] |
+    Flat buffer: header[3] | taken_bits[R*G] | e_rid[E] | e_meta[E] |
     e_slot[E] | e_row[E] | l_rep[Lb] | l_row[Lb] | l_donor[Lb] |
     l_dexec[Lb] | l_dstat[Lb] | l_lexec[Lb] | app extras
-    (device-app: e_resp[E] | e_miss[E])."""
+    (device-app: e_resp[E] | e_miss[E]).
+
+    Head (what the host pulls of a served tick while ``n_exec <=
+    head_exec``): header[3] | taken_words[R*Gw] | e_rid[Kh] | e_meta[Kh] |
+    e_slot[Kh] | e_row[Kh] | the six l_*[Lb] columns whole.  ``taken_words``
+    packs ``per = 32 // P`` groups to a word (``Gw = ceil(G / per)``; row
+    g's P bits at shift ``(g // Gw) * P`` of word ``g % Gw``; read with
+    :func:`taken_bit`); ``Kh = head_exec = min(E, _SPARSE_BLOCKS)``: what
+    the sparse branch of :func:`_compact_columns` can fill.  ``P = 0``
+    (consumers of the flat buffer alone) leaves the words unpacked."""
 
     HEADER = 3  # n_exec, decided_total, lag_n
 
     LAG_COLS = 6  # rep, row, donor, donor exec, donor status, laggard exec
 
-    def __init__(self, R: int, G: int, exec_budget: int, lag_budget: int):
+    def __init__(self, R: int, G: int, exec_budget: int, lag_budget: int,
+                 P: int = 0, head_exec: Optional[int] = None):
         self.R, self.G = R, G
         self.E, self.Lb = exec_budget, lag_budget
         self.o_taken = self.HEADER
@@ -1371,6 +1443,25 @@ class CompactLayout:
         self.o_miss = self.base + self.E        # device-app: descriptor miss
         self.total_plain = self.base
         self.total_device = self.base + 2 * self.E
+        # the head
+        self.per = max(1, 32 // P) if P else 1  # groups to a word
+        self.Gw = -(-G // self.per)
+        self.head_exec = (min(self.E, _SPARSE_BLOCKS) if head_exec is None
+                          else head_exec)
+        self.h_exec = self.o_taken + R * self.Gw
+        self.h_lag = self.h_exec + 4 * self.head_exec
+        self.total_head = self.h_lag + self.LAG_COLS * self.Lb
+
+    @classmethod
+    def of_head(cls, words: int, R: int, G: int, exec_budget: int,
+                lag_budget: int, P: int) -> "CompactLayout":
+        """The layout of a head ``words`` long: ``head_exec`` is read from
+        the buffer the program returned, not assumed."""
+        L = cls(R, G, exec_budget, lag_budget, P, head_exec=0)
+        Kh, rest = divmod(words - L.total_head, 4)
+        if rest or not 0 <= Kh <= exec_budget:
+            raise ValueError(f"no head of this plane is {words} words long")
+        return cls(R, G, exec_budget, lag_budget, P, head_exec=Kh)
 
     def kv_extras(self, flat):
         """Device-app extras aligned with the exec stream: (e_resp, e_miss)."""
@@ -1378,32 +1469,23 @@ class CompactLayout:
                 flat[self.o_miss:self.o_miss + self.E])
 
 
-def unpack_compact(flat, R: int, G: int, exec_budget: int,
-                   lag_budget: int) -> CompactHostOutbox:
-    """Host-side inverse of :func:`_compact_outbox_impl` (zero-copy views
-    into the one transferred buffer)."""
-    flat = np.asarray(flat)
-    L = CompactLayout(R, G, exec_budget, lag_budget)
-    E, Lb = L.E, L.Lb
-    n_exec, decided_total, lag_n = (int(flat[0]), int(flat[1]), int(flat[2]))
-    o = L.o_exec
-    e_rid = flat[o:o + n_exec]; o += E
-    e_meta = flat[o:o + n_exec]; o += E
-    e_slot = flat[o:o + n_exec]; o += E
-    e_row = flat[o:o + n_exec]; o += E
-    assert o == L.o_lag
+def _host_outbox(buf, taken, taken_shift: int, o_exec: int, E: int,
+                 o_lag: int, Lb: int) -> CompactHostOutbox:
+    """Views into one pulled buffer (flat or head): its header, four exec
+    columns ``E`` apart from ``o_exec`` and six laggard columns ``Lb``
+    apart from ``o_lag``, each trimmed to its live length."""
+    n_exec, decided_total, lag_n = (int(buf[0]), int(buf[1]), int(buf[2]))
+    e_rid, e_meta, e_slot, e_row = (
+        buf[o_exec + i * E:o_exec + i * E + n_exec] for i in range(4))
     ln = min(lag_n, Lb)
-    l_rep = flat[o:o + ln]; o += Lb
-    l_row = flat[o:o + ln]; o += Lb
-    l_donor = flat[o:o + ln]; o += Lb
-    l_dexec = flat[o:o + ln]; o += Lb
-    l_dstat = flat[o:o + ln]; o += Lb
-    l_lexec = flat[o:o + ln]
+    l_rep, l_row, l_donor, l_dexec, l_dstat, l_lexec = (
+        buf[o_lag + i * Lb:o_lag + i * Lb + ln]
+        for i in range(CompactLayout.LAG_COLS))
     return CompactHostOutbox(
         n_exec=n_exec,
         decided_total=decided_total,
         lag_n=lag_n,
-        taken_bits=flat[L.o_taken:L.o_taken + R * G].reshape(R, G),
+        taken_bits=taken,
         e_rid=e_rid,
         e_rep=e_meta & 0xFF,
         e_row=e_row,
@@ -1415,7 +1497,34 @@ def unpack_compact(flat, R: int, G: int, exec_budget: int,
         l_dexec=l_dexec,
         l_dstat=l_dstat,
         l_lexec=l_lexec,
+        taken_shift=taken_shift,
     )
+
+
+def unpack_compact(flat, R: int, G: int, exec_budget: int,
+                   lag_budget: int) -> CompactHostOutbox:
+    """Host-side inverse of :func:`_compact_outbox_impl`'s flat buffer
+    (zero-copy views into the one transferred buffer)."""
+    flat = np.asarray(flat)
+    L = CompactLayout(R, G, exec_budget, lag_budget)
+    return _host_outbox(
+        flat, flat[L.o_taken:L.o_taken + R * G].reshape(R, G), 0,
+        L.o_exec, L.E, L.o_lag, L.Lb)
+
+
+def unpack_head(head, R: int, G: int, P: int, exec_budget: int,
+                lag_budget: int) -> Optional[CompactHostOutbox]:
+    """The same outbox from the head of the same tick (equal field by
+    field, the acceptance words packed: :func:`taken_bit`), or None where
+    the head's own header says the tick decided more than it holds: the
+    flat buffer has the whole list."""
+    head = np.asarray(head)
+    L = CompactLayout.of_head(head.size, R, G, exec_budget, lag_budget, P)
+    if int(head[0]) > L.head_exec:
+        return None
+    return _host_outbox(
+        head, head[L.o_taken:L.h_exec].reshape(R, L.Gw), P,
+        L.h_exec, L.head_exec, L.h_lag, L.Lb)
 
 
 # --------------------------------------------------------------------------
@@ -1538,17 +1647,26 @@ def merge_outbox(out_l: HostOutbox, out_r: HostOutbox) -> HostOutbox:
 
 
 def merge_compact_outbox(co_l: CompactHostOutbox, co_r: CompactHostOutbox,
-                         g_log: int) -> CompactHostOutbox:
+                         g_log: int,
+                         g_reg: Optional[int] = None) -> CompactHostOutbox:
     """Merge two planes' compact outboxes into composite rows: counts sum,
     taken_bits stack along G, and the e_*/l_* columns (already trimmed to
     valid length by unpack_compact — no padding reaches the host) simply
-    concatenate with the register plane's row ids offset by g_log."""
+    concatenate with the register plane's row ids offset by g_log.  The
+    acceptance words come out one per composite row: a head's packed words
+    are expanded (composite rows do not divide into either plane's words),
+    for which the register plane's width ``g_reg`` is needed."""
     cat = np.concatenate
+    if g_reg is None:  # from flat buffers: one word per row already
+        if co_r.taken_shift:
+            raise ValueError("merging a head's packed words needs g_reg")
+        g_reg = co_r.taken_bits.shape[1]
     return CompactHostOutbox(
         n_exec=co_l.n_exec + co_r.n_exec,
         decided_total=co_l.decided_total + co_r.decided_total,
         lag_n=co_l.lag_n + co_r.lag_n,
-        taken_bits=np.hstack([co_l.taken_bits, co_r.taken_bits]),
+        taken_bits=np.hstack([taken_dense(co_l, g_log),
+                              taken_dense(co_r, g_reg)]),
         e_rid=cat([co_l.e_rid, co_r.e_rid]),
         e_rep=cat([co_l.e_rep, co_r.e_rep]),
         e_row=cat([co_l.e_row, co_r.e_row + g_log]),
@@ -1608,7 +1726,8 @@ class TickParams(NamedTuple):
 
 class TickPacks(NamedTuple):
     """What one served tick hands the host, None where a plane is absent.
-    ``out``/``rout``: the flat outbox per plane (compact or full);
+    ``out``/``rout``: the outbox per plane, a :class:`CompactPack` (flat
+    buffer and head) or the full outbox's one flat buffer;
     ``*lease_pack``: [LP_ROWS, G]; ``*health_pack``: see ``HealthLayout``
     (top-K clamped to ``min(health_topk, G_plane)``, as the host unpacks
     it in ``PaxosManager._adopt_health_pack``)."""
@@ -1754,8 +1873,9 @@ def _replay_scan_impl(planes: TickPlanes, xs, P: int, params: TickParams,
         pl, _ = carry
         pl, pk = _tick_step(pl, _coo_inbox(x, R, P, g_log + g_reg), params,
                             scat_budget)
-        packed = (pk.out if pk.rout is None
-                  else jnp.concatenate([pk.out, pk.rout]))
+        # the flat buffers alone: a window's ticks are unpacked whole
+        packed = (pk.out.flat if pk.rout is None
+                  else jnp.concatenate([pk.out.flat, pk.rout.flat]))
         lps = [lp for lp in (pk.lease_pack, pk.rlease_pack) if lp is not None]
         waits = (sum(jnp.sum(lp[LP_WAIT]) for lp in lps).astype(I32)
                  if lps else None)

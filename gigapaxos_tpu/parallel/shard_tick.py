@@ -45,7 +45,14 @@ jitted functions carry the names a trace finds them by:
     single-device path.  It is ``tk._compact_columns``, shared with the
     single-device programs: both its branches partition to the one-device
     buffer (tests/test_compact_sparse.py, on the virtual CPU mesh; through
-    the manager in tests/test_obs_request_stages.py).
+    the manager in tests/test_obs_request_stages.py).  Like the one-device
+    tick it returns a ``tk.CompactPack``: the flat buffer and the short
+    head the host pulls in its place while the tick decided no more than
+    the head holds (1.7 MB against 46 MB at 1M groups).  The second output
+    is in the same jit and leaves the partitioner's assignment alone: both
+    outputs equal the one-device program's word for word
+    (tests/test_compact_sparse.py, tests/test_outbox_head.py), unlike the
+    demand operand below.
 ``jit_mesh_demand_fold``
     the placement plane's demand EWMA, only with ``cfg.placement.enabled``.
 
@@ -209,9 +216,9 @@ def fetch_host_outbox(out: TickOutbox) -> "tk.HostOutbox":
 
 
 def make_mesh_compact(exec_budget: int, lag_budget: int):
-    """The jitted global-view compaction of a sharded TickOutbox (donated):
-    the second dispatch of a mesh tick, ``jit_mesh_compact_outbox`` in a
-    trace."""
+    """The jitted global-view compaction of a sharded TickOutbox (donated)
+    into its ``tk.CompactPack`` (flat buffer, head): the second dispatch of
+    a mesh tick, ``jit_mesh_compact_outbox`` in a trace."""
     def mesh_compact_outbox(out):
         return tk._compact_outbox_impl(out, exec_budget=exec_budget,
                                        lag_budget=lag_budget)
@@ -236,7 +243,7 @@ def make_shardmap_tick_compact(mesh: Mesh, own_row: int, exec_budget: int,
     TickOutbox, so no later dispatch can read ``decided_now``.  With a decay
     set, the returned callable takes and returns the [G] f32 demand array
     (``P(groups)``-sharded, see :func:`init_demand`):
-    ``fn(state, inbox, demand) -> (state, flat, new_demand)``.
+    ``fn(state, inbox, demand) -> (state, pack, new_demand)``.
     """
     tick = make_shardmap_tick(mesh, own_row, exec_budget)
     compact = make_mesh_compact(exec_budget, lag_budget)

@@ -54,12 +54,13 @@ from .bulkstore import BulkOverrun, BulkStore
 from .paystore import PayloadStore
 from ..ops.pallas_gather import check_lanes
 from ..ops.tick import (LP_ASN, LP_EPOCH, LP_HOLDER, LP_UNTIL, LP_WAIT,
-                        CompactHostOutbox, HostOutbox, TickInbox, TickParams,
-                        TickPlanes, compact_path, frontier_rows,
+                        CompactHostOutbox, CompactPack, HostOutbox, TickInbox,
+                        TickParams, TickPlanes, compact_path, frontier_rows,
                         health_clear_rows, init_health, lease_clear_rows,
                         merge_compact_outbox, merge_health, merge_outbox,
                         one_or_pair, paxos_tick_planes, sweep_frontier,
-                        unpack_compact, unpack_health, unpack_outbox)
+                        taken_bit, taken_dense, unpack_compact, unpack_head,
+                        unpack_health, unpack_outbox)
 
 
 @dataclass
@@ -205,7 +206,7 @@ class PaxosManager:
         from ..ops.tick import CompactLayout
 
         self._compact_layout = CompactLayout(
-            self.R, self.G, self._exec_budget, self._lag_budget
+            self.R, self.G, self._exec_budget, self._lag_budget, self.P
         )
         self._compact_layout_reg = (CompactLayout(
             self.R, self.G_reg, self._exec_budget, self._lag_budget
@@ -503,6 +504,22 @@ class PaxosManager:
             help="a tick's completion blocked until its outbox is ready on "
                  "the device, before the pull",
             plane=spill_ns)
+        #: what follows the wait in "tally": the pull and the unpack; and
+        #: which buffer a compacted plane's completion pulled: the head, or
+        #: the whole flat buffer where the tick decided more than the head
+        #: holds (ops/tick.py CompactLayout)
+        self._outbox_pull_h = _obs_registry().histogram(
+            "tick_outbox_pull_seconds",
+            help="a tick's completion pulling its outbox to the host and "
+                 "unpacking it, after the wait for the program",
+            plane=spill_ns)
+        self._outbox_pull_c = {
+            pull: _obs_registry().counter(
+                "outbox_pulls_total",
+                help="compacted outbox buffers pulled, one per completed "
+                     "tick and plane: the head, or the flat buffer whole",
+                plane=spill_ns, pull=pull)
+            for pull in ("head", "full")}
         #: which branch the device's compaction took for each list, one
         #: increment per compaction; mirrored from the header this loop
         #: reads anyway through the rule the device used (compact_path)
@@ -2392,35 +2409,34 @@ class PaxosManager:
         # (both are "tally"); the interpreter lock is free meanwhile
         t0 = time.perf_counter()
         jax.block_until_ready(packed)
-        self._device_wait_h.observe(time.perf_counter() - t0)
+        t1 = time.perf_counter()
+        self._device_wait_h.observe(t1 - t0)
         if lease_pack is not None:
             self._adopt_lease_pack(lease_pack)
         if health_pack is not None:
             self._adopt_health_pack(health_pack)
         if self._use_compact:
-            if isinstance(packed, tuple):
-                # mixed planes: two per-plane compact buffers; unpack each
-                # against its own geometry, then merge with register rows
-                # re-offset into the composite row space
-                co_l = unpack_compact(np.asarray(packed[0]), self.R, self.G,
-                                      self._exec_budget, self._lag_budget)
-                co_r = unpack_compact(np.asarray(packed[1]), self.R,
-                                      self.G_reg, self._exec_budget,
-                                      self._lag_budget)
-                self._count_compact_paths(co_l, self.W, self.G)
-                self._count_compact_paths(co_r, 1, self.G_reg)
-                out = merge_compact_outbox(co_l, co_r, self.G)
-                flat = None
-            else:
+            e_resp = e_miss = None
+            if self._device_app:
+                # the app's extras ride the flat buffer: pulled whole, and
+                # sliced through the shared layout descriptor —
+                # fused_compact packs them through the same object
                 flat = np.asarray(packed)
                 out = unpack_compact(flat, self.R, self.G,
                                      self._exec_budget, self._lag_budget)
-                self._count_compact_paths(out, self.W, self.G)
-            e_resp = e_miss = None
-            if self._device_app:
-                # extras sliced through the shared layout descriptor —
-                # fused_compact packs them through the same object
+                self._count_compact(out, "full", self.W, self.G)
                 e_resp, e_miss = self._compact_layout.kv_extras(flat)
+            else:
+                # a CompactPack per plane (mixed planes: a (log, register)
+                # pair), each unpacked against its own geometry, then
+                # merged with register rows re-offset into composite rows
+                packs = ((packed,) if isinstance(packed, CompactPack)
+                         else packed)
+                cos = [self._pull_compact(pack, g, w) for pack, g, w in zip(
+                    packs, (self.G, self.G_reg), (self.W, 1))]
+                out = cos[0] if len(cos) == 1 else merge_compact_outbox(
+                    *cos, self.G, self.G_reg)
+            self._outbox_pull_h.observe(time.perf_counter() - t1)
             pc.mark("tally")
             self._process_compact(out, placed, bulk_placed, e_resp, e_miss)
         else:
@@ -2445,6 +2461,7 @@ class PaxosManager:
                 out = merge_outbox(out_l, out_r)
             else:
                 out = unpack_outbox(packed, self.R, self.P, self.W, self.G)
+            self._outbox_pull_h.observe(time.perf_counter() - t1)
             pc.mark("tally")
             self._process_outbox(out, placed, bulk_placed)
         pc.mark("execute")
@@ -2461,8 +2478,24 @@ class PaxosManager:
         pc.mark("sweep")
         return out
 
-    def _count_compact_paths(self, co, W: int, G: int) -> None:
-        """One compacted plane's two lists -> ``compact_path_ticks_total``."""
+    def _pull_compact(self, pack, G: int, W: int) -> CompactHostOutbox:
+        """One plane's compacted outbox from the device: its head, and the
+        flat buffer only where the head's own header says the tick decided
+        more than the head holds."""
+        pull = "head"
+        co = unpack_head(np.asarray(pack.head), self.R, G, self.P,
+                         self._exec_budget, self._lag_budget)
+        if co is None:
+            pull = "full"
+            co = unpack_compact(np.asarray(pack.flat), self.R, G,
+                                self._exec_budget, self._lag_budget)
+        self._count_compact(co, pull, W, G)
+        return co
+
+    def _count_compact(self, co, pull: str, W: int, G: int) -> None:
+        """One compacted plane's pull -> ``outbox_pulls_total``, and its two
+        lists -> ``compact_path_ticks_total``."""
+        self._outbox_pull_c[pull].inc()
         for lst, n, cap, count in (
                 ("exec", self.R * W * G, self._exec_budget, co.n_exec),
                 ("lag", self.R * G, self._lag_budget, co.lag_n)):
@@ -2655,15 +2688,21 @@ class PaxosManager:
         route through the scalar path, whose app ``execute`` re-applies
         the descriptor host-side (or fails the request if the payload is
         gone)."""
-        taken = co.taken_bits
-        for row, take in (placed or []):
-            for rid, entry, p in reversed(take):
-                if (not (taken[entry, row] >> p) & 1
-                        and rid in self.outstanding):
-                    self._queues[row].appendleft(rid)
+        if placed:
+            # one vectorized look at every placed position; a rejection is
+            # rare (a full window, a closed group), and only then are the
+            # rows walked to requeue in order
+            at = np.array([(entry, row, p) for row, take in placed
+                           for _, entry, p in take]).T
+            if not taken_bit(co, *at).all():
+                for row, take in placed:
+                    for rid, entry, p in reversed(take):
+                        if (not taken_bit(co, entry, row, p)
+                                and rid in self.outstanding):
+                            self._queues[row].appendleft(rid)
         if bulk_placed is not None:
             b_rids, b_e, b_p, b_r = bulk_placed
-            tk = (taken[b_e, b_r] >> b_p) & 1
+            tk = taken_bit(co, b_e, b_r, b_p)
             rej = b_rids[tk == 0]
             if rej.size:
                 self._bulk_leftover = (
@@ -2774,7 +2813,7 @@ class PaxosManager:
             # host demand fold (single-device compact path): per-group
             # decisions are gone from the flat buffer, so fold the intake
             # acceptance bits instead — popcount of each row's taken mask
-            bits = co.taken_bits.astype(np.int64)
+            bits = taken_dense(co, self.G_total).astype(np.int64)
             per_row = np.zeros(bits.shape[1], np.int64)
             for _ in range(self.P):
                 per_row += (bits & 1).sum(axis=0)

@@ -5,7 +5,9 @@ donor-status select against ``take_along_axis``.
 
 The plane (R=3, W=4, G=8,192) is wide enough for both branches once the
 module's K is lowered to 64 for the test; the served path's K makes every
-test-sized plane dense from its shape alone.
+test-sized plane dense from its shape alone.  Each case also holds the head
+of the buffer (ISSUE 34) to it: the same host outbox while ``n_exec <= K``,
+refused by its own header above.
 """
 
 import functools
@@ -105,11 +107,12 @@ def compact():
     fn = jax.jit(functools.partial(tk._compact_outbox_impl, exec_budget=E,
                                    lag_budget=LB))
 
-    def run(outbox: tk.TickOutbox) -> np.ndarray:
+    def run(outbox: tk.TickOutbox) -> tuple:
+        """(flat buffer, head) of one compaction, as numpy."""
         with small_k():
             assert tk.compact_blocks(N, E) == K
             assert tk.compact_blocks(R * G, LB) == LB
-            return np.asarray(fn(outbox))
+            return tuple(np.asarray(a) for a in fn(outbox))
 
     return run
 
@@ -130,7 +133,7 @@ def test_packed_buffer_equals_the_reference_word_for_word(compact, case):
     hits, laggards = CASES[case]
     out = random_outbox(case, hits, laggards)
     want = reference_buffer(out)
-    got = compact(device_outbox(out))
+    got, head = compact(device_outbox(out))
     assert got.shape == want.shape == (
         tk.CompactLayout(R, G, E, LB).total_plain,)
     bad = np.flatnonzero(got != want)
@@ -141,6 +144,18 @@ def test_packed_buffer_equals_the_reference_word_for_word(compact, case):
         assert (tk.compact_path(N, E, n_exec) == "sparse") == (n_exec <= K)
         assert (tk.compact_path(R * G, LB, lag_n) == "sparse") == (
             lag_n <= LB)
+        assert head.shape == (tk.CompactLayout(R, G, E, LB, P).total_head,)
+    # the head holds what the sparse branch can fill: the same host outbox
+    # from a seventh of the words, or None and the flat buffer is pulled
+    co = tk.unpack_compact(got, R, G, E, LB)
+    co_h = tk.unpack_head(head, R, G, P, E, LB)
+    assert (co_h is None) == (n_exec > K)
+    if co_h is not None:
+        for f, a, b in zip(co._fields, co_h, co):
+            if f == "taken_bits":
+                a, b = tk.taken_dense(co_h, G), tk.taken_dense(co, G)
+            assert np.array_equal(a, b) if f != "taken_shift" else (
+                (a, b) == (P, 0)), f
 
 
 def test_served_path_k_leaves_test_sized_planes_dense():
@@ -193,11 +208,15 @@ def test_partitioned_compaction_equals_the_one_device_buffer(replica_shards):
             out = {k: (np.concatenate([v, np.zeros_like(v[:1])])
                        if v.ndim > 1 else v) for k, v in out.items()}
         with small_k():
-            want = np.asarray(fn(device_outbox(out)))
-            got = np.asarray(fn(device_outbox(out, lambda k, v: jax.device_put(
-                v, NamedSharding(mesh, _OUTBOX_SPECS[k])))))
-        assert int(want[0]) == hits and int(want[2]) == laggards
-        assert np.array_equal(got, want)
+            want = fn(device_outbox(out))
+            got = fn(device_outbox(out, lambda k, v: jax.device_put(
+                v, NamedSharding(mesh, _OUTBOX_SPECS[k]))))
+        assert int(want.flat[0]) == hits and int(want.flat[2]) == laggards
+        # the second output did not move the partitioner (shard_tick.py:
+        # an operand added to this jit once multiplied the header's counts)
+        assert np.array_equal(got.flat, want.flat)
+        assert np.array_equal(got.head, want.head)
+        assert list(want.head[:3]) == list(want.flat[:3])
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])

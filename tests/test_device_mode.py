@@ -276,7 +276,10 @@ def test_compact_layout_single_source_of_truth():
     _, packs = paxos_tick_planes(
         TickPlanes(s), inbox,
         TickParams(exec_budget=E, lag_budget=Lb, compact=True))
-    assert np.asarray(packs.out).shape[0] == L.total_plain
+    assert np.asarray(packs.out.flat).shape[0] == L.total_plain
+    # and its head: the same descriptor, told the inbox's P
+    assert np.asarray(packs.out.head).shape[0] == CompactLayout(
+        R, G, E, Lb, P=2).total_head == 3 + R * 1 + 4 * E + 6 * Lb
 
     # device-app buffer: total_device, and the response round-trips
     kv = init_kv(R, G, slots=8, table=1 << 16)

@@ -131,7 +131,12 @@ def test_tick_completion_families_carry_their_labels():
     or off (off: every tick is ``same_call``);
     ``tick_device_wait_seconds{plane}`` is observed once per completion,
     inside the ``tally`` phase and before the pull: the benchmark's
-    ``device_wait_ms`` reads its mean."""
+    ``device_wait_ms`` reads its mean.  What follows it in ``tally``, the
+    pull and the unpack, is ``tick_outbox_pull_seconds{plane}``
+    (``outbox_pull_ms``), and ``outbox_pulls_total{plane,pull=head|full}``
+    rises once per completed tick and compacted plane buffer with the
+    buffer that was pulled (ISSUE 34): the head first, the flat buffer
+    only when the head refuses itself."""
     import json
 
     from gigapaxos_tpu.config import GigapaxosTpuConfig
@@ -143,27 +148,42 @@ def test_tick_completion_families_carry_their_labels():
     assert re.search(r'_completions_c\["held" if hold else "same_call"\]'
                      r'\.inc\(\)', src)
     body = src[src.index("def _complete_tick"):src.index(
-        "def _count_compact_paths")]
+        "def _pull_compact")]
     wait = body.index("self._device_wait_h.observe(")
     assert body.index("jax.block_until_ready(packed)") < wait
-    assert wait < body.index("np.asarray(packed") < body.index(
-        'pc.mark("tally")')
+    pulled = [body.index("np.asarray(packed"),
+              body.index("self._pull_compact(pack")]
+    assert wait < min(pulled) and max(pulled) < body.index(
+        "self._outbox_pull_h.observe(") < body.index('pc.mark("tally")')
+    # both arms of the completion close the span before they mark "tally"
+    assert body.count("self._outbox_pull_h.observe(") == body.count(
+        'pc.mark("tally")') == 2
+    pull = src[src.index("def _pull_compact"):src.index(
+        "def _count_compact")]
+    assert pull.index("np.asarray(pack.head)") < pull.index(
+        "if co is None:") < pull.index("np.asarray(pack.flat)")
 
     plane = "t_completion_labels"
-    m = PaxosManager(GigapaxosTpuConfig(), 3, [KVApp() for _ in range(3)],
-                     spill_ns=plane)
+    cfg = GigapaxosTpuConfig()
+    cfg.paxos.compact_outbox = True
+    m = PaxosManager(cfg, 3, [KVApp() for _ in range(3)], spill_ns=plane)
     m.create_paxos_instance("svc", [0, 1, 2])
     m.run_ticks(3)
     snap = registry().snapshot()
     assert snap[f"tick_completions_total{{mode=same_call,plane={plane}}}"] == 3
     assert snap[f"tick_completions_total{{mode=held,plane={plane}}}"] == 0
     assert snap[f"tick_device_wait_seconds{{plane={plane}}}"]["count"] == 3
-    with open(os.path.join(ROOT, "chipbench", "layer_metrics",
-                           "device_wait_ms.json")) as f:
-        metric = json.load(f)
-    assert metric["reader"] == "histogram_mean"
-    assert metric["args"] == {"family": "tick_device_wait_seconds",
-                              "labels": {"plane": "ar"}}
+    assert snap[f"tick_outbox_pull_seconds{{plane={plane}}}"]["count"] == 3
+    assert snap[f"outbox_pulls_total{{plane={plane},pull=head}}"] == 3
+    assert snap[f"outbox_pulls_total{{plane={plane},pull=full}}"] == 0
+    for name, family in (("device_wait_ms", "tick_device_wait_seconds"),
+                         ("outbox_pull_ms", "tick_outbox_pull_seconds")):
+        with open(os.path.join(ROOT, "chipbench", "layer_metrics",
+                               name + ".json")) as f:
+            metric = json.load(f)
+        assert metric["reader"] == "histogram_mean"
+        assert metric["args"] == {"family": family,
+                                  "labels": {"plane": "ar"}}
 
 
 def test_wal_fsync_goes_through_instrumented_sync_only():
@@ -206,6 +226,10 @@ WIRING = {
     # completion was blocked for the program before the pull (ISSUE 31)
     "tick_completions_total": "gigapaxos_tpu/paxos/manager.py",
     "tick_device_wait_seconds": "gigapaxos_tpu/paxos/manager.py",
+    # the rest of "tally": the pull and the unpack, and which buffer a
+    # compacted plane's completion pulled (ISSUE 34)
+    "tick_outbox_pull_seconds": "gigapaxos_tpu/paxos/manager.py",
+    "outbox_pulls_total": "gigapaxos_tpu/paxos/manager.py",
     "jit_compile_seconds": "gigapaxos_tpu/obs/compiles.py",
     "compile_cache_lookups_total": "gigapaxos_tpu/obs/compiles.py",
     "wal_fsync_seconds": "gigapaxos_tpu/wal/logger.py",

@@ -141,7 +141,10 @@ def test_the_one_entry_equals_the_per_plane_composition(case):
         _assert_same(packs, ref_packs, f"packs of tick {t}")
         _assert_same(planes, ref, f"planes after tick {t}")
         if compact:
-            executed += int(np.asarray(packs.out)[0])
+            # the flat buffer and its head: one header
+            executed += int(np.asarray(packs.out.flat)[0])
+            assert int(np.asarray(packs.out.head)[0]) == int(
+                np.asarray(packs.out.flat)[0])
     # absent planes stay absent, present ones come back
     assert [x is None for x in planes] == [
         False, not reg, not lease, not (lease and reg), not health,
@@ -211,8 +214,8 @@ def test_the_replay_scan_is_k_steps_of_the_one_entry(lease, reg):
     step = _planes(lease, reg, False, False)
     for k, ib in enumerate(inboxes):
         step, pk = tk.paxos_tick_planes(step, ib, params)
-        row = pk.out if pk.rout is None else jnp.concatenate(
-            [pk.out, pk.rout])
+        row = pk.out.flat if pk.rout is None else jnp.concatenate(
+            [pk.out.flat, pk.rout.flat])
         assert np.array_equal(np.asarray(packs[k]), np.asarray(row)), k
         if lease:
             lps = [lp for lp in (pk.lease_pack, pk.rlease_pack)

@@ -71,6 +71,11 @@ mesh = pmesh.make_mesh(topo.devices, replica_shards=1)
 m_state = shaped(jax.eval_shape(lambda: st.init_state(R, G, W)),
                  pmesh.state_shardings(mesh))
 m_inbox = shaped(inbox(G), pmesh.inbox_shardings(mesh))
+m_outbox = shaped(
+    jax.eval_shape(lambda: tk.paxos_tick_impl(
+        st.init_state(R, G, W), tk.make_inbox(R, G, P))[1]),
+    tk.TickOutbox(**{f: jax.sharding.NamedSharding(mesh, spec)
+                     for f, spec in stk._OUTBOX_SPECS.items()}))
 
 params = tk.TickParams(exec_budget=E, lag_budget=Lb, compact=True,
                        lease_horizon=64)
@@ -80,31 +85,39 @@ def served(g, **planes):
     return (tk.TickPlanes(state, **planes), inbox(g), params)
 
 
+head = tk.CompactLayout(R, G, E, Lb, P).total_head
 programs = [
-    # (name, jitted fn, args, Pallas calls it must carry)
-    ("log-plane compact tick", tk.paxos_tick_planes, served(G), 26),
+    # (name, jitted fn, args, Pallas calls it must carry, heads it returns:
+    # one per compacted plane beside the flat buffer, none from replay)
+    ("log-plane compact tick", tk.paxos_tick_planes, served(G), 26, 1),
     ("the kept name the harness traces", tk.paxos_tick_compact,
-     (state, inbox(G), -1, E, Lb), 26),
+     (state, inbox(G), -1, E, Lb), 26, 1),
     ("mixed log+register tick (W=4 and W=1)", tk.paxos_tick_planes,
-     served(2 * G, rstate=rstate), 52),
-    ("lease tick", tk.paxos_tick_planes, served(G, lease=lease), 26),
-    ("health tick", tk.paxos_tick_planes, served(G, health=health), 26),
+     served(2 * G, rstate=rstate), 52, 2),
+    ("lease tick", tk.paxos_tick_planes, served(G, lease=lease), 26, 1),
+    ("health tick", tk.paxos_tick_planes, served(G, health=health), 26, 1),
     ("replay scan, sparse window of 128 lanes", tk.replay_scan_ticks,
-     (tk.TickPlanes(narrow), xs, P, params, E), 26),
+     (tk.TickPlanes(narrow), xs, P, params, E), 26, 0),
     ("shard_map tick over four chips", stk.make_shardmap_tick(mesh, -1, E),
-     (m_state, m_inbox), 26),
+     (m_state, m_inbox), 26, 0),
+    ("compaction of a sharded outbox over four chips",
+     stk.make_mesh_compact(E, Lb), (m_outbox,), 0, 1),
 ]
-for name, fn, args, want in programs:
+for name, fn, args, want, heads in programs:
     low = fn.lower(*args)
     got = low.as_text().count("@tpu_custom_call")
     assert got == want, f"{name}: {got} Mosaic custom calls, expected {want}"
+    got = [o.shape for o in jax.tree.leaves(low.out_info)].count((head,))
+    assert got == heads, f"{name}: {got} outputs of a head's length"
     hlo = low.compile().as_text()
     # the named scopes survive XLA:TPU's fusion: the compaction's fusions
     # still say where they came from, which is what a trace reader splits
     # the device time by (obs/phase.py TICK_SCOPES)
-    if "compact" in name or "health" in name or "lease" in name:
-        for scope in ("compact_outbox/scatter", "/prepare/", "/accept/"):
-            assert scope in hlo, f"{name}: no op carries {scope!r}"
+    scopes = ["compact_outbox/scatter"] if heads else []
+    if want and ("compact" in name or "health" in name or "lease" in name):
+        scopes += ["compact_outbox/scatter", "/prepare/", "/accept/"]
+    for scope in scopes:
+        assert scope in hlo, f"{name}: no op carries {scope!r}"
     print("COMPILED", name, flush=True)
 print("ALL-COMPILED")
 '''

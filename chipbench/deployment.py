@@ -8,7 +8,9 @@ later PRs may change the program and the smoke but not the yardstick.
 
 from __future__ import annotations
 
+import collections.abc
 import os
+import threading
 
 import numpy as np
 
@@ -79,6 +81,98 @@ def populate(cluster, n: int) -> list:
     if adopted < n:
         raise DeploymentError(f"the actives adopted {adopted} of {n} names")
     return names
+
+
+class Loaded(collections.abc.Mapping):
+    """service name -> {key: value} of the records ``preload`` wrote: what a
+    reference is given as ``initial``.  Held as one array of bytes, not a
+    dict of a million dicts that the collector would walk inside the
+    window."""
+
+    def __init__(self, key: str, values: np.ndarray):
+        self._key, self._values = key, values
+
+    def __getitem__(self, name: str) -> dict:
+        number = name[len(NAME_PREFIX):]
+        if not (name.startswith(NAME_PREFIX) and number.isdecimal()
+                and int(number) < len(self._values)):
+            raise KeyError(name)
+        return {self._key: self._values[int(number)].decode()}
+
+    def __iter__(self):
+        return (f"{NAME_PREFIX}{i}" for i in range(len(self._values)))
+
+    def __len__(self) -> int:
+        return len(self._values)
+
+
+#: the sequence numbers of the loaded records' values start here: past the
+#: window's (from 0) and the warm-up's (from 10**9)
+PRELOAD_SEQ0 = 2 * 10 ** 9
+
+
+def record_values(seed: int, n: int, width: int) -> np.ndarray:
+    """``n`` record values as an ``S<width>`` array, made as the generators
+    make theirs, with sequence numbers from ``PRELOAD_SEQ0``."""
+    from .generators.open_poisson import value_array
+
+    return value_array(np.random.default_rng([int(seed), 3]), n, width,
+                       PRELOAD_SEQ0)
+
+
+def _bulk_wave(cluster, rows: np.ndarray, payloads: list,
+               timeout_s: float) -> list:
+    """One ``propose_bulk`` of one request a row (as ``chip_smoke.py``'s
+    ``_wave``); the answers, in no order, once every one has come."""
+    answers: list = []
+    done = threading.Event()
+
+    def sink(offsets, responses):
+        answers.extend(responses)
+        if len(answers) >= len(payloads):
+            done.set()
+
+    rids = cluster.manager.propose_bulk(rows, payloads, batch_sink=sink)
+    if not (rids > 0).all():
+        raise DeploymentError(f"preload: {int((rids <= 0).sum())} of "
+                              f"{len(payloads)} records not admitted")
+    cluster.driver.kick()
+    if not done.wait(timeout_s):
+        raise DeploymentError(f"preload: {len(answers)} of {len(payloads)} "
+                              f"records answered within {timeout_s:.0f}s")
+    return answers
+
+
+#: names a ``propose_bulk`` of the preload: a wave of 262,144 completes in
+#: 1.6-1.7 s at 1M groups on one chip (my scratch runs, PR 31)
+PRELOAD_WAVE = 262144
+
+
+def preload(cluster, names: list, params: dict, seed: int,
+            timeout_s: float = 600.0, wave: int = PRELOAD_WAVE) -> Loaded:
+    """Load one seeded record into every name before the warm-up, through
+    consensus and the journal: ``PaxosManager.propose_bulk`` in waves of one
+    ``PUT <key> <value>`` a name, each wave waited for and every answer held
+    to ``OK``.  Nothing is written into ``app.db`` behind the protocol's
+    back: what a replica holds after a restart is what the journal says.
+    ``params`` is a traffic file's ``preload``: ``key`` and ``value_bytes``.
+    ``wave`` is not a traffic file's to set: a test makes it small so that
+    the several waves a 1M load takes run at a rehearsal's size."""
+    m = cluster.manager
+    key = params["key"]
+    values = record_values(seed, len(names), int(params["value_bytes"]))
+    rows = np.fromiter((m.rows.row(f"{s}#0") for s in names), np.int64,
+                       len(names))
+    put = f"PUT {key} ".encode()
+    for lo in range(0, len(names), wave):
+        answers = _bulk_wave(
+            cluster, rows[lo:lo + wave],
+            [put + v for v in values[lo:lo + wave].tolist()], timeout_s)
+        wrong = sum(a != b"OK" for a in answers)
+        if wrong:
+            raise DeploymentError(f"preload: {wrong} of {len(answers)} "
+                                  f"records not answered OK")
+    return Loaded(key, values)
 
 
 def warm_sweep_buckets(m, up_to: int) -> int:

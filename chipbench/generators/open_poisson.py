@@ -1,4 +1,5 @@
-"""Open-loop Poisson arrivals of single-key writes.
+"""Open-loop Poisson arrivals of single-key writes; and ``Schedule``, what
+every generator returns.
 
 Parameters (a traffic file's ``params``):
 
@@ -28,18 +29,44 @@ _HEX = np.frombuffer(b"0123456789abcdef", dtype=np.uint8)
 class Schedule:
     """``n`` requests: ``due`` seconds from the start of the schedule,
     ascending; ``name``/``entry`` indices into the populated names and the
-    actives; ``payload[i]`` the request bytes; ``value[i]`` what it writes."""
+    actives; ``payload[i]`` the request bytes, and what the check needs to
+    know of them: ``kind[i]`` (``update``, ``read`` or ``delete``),
+    ``key[i]`` the key it touches, ``value[i]`` what an update writes
+    (``None`` otherwise)."""
 
     due: np.ndarray
     name: np.ndarray
     entry: np.ndarray
     payload: list
+    kind: list
+    key: list
     value: list
-    key: str
 
 
 def _rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng([int(seed), int(stream)])
+
+
+def value_array(rng: np.random.Generator, n: int, width: int,
+                seq0: int) -> np.ndarray:
+    """``n`` values of ``width`` characters as an ``S<width>`` array: the
+    sequence number from ``seq0`` in the first 12, so that no two values of
+    a run are equal (the reference names a read's write by its value), then
+    a seeded hexadecimal tail."""
+    if width < 16:
+        raise ValueError("value_bytes under 16 leaves no room for the "
+                         "sequence number and the seeded tail")
+    tail = _HEX[rng.integers(0, 16, size=(n, width - 12))]
+    seq = seq0 + np.arange(n, dtype=np.int64)
+    digits = seq[:, None] // 10 ** np.arange(11, -1, -1, dtype=np.int64) % 10
+    both = np.concatenate([(digits + ord("0")).astype(np.uint8), tail], axis=1)
+    return np.ascontiguousarray(both).view(f"S{width}").ravel()
+
+
+def unique_values(rng: np.random.Generator, n: int, width: int,
+                  seq0: int) -> list:
+    """``value_array`` as a list of ``str``."""
+    return [v.decode() for v in value_array(rng, n, width, seq0).tolist()]
 
 
 def schedule(params: dict, seed: int, seconds: float, n_names: int,
@@ -54,12 +81,7 @@ def schedule(params: dict, seed: int, seconds: float, n_names: int,
     name = rng.integers(0, n_names, size=n)
     entry = rng.integers(0, n_entries, size=n)
     key = params["key"]
-    width = int(params["value_bytes"])
-    if width < 16:
-        raise ValueError("value_bytes under 16 leaves no room for the "
-                         "sequence number and the seeded tail")
-    tail = _HEX[rng.integers(0, 16, size=(n, width - 12))]
-    value = [f"{seq0 + i:012d}" + tail[i].tobytes().decode()
-             for i in range(n)]
+    value = unique_values(rng, n, int(params["value_bytes"]), seq0)
     payload = [f"PUT {key} {v}".encode() for v in value]
-    return Schedule(due, name, entry, payload, value, key)
+    return Schedule(due, name, entry, payload, ["update"] * n, [key] * n,
+                    value)

@@ -8,6 +8,7 @@ holds no cell's name or number.
 from __future__ import annotations
 
 import argparse
+import collections
 import dataclasses
 import faulthandler
 import gc
@@ -22,7 +23,7 @@ import time
 
 import numpy as np
 
-from . import deployment, load, reference, spec, stats, tracing
+from . import deployment, load, references, spec, stats, tracing
 
 #: a later run of a cell must exit within 360 s, the first (compiling) within
 #: 1200 s; past this the process dumps every thread's stack and exits non-zero
@@ -308,48 +309,54 @@ def timeline_diagnostics(rows: list, collections: list, t0: float,
     return out
 
 
-def _collect_writes(loads: list, names: list) -> tuple:
-    """(name -> [Write], [(name, request, reply)] of the acknowledged) over
-    every request the run sent, warm-up included."""
+def _collect_ops(loads: list, names: list) -> dict:
+    """name -> [``references.Op``] over every request the run sent, warm-up
+    included, in the order sent."""
     from gigapaxos_tpu.reconfiguration import packets as pkt
 
-    writes: dict = {}
-    replies = []
+    ops: dict = {}
     for ld in loads:
         s = ld.sched
         for i in range(ld.n_sent):
-            name = names[s.name[i]]
             st = int(ld.status[i])
             status = ("ok" if st == stats.OK else
                       "unknown" if st == stats.PENDING else "refused")
-            writes.setdefault(name, []).append(reference.Write(
-                s.value[i], float(ld.sent[i]), float(ld.done[i]), status))
-            if st == stats.OK:
-                replies.append((name, s.payload[i],
-                                pkt.b64d(ld.reply[i]) or b""))
-    return writes, replies
+            reply = (pkt.b64d(ld.reply[i]) or b"") if st == stats.OK else None
+            ops.setdefault(names[s.name[i]], []).append(references.Op(
+                s.kind[i], s.key[i], s.value[i], float(ld.sent[i]),
+                float(ld.done[i]), status, reply))
+    return ops
 
 
 def check(cell, cluster, client, loads: list, names: list, actives: list,
-          seed: int) -> list:
-    """The reference against the replies, the replicas and a read-back
-    through the client; returns the problems found."""
-    key = loads[-1].sched.key
-    writes, replies = _collect_writes(loads, names)
-    acked = sorted(n for n, ws in writes.items()
-                   if any(w.status == "ok" for w in ws))
+          seed: int, initial) -> list:
+    """The configuration's reference against the replies, the replicas and a
+    read-back through the client; returns the problems found."""
+    ops = _collect_ops(loads, names)
+    acked = sorted(n for n, os_ in ops.items()
+                   if any(o.status == "ok" for o in os_))
     rng = np.random.default_rng([seed, 2])
     pick = rng.choice(len(acked), replace=False, size=min(
         len(acked), int(cell.traffic["readback_names"])))
-    readback = load.read_back(client, [acked[i] for i in pick], actives, key,
-                              client.default_deadline_s)
-    problems = reference.check_run(
-        writes, lambda n: deployment.replica_tables(cluster, n), replies,
-        readback, key)
+    # each name picked is read back at the key of its first request
+    by_key: dict = {}
+    for i in pick:
+        by_key.setdefault(ops[acked[i]][0].key, []).append(acked[i])
+    readback: dict = {}
+    for key, picked in by_key.items():
+        got = load.read_back(client, picked, actives, key,
+                             client.default_deadline_s)
+        readback.update((name, {key: value}) for name, value in got.items())
+    problems = spec.reference(cell.config["reference"]).check_run(
+        ops, lambda n: deployment.replica_tables(cluster, n), readback,
+        initial)
     for p in problems[:10]:
         note(f"WRONG: {p}")
-    note(f"check: {len(writes)} names touched on {cluster.manager.R} replicas,"
-         f" {len(replies)} replies, {len(readback)} read back by GET; "
+    by_kind = collections.Counter(o.kind for os_ in ops.values() for o in os_
+                                  if o.status == "ok")
+    note("acknowledged by kind: " + json.dumps(by_kind, sort_keys=True))
+    note(f"check: {len(ops)} names touched on {cluster.manager.R} replicas,"
+         f" {sum(by_kind.values())} replies, {len(readback)} read back by GET; "
          f"{len(problems)} problem(s)")
     return problems
 
@@ -396,6 +403,13 @@ def run(args, t_start: float) -> dict:
         actives = list(cfg.nodes.active_ids())
         note(f"populated and adopted {len(names):,} groups")
         client = ReconfigurableAppClient(cfg.nodes)
+        initial: dict = {}
+        if "preload" in cell.traffic:
+            t = time.monotonic()
+            initial = deployment.preload(cluster, names,
+                                         cell.traffic["preload"], args.seed)
+            note(f"preload: {len(initial):,} records through consensus and "
+                 f"the journal in {time.monotonic() - t:.2f}s")
 
         warm = warm_up(cell, gen, args.seed, m, client, names, actives)
         t = time.monotonic()
@@ -453,7 +467,7 @@ def run(args, t_start: float) -> dict:
         note("diag: " + json.dumps(diag))
 
         problems = check(cell, cluster, client, [warm, window], names,
-                         actives, args.seed)
+                         actives, args.seed, initial)
         device = device_report(cell.chips)
         result = {"correct": not problems and e2e["attempted"] > 0,
                   "attempted": e2e["attempted"], "failed": e2e["failed"],

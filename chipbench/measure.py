@@ -18,7 +18,7 @@ and prints per metric the median and two spreads, each a share of the median:
 ``check_spread`` (the quartile rule, the run farthest from the median out)
     A ``benchmark`` PR's bound is refused as too tight where the mean of the
     two sets' ``check_spread`` is over half of it (PR 29 was: 6.1% and 4.6%
-    against 6%).  ``--against <label>`` prints that mean over two sets.
+    against 6%).
 
 ``driver_spread`` (the rule that decides every later PR's check)
     the range of one side's runs, leaving out the run farthest from their

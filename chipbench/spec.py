@@ -100,6 +100,13 @@ def generator(kind: str):
     return importlib.import_module(f"chipbench.generators.{kind}")
 
 
+def reference(name: str):
+    """``references/<name>.py``: ``check_run(ops_by_name, tables_of,
+    readback, initial) -> [problem strings]`` (``references/__init__.py``);
+    a configuration file names its own under ``"reference"``."""
+    return importlib.import_module(f"chipbench.references.{name}")
+
+
 def reader(name: str):
     """``readers/<name>.py``: ``read(run, **args) -> float | None``."""
     return importlib.import_module(f"chipbench.readers.{name}")

@@ -83,6 +83,31 @@ def test_every_cell_loads_and_configs_state_what_they_run(bench):
         spec.load_cell("no-such-cell")
 
 
+def test_every_configuration_names_a_reference_that_exists(bench):
+    files = glob.glob(os.path.join(spec.HERE, "configs", "*.json"))
+    assert len(files) >= 5
+    assert {os.path.join(spec.ROOT, c["file"]) for c in bench["configs"]} \
+        <= set(files)
+    for path in files:
+        config = spec.load_config(path)
+        assert hasattr(spec.reference(config["reference"]), "check_run"), path
+    # a reference is independent of the program
+    for path in glob.glob(os.path.join(spec.HERE, "references", "*.py")):
+        with open(path) as f:
+            assert not re.search(r"^\s*(from|import)\s+gigapaxos", f.read(),
+                                 re.M), path
+
+
+def test_a_traffic_file_names_a_generator_and_may_carry_a_preload():
+    files = glob.glob(os.path.join(spec.HERE, "traffic", "*.json"))
+    assert len(files) >= 2
+    for path in files:
+        traffic = spec.load_traffic(os.path.basename(path)[:-5])
+        assert hasattr(spec.generator(traffic["generator"]), "schedule")
+        assert set(traffic.get("preload", {"key", "value_bytes"})) == {
+            "key", "value_bytes"}
+
+
 def test_the_rehearsal_configuration_is_nothing_but_a_file(bench):
     path = "chipbench/configs/rehearsal-3r-4k.json"
     assert path not in {c["file"] for c in bench["configs"]}

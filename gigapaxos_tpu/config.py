@@ -75,7 +75,8 @@ class PaxosTuning:
     # then processes tick N-1's decision stream (host app execution) while
     # the device computes tick N and the WAL drains.  Holding costs one
     # tick of response latency and buys ticks per second, so a tick holds
-    # only when its inbox left work behind that another tick has to place;
+    # only when its inbox left work behind that another tick has to place
+    # (a bulk leftover, or at least as many requests as it placed);
     # otherwise it completes its outbox itself, as with the option off.
     # Checkpoints drain synchronously.
     pipeline_ticks: bool = False

@@ -77,7 +77,15 @@ class KVApp(Replicable):
     """A tiny deterministic KV store per service name.
 
     Request format (utf-8): ``PUT <key> <value>`` | ``GET <key>`` |
-    ``DEL <key>``; the workload analog of ``TESTPaxosApp.java:60``.
+    ``DEL <key>`` | ``SETRANGE <key> <offset> <bytes>``; the workload analog
+    of ``TESTPaxosApp.java:60``.
+
+    ``SETRANGE`` (Redis's, without its padding) overwrites ``len(bytes)``
+    characters of the value under ``key`` from ``offset`` and answers ``OK``;
+    ``NF`` where the key is absent; ``ERR`` where the offset is no decimal
+    number or the range passes the value's end: a value's width is fixed by
+    its ``PUT``.  It is how a field of a fixed-width record is updated (YCSB's
+    ``update`` with ``writeallfields=false``) without rewriting the record.
     """
 
     def __init__(self):
@@ -98,6 +106,19 @@ class KVApp(Replicable):
             return b"NF" if v is None else v.encode()
         if op == "DEL" and len(parts) >= 2:
             return b"OK" if t.pop(parts[1], None) is not None else b"NF"
+        if op == "SETRANGE" and len(parts) == 3:
+            offset, sep, data = parts[2].partition(" ")
+            v = t.get(parts[1])
+            if v is None:
+                return b"NF"
+            # ascii digits only: int() would also take "+1", " 1", "1_0"
+            if not (sep and offset.isascii() and offset.isdigit()):
+                return b"ERR"
+            start = int(offset)
+            if start + len(data) > len(v):
+                return b"ERR"
+            t[parts[1]] = v[:start] + data + v[start + len(data):]
+            return b"OK"
         return b"ERR"
 
     def checkpoint(self, name: str) -> bytes:

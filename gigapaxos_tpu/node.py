@@ -25,6 +25,7 @@ objects are unchanged — only the manager's mesh placement differs.
 
 from __future__ import annotations
 
+import gc
 import os
 import signal
 import threading
@@ -46,6 +47,10 @@ from .reconfiguration.rc_db import (
     RepliconfigurableReconfiguratorDB,
 )
 from .reconfiguration.reconfigurator import Reconfigurator
+
+#: the collector's thresholds as the process came with them: what a closed
+#: cluster puts back (a serving one collects its oldest generation rarely)
+_GC_THRESHOLD = gc.get_threshold()
 
 
 class RebalancerDaemon:
@@ -275,6 +280,21 @@ class InProcessCluster:
                        *self.reconfigurators.values()):
                 ep.close()
             raise
+        # What the process holds now it holds for as long as it serves: its
+        # modules, both planes' compiled programs and state, the endpoints.
+        # The collector's oldest generation walked all of it every few
+        # seconds of serving, every thread stopped (108-253 ms a time at 1M
+        # groups, four or five times in a 20 s window: PERF.md section 6, PR
+        # 36); frozen, a collection walks what was made since.  That is
+        # still the apps' tables, which a deployment loads after this line
+        # (59-84 ms a collection with 3 x 1M records), so the oldest
+        # generation is collected a hundredth as often while the cluster
+        # serves: the young generations, which take what a request leaves
+        # behind, run as before (1.2 ms a time).  ``close`` puts both back,
+        # so that a closed cluster's cycles are collected.
+        gc.collect()
+        gc.freeze()
+        gc.set_threshold(*_GC_THRESHOLD[:2], 100 * _GC_THRESHOLD[2])
         if start_fd:
             for r in rc_ids:
                 self.fds[r] = FailureDetection(
@@ -465,6 +485,8 @@ class InProcessCluster:
             ar.close()
         for rc in self.reconfigurators.values():
             rc.close()
+        gc.unfreeze()
+        gc.set_threshold(*_GC_THRESHOLD)
 
     def shutdown(self, drain_timeout_s: float = 10.0) -> bool:
         """Graceful stop: drain in-flight work, then close.  Returns the

@@ -16,6 +16,7 @@ import threading
 import time
 from typing import Callable, Optional
 
+from ..utils.heap import grow_arenas_by_whole_heaps
 from ..wal.logger import WalError
 from .manager import PaxosManager
 
@@ -65,6 +66,8 @@ class TickDriver:
         self.manager = manager
         self.idle_sleep_s = idle_sleep_s
         self.drain_ticks = drain_ticks
+        # the tick thread allocates from its own malloc arena (utils/heap.py)
+        grow_arenas_by_whole_heaps()
         #: the exception that fail-stopped this driver, if any
         self.fatal: Optional[BaseException] = None
         #: wall time of the first tick — its compile, for a cold plane

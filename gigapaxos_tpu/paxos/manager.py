@@ -52,6 +52,7 @@ _MGR_SEQ = _itertools.count()
 from . import state as st
 from .bulkstore import BulkOverrun, BulkStore
 from .paystore import PayloadStore
+from ..wal.journal import MAX_RECORD as _WAL_MAX_RECORD
 from ..ops.pallas_gather import check_lanes
 from ..ops.tick import (LP_ASN, LP_EPOCH, LP_HOLDER, LP_UNTIL, LP_WAIT,
                         CompactHostOutbox, CompactPack, HostOutbox, TickInbox,
@@ -61,6 +62,11 @@ from ..ops.tick import (LP_ASN, LP_EPOCH, LP_HOLDER, LP_UNTIL, LP_WAIT,
                         one_or_pair, paxos_tick_planes, sweep_frontier,
                         taken_bit, taken_dense, unpack_compact, unpack_head,
                         unpack_health, unpack_outbox)
+
+
+#: journal bytes of one bulk placement beside its body, rounded up: rid,
+#: entry, p, row and stop columns (21) and the codec's tag and length (5)
+_BULK_JOURNAL_OVERHEAD = 32
 
 
 @dataclass
@@ -457,7 +463,8 @@ class PaxosManager:
         #: misses none
         self._unreturned = collections.deque()
         #: whether the last _build_inbox left work behind that only another
-        #: tick can place: what a pipelined tick holds its outbox for
+        #: tick can place, and as much of it as it placed: what a pipelined
+        #: tick holds its outbox for (tick() has the weighing)
         self._backlog = False
         #: lock-free propose staging (drained at each tick; deque append/
         #: popleft are thread-safe) + a tiny rid-assignment lock that never
@@ -497,6 +504,25 @@ class PaxosManager:
                      "outbox: the one that dispatched them, or the next",
                 plane=spill_ns, mode=mode)
             for mode in ("same_call", "held")}
+        #: what a deployment's bodies and skew cost this plane (PR 36), each
+        #: a histogram of plain numbers: the requests a tick's inbox left
+        #: queued behind P placed for their name; the bytes a tick handed to
+        #: the journal; the bytes of each response handed to a scalar
+        #: request's callback at its entry replica
+        self._deferred_h = _obs_registry().histogram(
+            "inbox_deferred_requests", unit="",
+            help="requests a tick's inbox left queued because their name "
+                 "already had proposals_per_tick placed",
+            plane=spill_ns)
+        self._wal_bytes_h = _obs_registry().histogram(
+            "wal_append_bytes", unit="",
+            help="bytes of the records a tick handed to the journal",
+            plane=spill_ns)
+        self._reply_bytes_h = _obs_registry().histogram(
+            "app_reply_bytes", unit="",
+            help="bytes execute() returned for a scalar request released "
+                 "at its entry replica",
+            plane=spill_ns)
         #: the part of "tally" that is blocked until the program's outbox is
         #: ready, before the pull
         self._device_wait_h = _obs_registry().histogram(
@@ -1242,6 +1268,17 @@ class PaxosManager:
         """Deactivator analog (PaxosManager.java:2951, period
         PC.DEACTIVATION_PERIOD): spill groups idle for
         ``deactivation_ticks``.  Returns the number paused."""
+        # Nobody idle that long is the common answer (every tick a process
+        # runs before its ``deactivation_ticks``-th, and any plane whose
+        # names are all in use), and one pass over the activity column
+        # gives it.  Finding it out below costs a drained pipeline, two
+        # [R, G] pulls and a sort of every resident row in Python: a tick of
+        # 0.5-1 s at 1M groups, every 256 ticks, inside every measured
+        # window (PERF.md section 6, PR 36).
+        active = self._last_active[self._n_members_np > 0]
+        if (not active.size or self.tick_num - int(active.min())
+                < self.cfg.paxos.deactivation_ticks):
+            return 0
         return len(self._pause_eligible(limit=limit, ignore_idle=False))
 
     def _pause_eligible(self, limit: int, ignore_idle: bool) -> List[str]:
@@ -1980,7 +2017,8 @@ class PaxosManager:
             self._bulk_placed = None
         now = time.perf_counter()  # one read for all of this tick's placements
         placed = []
-        backlog = False
+        emptied = []
+        deferred = n_placed = 0
         for row, q in self._queues.items():
             used = collections.Counter()
             take = []
@@ -2013,12 +2051,23 @@ class PaxosManager:
                     self.reqtrace.event(rid, "placed", tick=self.tick_num)
             if take:
                 placed.append((row, take))
-            if q and len(take) == self.P:
-                backlog = True  # more for this row than one tick takes
+                n_placed += len(take)
+            if not q:
+                emptied.append(row)
+            elif len(take) == self.P:
+                deferred += len(q)  # more for this row than one tick takes
+        # a row's queue goes with its last request: this loop visits the
+        # rows that have something queued, not every row a request ever
+        # came for (at 1M groups and 1,000 req/s over fresh names that was
+        # +1.4 us a tick for each name served since the start: the period
+        # grew from 63 to 85 ms inside 20 s, PERF.md section 6, PR 36)
+        for row in emptied:
+            del self._queues[row]
+        self._deferred_h.observe(deferred)
         self._placed = placed
         self._place_bulk(req, stp, placed)
-        self._backlog = bool(backlog or self._bulk_leftover.size
-                             or self._bulk_chunks)
+        self._backlog = bool(self._bulk_leftover.size or self._bulk_chunks
+                             or 0 < n_placed <= deferred)
         # hand the jit copies (the staging buffers get mutated next tick; a
         # zero-copy dispatch aliasing them would race the async step); the
         # WAL reads inbox.alive without a device round-trip.  Two copies
@@ -2126,6 +2175,14 @@ class PaxosManager:
                 p[sel] += cnt
         fit = (p >= 0) & (p < self.P)
         if fit.any():
+            # a tick's placements are journaled as ONE record, and a journal
+            # scan believes none over MAX_RECORD: place what fits in half of
+            # it, bodies and framing, in arrival order (so a key's order
+            # holds), always the first; the others wait a tick.  A wave of
+            # 262,144 bodies of 1 KB is over it (PERF.md section 6, PR 36).
+            cost = np.where(fit, store.pay_len[idx].astype(np.int64)
+                            + _BULK_JOURNAL_OVERHEAD, 0)
+            fit &= np.cumsum(cost) - cost <= _WAL_MAX_RECORD // 2
             fe, fp, fr = entries[fit], p[fit], rows[fit]
             req[fe, fp, fr] = q[fit].astype(np.int32)
             stp[fe, fp, fr] = store.stop[idx[fit]]
@@ -2280,9 +2337,14 @@ class PaxosManager:
         pc.mark("intake")
         # Holding this tick's outbox for the next call overlaps the device
         # with the next inbox's build (a period of max(host, device), not
-        # their sum) and costs every request in it one period.  Ticks per
+        # their sum) and costs every request in it the hand-over.  Ticks per
         # second matter only to work that is waiting for a tick, so a tick
-        # holds only when its inbox could not place all there was.
+        # holds only when its inbox could not place all there was: a bulk
+        # leftover, or requests left queued behind P placed for their name
+        # that are at least as many as the requests it did place.  One hot
+        # name's two or three behind seventy others is not that: at 1M
+        # groups holding took 30 ms off the tick they waited for and added
+        # 29 ms to every reply of the held one (PERF.md section 6, PR 36).
         hold = self.cfg.paxos.pipeline_ticks and self._backlog
         placed = self._placed
         bulk_placed = self._bulk_placed
@@ -2358,7 +2420,8 @@ class PaxosManager:
         this = (packed, placed, bulk_placed, frontier, lease_pack,
                 health_pack, done_at)
         if self.wal is not None:
-            self.wal.log_inbox(self.tick_num, inbox)
+            self._wal_bytes_h.observe(
+                self.wal.log_inbox(self.tick_num, inbox))
         pc.mark("wal_fsync")
         self.tick_num += 1
         self._completions_c["held" if hold else "same_call"].inc()
@@ -2621,6 +2684,7 @@ class PaxosManager:
             rec.responded = True
             if rec.callback is not None:
                 self._held_callbacks.append((rec.callback, rid, response))
+            self._reply_bytes_h.observe(len(response or b""))
             if rec.t_staged and rec.t_placed:  # a recovered record has none
                 self._stage_queue_h.observe(rec.t_placed - rec.t_staged)
                 self._stage_commit_h.observe(

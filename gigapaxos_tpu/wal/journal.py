@@ -59,9 +59,12 @@ KIND_BARRIER = 1
 #: resync plausibility bound: a candidate frame whose seq jumps more than
 #: this past the last good one is treated as a CRC-colliding false positive
 SEQ_SLACK = 1 << 20
-#: largest frame body a scan will believe (matches nothing the loggers
-#: write; a corrupt length field larger than this is rejected immediately)
+#: largest frame body a scan will believe (a corrupt length field larger
+#: than this is rejected immediately), and so the largest a logger may write:
+#: a record over ``MAX_RECORD`` would read back as a scribble, and every
+#: fsynced record behind it with it
 MAX_FRAME = 1 << 28
+MAX_RECORD = MAX_FRAME - _BODY.size
 
 
 class JournalCorruptError(RuntimeError):

@@ -37,7 +37,8 @@ from typing import List, Optional
 import numpy as np
 
 from . import records
-from .journal import JournalCorruptError, iter_scan_records, scan_journal
+from .journal import (MAX_RECORD, JournalCorruptError, iter_scan_records,
+                      scan_journal)
 from ..obs.metrics import registry as _obs_registry
 from ..paxos.paystore import DEDUP_MIN_BYTES, payload_digest
 from ..paxos.state import PaxosState
@@ -317,6 +318,12 @@ class PaxosLogger:
             f"WAL {self.dir} append/fsync failed: {exc}") from exc
 
     def _append(self, rec: bytes) -> None:
+        if len(rec) > MAX_RECORD:
+            # refused BEFORE it reaches the file: a scan would take it for
+            # damage at the next start, with every acked record behind it
+            raise WalError(
+                f"WAL {self.dir}: a record of {len(rec):,} bytes is over "
+                f"the {MAX_RECORD:,} a journal scan believes")
         try:
             self.journal.append(rec)
         except OSError as e:
@@ -528,9 +535,10 @@ class PaxosLogger:
         self._pay_seen.add(d)
         return pl
 
-    def log_inbox(self, tick_num: int, inbox) -> None:
+    def log_inbox(self, tick_num: int, inbox) -> int:
         """Called by the manager after `_build_inbox`, before running the
-        tick: record exactly what was placed, with payloads for replay."""
+        tick: record exactly what was placed, with payloads for replay.
+        Returns the bytes of the records it appended."""
         m = self.manager
         g_log = getattr(m, "G", None)
         has_reg = bool(getattr(m, "G_reg", 0))
@@ -568,10 +576,13 @@ class PaxosLogger:
             entries = _entries(take)
             if entries:
                 placed_with_payloads.append((row, entries))
+        n_bytes = 0
         if reg_placed:
             # appended BEFORE the tick record it belongs to; replay
             # stashes it and folds the rows into the same tick's inbox
-            self._append(records.dumps((OP_REG, tick_num, reg_placed)))
+            reg_bytes = records.dumps((OP_REG, tick_num, reg_placed))
+            self._append(reg_bytes)
+            n_bytes = len(reg_bytes)
         bulk = None
         bp = getattr(m, "_bulk_placed", None)
         if bp is not None:
@@ -602,6 +613,7 @@ class PaxosLogger:
         if self._ticks_since_sync >= self.sync_every:
             self._sync()
             self._ticks_since_sync = 0
+        return n_bytes + len(rec_bytes)
 
     def is_synced(self) -> bool:
         """True when every logged tick is covered by an fsync (the manager

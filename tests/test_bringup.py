@@ -72,6 +72,44 @@ def test_cluster_construction_raises_on_a_dead_plane(monkeypatch):
         InProcessCluster(cfg, KVApp, ready_timeout_s=60)
 
 
+def test_a_ready_cluster_freezes_what_it_holds_and_thaws_it_on_close():
+    """ISSUE 36: once both planes are up the collector's oldest generation
+    no longer walks what the process holds for life (``gc.freeze``); a closed
+    cluster hands all of it back, so that its cycles are collected."""
+    import gc
+
+    from gigapaxos_tpu.node import InProcessCluster
+
+    cfg = _small_cfg()
+    for i in range(3):
+        cfg.nodes.actives[f"AR{i}"] = ("127.0.0.1", 0)
+        cfg.nodes.reconfigurators[f"RC{i}"] = ("127.0.0.1", 0)
+    gc.unfreeze()
+    young, middle, oldest = gc.get_threshold()
+    cluster = InProcessCluster(cfg, KVApp, ready_timeout_s=120)
+    try:
+        # ... and collects that generation a hundredth as often while it
+        # serves (a deployment's records are made after this point, and a
+        # collection walks them with every thread stopped); the young
+        # generations as before
+        assert gc.get_threshold() == (young, middle, 100 * oldest)
+        frozen = gc.get_freeze_count()
+        # the modules alone are tens of thousands of tracked objects
+        assert frozen > 10_000
+        assert gc.isenabled()
+        # what is made from here on is the collector's as before
+        ring = []
+        ring.append(ring)
+        del ring
+        assert gc.collect() >= 1
+        # (a frozen object still goes when its last reference does)
+        assert 10_000 < gc.get_freeze_count() <= frozen
+    finally:
+        cluster.close()
+    assert gc.get_freeze_count() == 0
+    assert gc.get_threshold() == (young, middle, oldest)
+
+
 # ------------------------------------------------------------- compile cache
 def test_compile_cache_goes_where_it_is_placed(monkeypatch, tmp_path):
     import jax

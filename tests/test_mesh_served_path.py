@@ -8,8 +8,8 @@ configuration at 4,096 groups) and on the one-device mapping
 (``rehearsal-3r-4k.json``), both built the way ``chipbench/run.py`` builds a
 cell (``deployment.make_config`` / ``build_cluster`` / ``populate``), with
 the Pallas kernels interpreted.  Every reply and every replica's table is
-held to ``chipbench/reference.py`` (``RefKV``, ``check_run``: what decides a
-run's ``correct`` on the chip); the mapping may change no answer; a write
+held to ``chipbench/references/kv_register.py`` (``RefKV``, ``check_run``:
+what decides a run's ``correct`` on the chip); the mapping may change no answer; a write
 dropped on one replica fails the check; the journal written under the mesh
 replays to the same tables after a restart; and the mesh's ticks count their
 dispatches.  Both configurations set ``pipeline_ticks``: every tick whose
@@ -31,7 +31,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench import deployment, load, reference, spec  # noqa: E402
+from chipbench import deployment, load, spec  # noqa: E402
+from chipbench.references import Op, kv_register  # noqa: E402
 from gigapaxos_tpu.obs.metrics import registry  # noqa: E402
 
 CONFIGS = {"mesh4": "chipbench/configs/rehearsal-3r-4k-mesh4.json",
@@ -99,7 +100,7 @@ class Served:
     devices: int                 # devices the data plane's state lies on
     rc_devices: int              # ... and the control plane's
     replies: list                # (name, request, reply) in the order offered
-    writes: dict                 # name -> [reference.Write] of the PUT phase
+    writes: dict                 # name -> [Op] of the PUT phase, replies in
     tables: dict                 # name -> [one dict per replica], at the end
     readback: dict               # name -> value a GET through the client gave
     problems: list               # check_run on the live cluster
@@ -150,9 +151,10 @@ def serve(mapping: str, run_dir: str) -> Served:
         for (name, payload, _), (sent, done, p) in zip(
                 puts, offer(client, actives, puts)):
             assert p.get("ok"), p
-            writes.setdefault(name, []).append(reference.Write(
-                payload.decode().split(" ", 2)[2], sent, done, "ok"))
             replies.append((name, payload, pkt.b64d(p["response"]) or b""))
+            writes.setdefault(name, []).append(Op(
+                "update", KEY, payload.decode().split(" ", 2)[2], sent, done,
+                "ok", replies[-1][2]))
         for ops in rounds:
             for (name, payload, _), (_, _, p) in zip(
                     ops, offer(client, actives, ops)):
@@ -169,14 +171,12 @@ def serve(mapping: str, run_dir: str) -> Served:
 
         # check_run holds a name's table to its PUTs alone: the names of the
         # mixed rounds (DELs among them) are held to RefKV by the test
-        put_replies = replies[:N_PUTS]
-        problems = reference.check_run(writes, tables_of, put_replies,
-                                       readback, KEY)
+        read = {name: {KEY: got} for name, got in readback.items()}
+        problems = kv_register.check_run(writes, tables_of, read, {})
         # a write dropped on one replica: acknowledged, held by two of three
         victim = m.apps[1].db[f"{acked[0]}#0"]
         dropped = victim.pop(KEY)
-        faulty = reference.check_run(writes, tables_of, put_replies,
-                                     readback, KEY)
+        faulty = kv_register.check_run(writes, tables_of, read, {})
         victim[KEY] = dropped
         tables = {name: copy.deepcopy(tables_of(name)) for name in touched}
         ticks1, counts1, modes1 = _plane_counts(cluster)
@@ -233,7 +233,7 @@ def test_every_reply_and_every_replicas_table_equal_the_references(runs,
                                                                    mapping):
     served = runs[mapping]
     assert len(served.replies) == N_PUTS + ROUNDS * MIXED_NAMES
-    ref = reference.RefKV()
+    ref = kv_register.RefKV()
     kinds = set()
     for name, request, reply in served.replies:
         assert reply == ref.apply(name, request), (name, request, reply)
@@ -303,8 +303,9 @@ def test_the_served_ticks_complete_in_the_call_that_dispatched_them(runs,
                                                                     mapping):
     """``tick_completions_total{plane,mode}`` counts every dispatched tick
     once, and on this schedule (nobody waiting for the device; of the 400
-    concurrent PUTs a handful of names draw more than P, which holds the
-    tick that could not place them all) the same-call side took the rest:
+    concurrent PUTs a handful of names draw more than P, and a tick holds
+    only where those left behind are as many as those it placed: at most a
+    tick or two here) the same-call side took the rest:
     the answers above are the same-call path's, under ``pipeline_ticks``."""
     served = runs[mapping]
     for plane in ("ar", "rc"):

@@ -186,6 +186,54 @@ def test_tick_completion_families_carry_their_labels():
                                   "labels": {"plane": "ar"}}
 
 
+def test_body_and_backlog_histograms_carry_the_plane_and_have_readers():
+    """``inbox_deferred_requests{plane}`` once a tick (0 where nothing was
+    left behind), ``wal_append_bytes{plane}`` once a journaled tick,
+    ``app_reply_bytes{plane}`` once a released scalar request; the
+    benchmark's three metrics of ISSUE 36 name them, data plane ``ar``."""
+    import json
+
+    from gigapaxos_tpu.config import GigapaxosTpuConfig
+    from gigapaxos_tpu.models.replicable import KVApp
+    from gigapaxos_tpu.obs.metrics import registry
+    from gigapaxos_tpu.paxos.manager import PaxosManager
+
+    plane = "t_body_labels"
+    cfg = GigapaxosTpuConfig()
+    cfg.paxos.compact_outbox = True
+    m = PaxosManager(cfg, 3, [KVApp() for _ in range(3)], spill_ns=plane)
+    m.create_paxos_instance("svc", [0, 1, 2])
+    got = []
+    m.propose("svc", b"PUT k " + b"v" * 50, lambda rid, resp: got.append(resp))
+    m.run_ticks(2)
+    burst = 3 * m.P   # one name, one entry: P a tick, the others left behind
+    for i in range(burst):
+        m.propose("svc", b"GET k", lambda rid, resp: got.append(resp), entry=0)
+    m.run_ticks(6)
+    m.drain_pipeline()
+    assert got == [b"OK"] + [b"v" * 50] * burst
+    snap = registry().snapshot()
+    deferred = snap[f"inbox_deferred_requests{{plane={plane}}}"]
+    assert deferred["count"] == m.tick_num == 8
+    # 12 queued: the builds left 8, then 4 behind; the other six none
+    assert deferred["sum"] == 2 * m.P + m.P and deferred["buckets"]["0"] == 6
+    replies = snap[f"app_reply_bytes{{plane={plane}}}"]
+    assert (replies["count"], replies["sum"]) == (1 + burst, 2 + 50 * burst)
+    assert snap[f"wal_append_bytes{{plane={plane}}}"]["count"] == 0  # no WAL
+    for name, reader, family in (
+            ("inbox_clear_pct", "histogram_zero_share",
+             "inbox_deferred_requests"),
+            ("wal_bytes_per_tick", "histogram_mean_value",
+             "wal_append_bytes"),
+            ("reply_bytes_mean", "histogram_mean_value", "app_reply_bytes")):
+        with open(os.path.join(ROOT, "chipbench", "layer_metrics",
+                               name + ".json")) as f:
+            metric = json.load(f)
+        assert metric["reader"] == reader
+        assert metric["args"] == {"family": family,
+                                  "labels": {"plane": "ar"}}
+
+
 def test_wal_fsync_goes_through_instrumented_sync_only():
     """Every durability point must flow through ``_sync`` (timed +
     stall-counted); a bare ``journal.sync()`` anywhere else is an
@@ -230,6 +278,11 @@ WIRING = {
     # compacted plane's completion pulled (ISSUE 34)
     "tick_outbox_pull_seconds": "gigapaxos_tpu/paxos/manager.py",
     "outbox_pulls_total": "gigapaxos_tpu/paxos/manager.py",
+    # what a deployment's bodies and skew cost a plane: requests an inbox
+    # left behind, bytes a tick journaled, bytes of a reply (ISSUE 36)
+    "inbox_deferred_requests": "gigapaxos_tpu/paxos/manager.py",
+    "wal_append_bytes": "gigapaxos_tpu/paxos/manager.py",
+    "app_reply_bytes": "gigapaxos_tpu/paxos/manager.py",
     "jit_compile_seconds": "gigapaxos_tpu/obs/compiles.py",
     "compile_cache_lookups_total": "gigapaxos_tpu/obs/compiles.py",
     "wal_fsync_seconds": "gigapaxos_tpu/wal/logger.py",

@@ -180,3 +180,34 @@ def test_remove_with_inflight_frees_row_counter():
     mgr.propose("y", b"PUT b 2", lambda r, v: got.update({"r": v}))
     assert run_until(mgr, lambda: "r" in got)
     assert mgr.pause_idle(limit=8) == 1
+
+
+def test_pause_idle_answers_nobody_is_idle_without_looking_at_the_device():
+    """ISSUE 36: while no resident name has been idle for
+    ``deactivation_ticks``, ``pause_idle`` says 0 from the activity column:
+    no pipeline drain, no [R, G] pull, no sort of the rows (a 0.5-1 s tick at
+    1M groups, every 256 ticks); once one has, it goes the whole way."""
+    cfg = GigapaxosTpuConfig()
+    cfg.paxos.max_groups = 8
+    cfg.paxos.deactivation_ticks = 6
+    mgr = PaxosManager(cfg, 3, [KVApp() for _ in range(3)])
+    looked = []
+    whole = mgr._pause_eligible
+    mgr._pause_eligible = lambda **kw: looked.append(mgr.tick_num) or whole(**kw)
+    assert mgr.pause_idle() == 0 and not looked      # no resident name at all
+    for name in ("a", "b"):
+        mgr.create_paxos_instance(name, [0, 1, 2])
+    got = {}
+    mgr.propose("a", b"PUT k v", lambda r, v: got.update({"a": v}))
+    assert run_until(mgr, lambda: "a" in got)
+    assert mgr.pause_idle() == 0 and not looked      # both younger than 6
+    while mgr.tick_num < 5:
+        mgr.tick()
+    assert mgr.pause_idle() == 0 and not looked
+    mgr.run_ticks(4)
+    mgr.propose("a", b"PUT k w", lambda r, v: got.update({"a2": v}))
+    assert run_until(mgr, lambda: "a2" in got)
+    # "b" has now been idle for 6 ticks, "a" has not: the whole way, once
+    assert mgr.pause_idle() == 1 and len(looked) == 1
+    assert mgr.rows.row("b") is None and mgr.rows.row("a") is not None
+    assert mgr.pause_idle() == 0 and len(looked) == 1  # only "a" left, young

@@ -167,9 +167,9 @@ def test_batched_replay_bit_identity(tmp_path, mode):
 
 @pytest.mark.parametrize("mode", ["full_pipe", "compact_pipe"])
 def test_replay_does_not_depend_on_the_side_a_tick_took(tmp_path, mode):
-    """Six writes a round to each name, against P = 4: every other tick's
-    inbox leaves some behind and holds its outbox, the ticks between
-    complete their own.  The journal records what was placed, so both
+    """Eight writes a round to each name, against P = 4: every other tick's
+    inbox leaves as many behind as it placed and holds its outbox, the ticks
+    between complete their own.  The journal records what was placed, so both
     replay arms rebuild the plane as it stood, tables included."""
     a = tmp_path / "a"
     a.mkdir()
@@ -177,8 +177,8 @@ def test_replay_does_not_depend_on_the_side_a_tick_took(tmp_path, mode):
     sides = []
     tick = m.tick
     m.tick = lambda: (tick(), sides.append(m._pending_out is not None))[0]
-    drive(m, per_round=6)
-    assert 6 > m.P and sum(a != b for a, b in zip(sides, sides[1:])) >= 10
+    drive(m, per_round=8)
+    assert 8 >= 2 * m.P and sum(a != b for a, b in zip(sides, sides[1:])) >= 10
     m.drain_pipeline()
     tables = [copy.deepcopy(app.db) for app in apps]
     m.wal.close()  # crash

@@ -151,10 +151,11 @@ def test_manager_random_crash_recover_pipelined(tmp_path, seed, compact,
     very delivery that advanced it (the _host_exec watermark fix).
 
     A pipelined tick holds its outbox only when its inbox left work behind
-    (ISSUE 31), which this trickle of arrivals does once or twice a run;
-    ``bursts`` adds, every eleventh tick, more writes to one name at one
-    entry replica than three ticks place, so the run changes sides a dozen
-    times, in both directions, under the same crashes and checkpoints."""
+    (ISSUE 31) and as much of it as it placed (ISSUE 36), which this trickle
+    of arrivals does once or twice a run; ``bursts`` adds, every eleventh
+    tick, more writes to one name at one entry replica than five ticks
+    place, so the run changes sides a dozen times, in both directions, under
+    the same crashes and checkpoints."""
     import os
 
     from gigapaxos_tpu.config import GigapaxosTpuConfig
@@ -205,7 +206,7 @@ def test_manager_random_crash_recover_pipelined(tmp_path, seed, compact,
         k, v = f"t{sent}", f"tv{t}"
         m.propose(f"g{g}", f"PUT {k} {v}".encode(), mk_cb(sent, g, k, v))
         if bursts and t % 11 == 5:
-            for i in range(3 * m.P):
+            for i in range(5 * m.P):
                 m.propose(f"g{t % 4}", f"PUT burst{i} x".encode(),
                           None, False, 0)
         m.tick()

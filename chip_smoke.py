@@ -271,6 +271,7 @@ def serve(log: Log, cluster, cfg, ref: RefKV, seed: int,
     names = [f"smoke{seed}-{i}" for i in range(3)]
     client = ReconfigurableAppClient(cfg.nodes)
     replies = []
+    sides0 = sides()
     try:
         for name in names:
             t = time.monotonic()
@@ -295,6 +296,12 @@ def serve(log: Log, cluster, cfg, ref: RefKV, seed: int,
         read_back(log, cluster, client, ref, names, rpc_timeout_s)
     finally:
         client.close()
+    took = {key: int(v - sides0[key]) for key, v in sides().items()}
+    log(f"served one request at a time; ticks since by where their outbox "
+        f"was completed, outbox buffers by pull and inboxes by path: {took}")
+    # a request or two a tick is a list: no tick copies and uploads [R, P, G]
+    check(took["short"] > 0 and took["dense"] == 0, f"the trickle's inboxes "
+          f"were not all handed over as short lists: {took}")
     return names
 
 
@@ -317,6 +324,22 @@ def read_back(log: Log, cluster, client, ref: RefKV, names,
         f"reference")
 
 
+def sides() -> dict:
+    """The data plane's dispatched ticks by where their outbox was completed
+    (``pipeline_ticks`` is on: a tick may hold it for the next call), its
+    outbox buffers by what was pulled (the head, or the flat buffer whole
+    where a tick decided more than the head holds), and its inboxes by how
+    they reached the device (a short list of placements, or dense)."""
+    from gigapaxos_tpu.obs.metrics import registry
+
+    snap = registry().snapshot()  # the cluster's data plane is "ar"
+    return {key: snap.get(series % key, 0) for series, keys in (
+        ("tick_completions_total{mode=%s,plane=ar}", ("same_call", "held")),
+        ("outbox_pulls_total{plane=ar,pull=%s}", ("head", "full")),
+        ("inbox_builds_total{path=%s,plane=ar}", ("short", "dense")))
+        for key in keys}
+
+
 def _wave(log: Log, cluster, what: str, rows, payloads,
           timeout_s: float) -> list:
     """One ``propose_bulk`` of one request per row; returns the responses
@@ -334,20 +357,6 @@ def _wave(log: Log, cluster, what: str, rows, payloads,
         if sum(b[0] for b in batches) >= n:
             done.set()
 
-    def sides() -> dict:
-        """The plane's dispatched ticks by where their outbox was completed
-        (``pipeline_ticks`` is on: a tick may hold it for the next call),
-        and its outbox buffers by what was pulled (the head, or the flat
-        buffer whole where a tick decided more than the head holds)."""
-        from gigapaxos_tpu.obs.metrics import registry
-
-        snap = registry().snapshot()  # the cluster's data plane is "ar"
-        return {key: snap.get(series % key, 0) for series, keys in (
-            ("tick_completions_total{mode=%s,plane=ar}",
-             ("same_call", "held")),
-            ("outbox_pulls_total{plane=ar,pull=%s}", ("head", "full")))
-            for key in keys}
-
     tick0, t0, sides0 = m.tick_num, time.monotonic(), sides()
     rids = m.propose_bulk(rows, payloads, batch_sink=sink)
     check((rids > 0).all(), f"{what}: {int((rids <= 0).sum())} of {n} "
@@ -359,8 +368,11 @@ def _wave(log: Log, cluster, what: str, rows, payloads,
     log(f"{what}: {n:,} requests admitted at tick {tick0}, completed in "
         f"{time.monotonic() - t0:.2f}s by tick {m.tick_num}, in "
         f"{len(batches)} completion batch(es) (size, tick) {batches[:4]}; "
-        f"ticks since by where their outbox was completed, and outbox "
-        f"buffers by pull: {took}")
+        f"ticks since by where their outbox was completed, outbox buffers "
+        f"by pull and inboxes by path: {took}")
+    # a bulk placement is not a list: its tick hands over the dense inbox
+    check(took["dense"] >= 1, f"{what}: {n:,} requests placed in bulk and "
+          f"no inbox handed over dense (path=dense): {took}")
     # one tick executes the wave on every replica: past the head, that
     # tick's outbox is the one pull of the whole flat buffer
     if m.R * n > m._compact_layout.head_exec:

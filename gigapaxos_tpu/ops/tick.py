@@ -1861,6 +1861,23 @@ def _coo_inbox(x, R: int, P: int, g_total: int) -> TickInbox:
     return TickInbox(req, stop, x["alive"])
 
 
+def _scatter_inbox_impl(cols, R: int, P: int, g_total: int):
+    """A served tick's scalar placements -> the dense ``(req, stop)`` its
+    program takes: ``cols`` is [5, K] i32, the rows (entry, lane, row, rid,
+    stop) of :func:`_coo_inbox`'s columns, padded with row == g_total."""
+    e, p, g, rid, stop = cols
+    ib = _coo_inbox(dict(e=e, p=p, g=g, rid=rid, stop=stop != 0, alive=None),
+                    R, P, g_total)
+    return ib.req, ib.stop
+
+
+#: The inbox of a tick that placed few requests, made on the device from a
+#: list of them (``PaxosManager._build_inbox``): the host hands over K
+#: placements, not [R, P, G] (63 MB at 1M groups).  One small program beside
+#: the tick, whose own programs take its result as they take numpy arrays.
+scatter_inbox = jax.jit(_scatter_inbox_impl, static_argnums=(1, 2, 3))
+
+
 def _replay_scan_impl(planes: TickPlanes, xs, P: int, params: TickParams,
                       scat_budget: int):
     R, g_log = planes.state.exec_slot.shape

@@ -65,9 +65,13 @@ What the two-dispatch structure costs was measured on a v5e-4 host at 1M
 groups (PERF.md sections 5 and 6, ``probe-1m-mesh4-open1k``): the tick
 program is the cheap half, and the compaction is most of the device time of
 a tick, because the partitioner all-gathers its full-width ``[R, W, G]``
-operands onto every chip before the rank (ROADMAP A8).  The host pays more
-than the device: the ``dispatch`` phase commits a numpy ``[R, P, G]`` inbox
-to four devices' layout every tick.  The tick's outputs cross the dispatch
+operands onto every chip before the rank (ROADMAP A8).  The host paid more
+than the device while the ``dispatch`` phase committed a numpy ``[R, P, G]``
+inbox to four devices' layout every tick; since PR 37 a tick that placed
+few requests hands over a list of them and ``jit__scatter_inbox_impl``
+(:func:`make_mesh_scatter_inbox`, a third small dispatch) makes the inbox
+in that layout on the devices; the dense commit is left to ticks that
+placed in bulk.  The tick's outputs cross the dispatch
 boundary as ordinary committed sharded arrays and stay device-resident.
 
 Why two programs and not one: the split dates from a jax release on which
@@ -224,6 +228,15 @@ def make_mesh_compact(exec_budget: int, lag_budget: int):
                                        lag_budget=lag_budget)
 
     return jax.jit(mesh_compact_outbox, donate_argnums=(0,))
+
+
+def make_mesh_scatter_inbox(mesh: Mesh):
+    """``tk.scatter_inbox`` with its result laid out as the mesh tick takes
+    its inbox (its ``in_shardings``): the list goes to every device, a few
+    kilobytes, and the tick finds ``req`` / ``stop`` in place."""
+    sh = inbox_shardings(mesh)
+    return jax.jit(tk._scatter_inbox_impl, static_argnums=(1, 2, 3),
+                   out_shardings=(sh.req, sh.stop))
 
 
 def make_shardmap_tick_compact(mesh: Mesh, own_row: int, exec_budget: int,

@@ -59,10 +59,19 @@ from ..ops.tick import (LP_ASN, LP_EPOCH, LP_HOLDER, LP_UNTIL, LP_WAIT,
                         TickParams, TickPlanes, compact_path, frontier_rows,
                         health_clear_rows, init_health, lease_clear_rows,
                         merge_compact_outbox, merge_health, merge_outbox,
-                        one_or_pair, paxos_tick_planes, sweep_frontier,
-                        taken_bit, taken_dense, unpack_compact, unpack_head,
-                        unpack_health, unpack_outbox)
+                        one_or_pair, paxos_tick_planes, scatter_inbox,
+                        sweep_frontier, taken_bit, taken_dense,
+                        unpack_compact, unpack_head, unpack_health,
+                        unpack_outbox)
 
+
+#: The most scalar placements a tick hands the device as a list, for
+#: ``ops.tick.scatter_inbox`` to make the [R, P, G] inbox there; a tick that
+#: placed more, or anything in bulk, hands over the dense arrays.  Sized from
+#: what XLA:TPU charges a scatter, 4.6-4.9 ns per offered update with the
+#: padding (PERF.md section 6): 20 us an array, against 60-140 placements a
+#: tick at 1,000 req/s.  One length, so one program.
+_SHORT_INBOX = 4096
 
 #: journal bytes of one bulk placement beside its body, rounded up: rid,
 #: entry, p, row and stop columns (21) and the codec's tag and length (5)
@@ -301,6 +310,8 @@ class PaxosManager:
         self.mesh = None
         self._mesh_tick = None
         self._mesh_tick_compact = None
+        #: makes a short inbox on the device(s), laid out as the tick takes it
+        self._scatter_inbox = scatter_inbox
         if cfg.paxos.mesh_devices:
             import jax
 
@@ -335,6 +346,7 @@ class PaxosManager:
                 )
             else:
                 self._mesh_tick = _stk.make_shardmap_tick(self.mesh, -1)
+            self._scatter_inbox = _stk.make_mesh_scatter_inbox(self.mesh)
             # recreate the state distributed (each device materializes only
             # its shard; no single-device peak)
             self.state = st.init_state(
@@ -449,9 +461,12 @@ class PaxosManager:
         # zeroed lazily at the next build instead of reallocating R*P*G
         self._in_req = np.zeros((self.R, self.P, self.G_total), np.int32)
         self._in_stp = np.zeros((self.R, self.P, self.G_total), bool)
-        #: the two copies of them that ticks are handed in turn, made at the
-        #: first build (see _build_inbox)
+        #: the two copies of them that dense ticks are handed in turn, made
+        #: at the first dense build (see _build_inbox)
         self._in_handed: list = []
+        #: the device's all-zero (req, stop): the first short inbox that
+        #: placed nothing, handed to every such tick after it
+        self._zero_inbox = None
         self._placed: list = []
         #: pipelined mode: what _complete_tick needs of a dispatched tick
         #: whose outbox was held, consumed by the next call (SURVEY §2.2
@@ -546,6 +561,21 @@ class PaxosManager:
                      "tick and plane: the head, or the flat buffer whole",
                 plane=spill_ns, pull=pull)
             for pull in ("head", "full")}
+        #: how each tick's inbox reached the device: a list of its
+        #: placements (or the resident all-zero inbox), or the dense arrays;
+        #: and the bytes of what _build_inbox handed to the dispatch
+        self._inbox_builds_c = {
+            path: _obs_registry().counter(
+                "inbox_builds_total",
+                help="tick inboxes by how they were handed to the device: a "
+                     "short list of placements scattered there, or dense",
+                plane=spill_ns, path=path)
+            for path in ("short", "dense")}
+        self._inbox_bytes_h = _obs_registry().histogram(
+            "inbox_upload_bytes", unit="",
+            help="bytes of the host arrays a tick's inbox handed to the "
+                 "dispatch",
+            plane=spill_ns)
         #: which branch the device's compaction took for each list, one
         #: increment per compaction; mirrored from the header this loop
         #: reads anyway through the rule the device used (compact_path)
@@ -2017,8 +2047,9 @@ class PaxosManager:
             self._bulk_placed = None
         now = time.perf_counter()  # one read for all of this tick's placements
         placed = []
+        cols = []  # (entry, p, row, rid, stop) of each: scatter_inbox's rows
         emptied = []
-        deferred = n_placed = 0
+        deferred = 0
         for row, q in self._queues.items():
             used = collections.Counter()
             take = []
@@ -2045,13 +2076,13 @@ class PaxosManager:
                 req[entry, p, row] = rid
                 stp[entry, p, row] = rec.stop
                 take.append((rid, entry, p))
+                cols.append((entry, p, row, rid, rec.stop))
                 if not rec.t_placed:
                     rec.t_placed = now  # a rejected intake is placed again
                 if self.reqtrace.enabled:
                     self.reqtrace.event(rid, "placed", tick=self.tick_num)
             if take:
                 placed.append((row, take))
-                n_placed += len(take)
             if not q:
                 emptied.append(row)
             elif len(take) == self.P:
@@ -2067,11 +2098,21 @@ class PaxosManager:
         self._placed = placed
         self._place_bulk(req, stp, placed)
         self._backlog = bool(self._bulk_leftover.size or self._bulk_chunks
-                             or 0 < n_placed <= deferred)
+                             or 0 < len(cols) <= deferred)
+        alive = self.alive.copy()  # the WAL reads it: no device round-trip
+        # The inbox is the list ``cols`` and what _place_bulk placed; a tick
+        # that placed nothing in bulk and no more than the list holds hands
+        # over the list.  A manager's first build is dense whatever it
+        # placed: a plane is ready after its first tick (TickDriver
+        # .wait_ready), and by then its tick has been dispatched with numpy
+        # arrays and the resident copies below exist; the first short one,
+        # one tick on, compiles scatter_inbox, before any traffic.
+        if (self._bulk_placed is None and len(cols) <= _SHORT_INBOX
+                and self._in_handed):
+            return self._short_inbox(cols, alive)
         # hand the jit copies (the staging buffers get mutated next tick; a
-        # zero-copy dispatch aliasing them would race the async step); the
-        # WAL reads inbox.alive without a device round-trip.  Two copies
-        # taken in turn, not fresh ones: at most one tick is in flight when
+        # zero-copy dispatch aliasing them would race the async step).  Two
+        # copies taken in turn, not fresh ones: at most one tick is in flight when
         # the next is built (_pending_out), so the copy handed out two
         # builds ago has been consumed, and a fresh [R, P, G] array is
         # memory the kernel pages in anew every tick: most of this phase
@@ -2084,7 +2125,32 @@ class PaxosManager:
         self._in_handed.reverse()
         np.copyto(out_req, req)
         np.copyto(out_stp, stp)
-        return TickInbox(out_req, out_stp, self.alive.copy())
+        self._inbox_builds_c["dense"].inc()
+        self._inbox_bytes_h.observe(out_req.nbytes + out_stp.nbytes)
+        return TickInbox(out_req, out_stp, alive)
+
+    def _short_inbox(self, cols: list, alive) -> TickInbox:
+        """The inbox of a tick that placed at most ``_SHORT_INBOX`` requests
+        and none in bulk: the device makes ``req`` / ``stop`` from the list
+        (``ops.tick.scatter_inbox``: what the dense arrays hold, bit for
+        bit) and the tick's program takes them as it takes numpy arrays,
+        without the two [R, P, G] copies here and their upload (63 MB a
+        tick at 1M groups).  The list is a fresh array each tick: the
+        program that reads it may still be running when the next is
+        built."""
+        self._inbox_builds_c["short"].inc()
+        if not cols and self._zero_inbox is not None:
+            # every tick of an idle plane: no tick donates its inbox
+            self._inbox_bytes_h.observe(0)
+            return TickInbox(*self._zero_inbox, alive)
+        x = np.zeros((5, _SHORT_INBOX), np.int32)
+        x[2] = self.G_total  # padding: one past the rows, dropped
+        x[:, :len(cols)] = np.array(cols, np.int32).reshape(-1, 5).T
+        made = self._scatter_inbox(x, self.R, self.P, self.G_total)
+        if not cols:
+            self._zero_inbox = made
+        self._inbox_bytes_h.observe(x.nbytes)
+        return TickInbox(*made, alive)
 
     def _place_bulk(self, req, stp, placed) -> None:
         """Vectorized placement of the bulk queue into the staging arrays:
@@ -2304,6 +2370,16 @@ class PaxosManager:
             demand_decay=(self._placement.decay
                           if self._demand_dev is not None else 0.0))
 
+    def _feed_governor(self) -> None:
+        """The intake governor's view of this node's client backlog: staged
+        + queued + in-flight scalar work + the live bulk window
+        (watermark-with-hysteresis shed, ISSUE 14).  Fed at the top of each
+        tick and before a completed tick's responses are released."""
+        if self.overload is not None:
+            self.overload.update(
+                self.pending_count() + len(self.outstanding)
+                + (self.bulk.n_live if self.bulk is not None else 0))
+
     @_locked
     def tick(self):
         """One manager step.  Returns the tick's :class:`HostOutbox` (full
@@ -2319,13 +2395,7 @@ class PaxosManager:
         not yet returned, or None."""
         pc = self._pc
         pc.begin()
-        if self.overload is not None:
-            # feed the intake governor once per tick: staged + queued +
-            # in-flight scalar work + the live bulk window is the node's
-            # client backlog (watermark-with-hysteresis shed, ISSUE 14)
-            self.overload.update(
-                self.pending_count() + len(self.outstanding)
-                + (self.bulk.n_live if self.bulk is not None else 0))
+        self._feed_governor()
         self._run_due_laggard_syncs()
         pc.mark("repair")
         reg = None
@@ -2367,8 +2437,9 @@ class PaxosManager:
         if self._device_app:
             self.state, self.kv, packed = res
         elif self.mesh is not None:
-            # numpy inbox: committed to the mesh layout by in_shardings on
-            # entry, as is the state after any eager admin-op mutation
+            # a dense (numpy) inbox is committed to the mesh layout by
+            # in_shardings on entry (a short one was made in it), as is the
+            # state after any eager admin-op mutation
             self.state, packed, *demand = res
             if demand:
                 # placement: the demand EWMA folds inside the compact
@@ -2528,6 +2599,12 @@ class PaxosManager:
             pc.mark("tally")
             self._process_outbox(out, placed, bulk_placed)
         pc.mark("execute")
+        # again before the responses leave: a caller that sends its next
+        # batch the moment the last was answered must meet a governor that
+        # knows the batch is done, not one that sheds for it until the next
+        # tick starts (the benchmark's preload sends its waves so and lost
+        # that race at 1M groups: PERF.md section 6, PR 37)
+        self._feed_governor()
         self._flush_callbacks()
         pc.mark("egress")
         if done_at % self._sweep_every == 0:

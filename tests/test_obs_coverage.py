@@ -234,6 +234,59 @@ def test_body_and_backlog_histograms_carry_the_plane_and_have_readers():
                                   "labels": {"plane": "ar"}}
 
 
+def test_inbox_path_families_carry_their_labels_and_have_a_reader():
+    """``inbox_builds_total{plane,path=short|dense}`` rises once per tick
+    with how its inbox reached the device, and ``inbox_upload_bytes{plane}``
+    is observed once per tick with the bytes of the host arrays
+    ``_build_inbox`` handed to the dispatch (ISSUE 37): the dense pair, a
+    list of ``_SHORT_INBOX`` placements, or nothing where the resident
+    all-zero inbox went out again.  The benchmark's ``inbox_bytes_per_tick``
+    reads the histogram's mean on the data plane."""
+    import json
+
+    import numpy as np
+
+    from gigapaxos_tpu.config import GigapaxosTpuConfig
+    from gigapaxos_tpu.models.replicable import KVApp
+    from gigapaxos_tpu.obs.metrics import registry
+    from gigapaxos_tpu.paxos import manager as manager_mod
+    from gigapaxos_tpu.paxos.manager import PaxosManager
+
+    src = _src(DRIVER_FILES["modea"])
+    build = src[src.index("def _build_inbox"):src.index("def _place_bulk")]
+    # each way out of the build counts itself once and observes its bytes
+    assert build.count('self._inbox_builds_c["dense"].inc()') == 1
+    assert build.count('self._inbox_builds_c["short"].inc()') == 1
+    assert build.count("self._inbox_bytes_h.observe(") == 3
+
+    plane = "t_inbox_labels"
+    cfg = GigapaxosTpuConfig()
+    cfg.paxos.compact_outbox = True
+    m = PaxosManager(cfg, 3, [KVApp() for _ in range(3)], spill_ns=plane)
+    m.create_paxos_instance("svc", [0, 1, 2])
+    m.run_ticks(2)  # the first build is dense; the second placed nothing
+    m.propose("svc", b"PUT k v")
+    m.run_ticks(1)
+    m.propose_bulk(np.array([m.rows.row("svc")]), b"PUT k w")
+    m.run_ticks(2)
+    snap = registry().snapshot()
+    assert snap[f"inbox_builds_total{{path=short,plane={plane}}}"] == 3
+    assert snap[f"inbox_builds_total{{path=dense,plane={plane}}}"] == 2
+    up = snap[f"inbox_upload_bytes{{plane={plane}}}"]
+    dense = 5 * 3 * m.P * m.G_total  # int32 req and bool stop
+    # the first empty list made the resident inbox; the second handed it out
+    assert up["count"] == m.tick_num == 5 and up["buckets"]["0"] == 1
+    assert up["sum"] == 2 * dense + 2 * 5 * 4 * manager_mod._SHORT_INBOX
+    with open(os.path.join(ROOT, "chipbench", "layer_metrics",
+                           "inbox_bytes_per_tick.json")) as f:
+        metric = json.load(f)
+    assert metric["reader"] == "histogram_mean_value"
+    assert metric["args"] == {"family": "inbox_upload_bytes",
+                              "labels": {"plane": "ar"}}
+    assert (metric["unit"], metric["better"], metric["layer"],
+            metric["moves"]) == ("B", "lower", "host loop", "commit_p50_ms")
+
+
 def test_wal_fsync_goes_through_instrumented_sync_only():
     """Every durability point must flow through ``_sync`` (timed +
     stall-counted); a bare ``journal.sync()`` anywhere else is an
@@ -283,6 +336,10 @@ WIRING = {
     "inbox_deferred_requests": "gigapaxos_tpu/paxos/manager.py",
     "wal_append_bytes": "gigapaxos_tpu/paxos/manager.py",
     "app_reply_bytes": "gigapaxos_tpu/paxos/manager.py",
+    # how a tick's inbox reached the device, a list of its placements or
+    # dense, and the bytes handed to the dispatch (ISSUE 37)
+    "inbox_builds_total": "gigapaxos_tpu/paxos/manager.py",
+    "inbox_upload_bytes": "gigapaxos_tpu/paxos/manager.py",
     "jit_compile_seconds": "gigapaxos_tpu/obs/compiles.py",
     "compile_cache_lookups_total": "gigapaxos_tpu/obs/compiles.py",
     "wal_fsync_seconds": "gigapaxos_tpu/wal/logger.py",

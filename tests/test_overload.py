@@ -157,6 +157,39 @@ def test_modea_governor_sheds_client_not_control():
     assert ok and ok[0] > 0
 
 
+def test_modea_governor_knows_a_batch_is_done_when_its_answers_leave():
+    """A caller that sends its next batch the moment the last was answered
+    (the benchmark's preload sends its waves so) meets a governor fed
+    before the answers were released: it shed while the batch was its
+    backlog, and admits the next one without waiting for a tick to start."""
+    import numpy as np
+
+    m = _manager(intake_hi=4)
+    names = ["svc"] + [f"g{i}" for i in range(5)]
+    for name in names[1:]:
+        m.create_paxos_instance(name, [0, 1, 2])
+    rows = np.array([m.rows.row(n) for n in names], np.int64)
+    answered = []
+
+    def sink(offsets, responses):
+        answered.extend(responses)
+
+    for wave in (b"w1", b"w2", b"w3"):
+        before = m.overload.transitions
+        rids = m.propose_bulk(rows, wave, batch_sink=sink,
+                              cls=overload.CLS_CLIENT)
+        assert (rids > 0).all(), (wave, rids, m.overload.backlog)
+        del answered[:]
+        for _ in range(10):
+            m.tick()
+            if len(answered) == len(rows):
+                break
+        assert len(answered) == len(rows)
+        # six at once is past the watermark: it shed, and it has stopped
+        assert m.overload.transitions == before + 2
+        assert not m.overload.shedding
+
+
 # ---------------------------------------------------- Mode B node intake
 def test_modeb_flood_nacks_then_resumes():
     from gigapaxos_tpu.modeb import ModeBNode

@@ -426,12 +426,17 @@ def test_pipelined_plane_is_pending_only_while_somebody_waits():
 
 
 @pytest.mark.parametrize("pipeline", [True, False])
-def test_inbox_copies_are_handed_in_turn_and_outlive_their_tick(pipeline):
-    """``_build_inbox`` hands the tick one of two resident copies of the
-    staging arrays, not a fresh one: the copy a tick got stays as it was
-    through the next build (a held tick's program may still be reading
-    it), never aliases the staging arrays, and comes back two builds
-    later."""
+def test_inbox_copies_are_handed_in_turn_and_outlive_their_tick(
+        pipeline, monkeypatch):
+    """A dense ``_build_inbox`` (one that placed in bulk, or more than the
+    short list holds: forced here, tests/test_short_inbox.py has the
+    choice) hands the tick one of two resident copies of the staging
+    arrays, not a fresh one: the copy a tick got stays as it was through
+    the next build (a held tick's program may still be reading it), never
+    aliases the staging arrays, and comes back two builds later."""
+    from gigapaxos_tpu.paxos import manager as manager_mod
+
+    monkeypatch.setattr(manager_mod, "_SHORT_INBOX", -1)
     with tempfile.TemporaryDirectory() as tmp:
         m, wal, _ = make_manager(tmp, pipeline=pipeline)
         got = []

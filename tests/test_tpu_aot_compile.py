@@ -102,6 +102,14 @@ programs = [
      (m_state, m_inbox), 26, 0),
     ("compaction of a sharded outbox over four chips",
      stk.make_mesh_compact(E, Lb), (m_outbox,), 0, 1),
+    # the inbox of a tick that placed few requests, from a list of them
+    ("short inbox scatter", tk.scatter_inbox, (S((5, 4096)), R, P, G), 0, 0),
+    ("short inbox scatter into the four chips' layout",
+     stk.make_mesh_scatter_inbox(mesh),
+     (jax.ShapeDtypeStruct((5, 4096), jnp.int32,
+                           sharding=jax.sharding.NamedSharding(
+                               mesh, jax.sharding.PartitionSpec())),
+      R, P, G), 0, 0),
 ]
 for name, fn, args, want, heads in programs:
     low = fn.lower(*args)

@@ -271,7 +271,7 @@ def serve(log: Log, cluster, cfg, ref: RefKV, seed: int,
     names = [f"smoke{seed}-{i}" for i in range(3)]
     client = ReconfigurableAppClient(cfg.nodes)
     replies = []
-    sides0 = sides()
+    sides0 = sides(cluster.manager)
     try:
         for name in names:
             t = time.monotonic()
@@ -296,12 +296,23 @@ def serve(log: Log, cluster, cfg, ref: RefKV, seed: int,
         read_back(log, cluster, client, ref, names, rpc_timeout_s)
     finally:
         client.close()
-    took = {key: int(v - sides0[key]) for key, v in sides().items()}
+    took = {key: int(v - sides0[key])
+            for key, v in sides(cluster.manager).items()}
     log(f"served one request at a time; ticks since by where their outbox "
-        f"was completed, outbox buffers by pull and inboxes by path: {took}")
+        f"was completed, outbox buffers by pull, inboxes by path and exec "
+        f"lists by the compaction's branch: {took}")
     # a request or two a tick is a list: no tick copies and uploads [R, P, G]
     check(took["short"] > 0 and took["dense"] == 0, f"the trickle's inboxes "
           f"were not all handed over as short lists: {took}")
+    # and a few executions a tick take the narrow end of the ladder: no
+    # tick's compaction paid for the widest sparse width or the whole plane
+    tiers = exec_tiers(cluster.manager)
+    if tiers:
+        wide = [f"exec:sparse{tiers[-1]}", "exec:dense"]
+        check(all(took[k] == 0 for k in wide) and sum(
+            took[f"exec:sparse{k}"] for k in tiers[:-1]) > 0,
+            f"the trickle's ticks did not all compact at the narrow widths "
+            f"{tiers[:-1]}: {took}")
     return names
 
 
@@ -324,20 +335,34 @@ def read_back(log: Log, cluster, client, ref: RefKV, names,
         f"reference")
 
 
-def sides() -> dict:
+def sides(m) -> dict:
     """The data plane's dispatched ticks by where their outbox was completed
     (``pipeline_ticks`` is on: a tick may hold it for the next call), its
     outbox buffers by what was pulled (the head, or the flat buffer whole
-    where a tick decided more than the head holds), and its inboxes by how
-    they reached the device (a short list of placements, or dense)."""
+    where a tick decided more than the head holds), its inboxes by how
+    they reached the device (a short list of placements, or dense), and its
+    exec lists by the branch the compaction took (``exec:sparse<K>`` for
+    each width of ``m``'s ladder, ``exec:dense``)."""
     from gigapaxos_tpu.obs.metrics import registry
 
     snap = registry().snapshot()  # the cluster's data plane is "ar"
-    return {key: snap.get(series % key, 0) for series, keys in (
+    took = {key: snap.get(series % key, 0) for series, keys in (
         ("tick_completions_total{mode=%s,plane=ar}", ("same_call", "held")),
         ("outbox_pulls_total{plane=ar,pull=%s}", ("head", "full")),
         ("inbox_builds_total{path=%s,plane=ar}", ("short", "dense")))
         for key in keys}
+    for path in [f"sparse{k}" for k in exec_tiers(m)] + ["dense"]:
+        took[f"exec:{path}"] = snap.get(
+            f"compact_path_ticks_total{{list=exec,path={path},plane=ar}}", 0)
+    return took
+
+
+def exec_tiers(m) -> tuple:
+    """The widths of the data plane's exec-list compaction, narrowest
+    first; empty where the plane is too narrow for any (the rehearsal's)."""
+    from gigapaxos_tpu.ops.tick import compact_tiers
+
+    return compact_tiers(m.R * m.W * m.G, m._exec_budget)
 
 
 def _wave(log: Log, cluster, what: str, rows, payloads,
@@ -357,19 +382,20 @@ def _wave(log: Log, cluster, what: str, rows, payloads,
         if sum(b[0] for b in batches) >= n:
             done.set()
 
-    tick0, t0, sides0 = m.tick_num, time.monotonic(), sides()
+    tick0, t0, sides0 = m.tick_num, time.monotonic(), sides(m)
     rids = m.propose_bulk(rows, payloads, batch_sink=sink)
     check((rids > 0).all(), f"{what}: {int((rids <= 0).sum())} of {n} "
           f"requests not admitted")
     cluster.driver.kick()
     check(done.wait(timeout_s), f"{what}: {sum(b[0] for b in batches)} of "
           f"{n} completed within {timeout_s:.0f}s")
-    took = {mode: int(v - sides0[mode]) for mode, v in sides().items()}
+    took = {mode: int(v - sides0[mode]) for mode, v in sides(m).items()}
     log(f"{what}: {n:,} requests admitted at tick {tick0}, completed in "
         f"{time.monotonic() - t0:.2f}s by tick {m.tick_num}, in "
         f"{len(batches)} completion batch(es) (size, tick) {batches[:4]}; "
         f"ticks since by where their outbox was completed, outbox buffers "
-        f"by pull and inboxes by path: {took}")
+        f"by pull, inboxes by path and exec lists by the compaction's "
+        f"branch: {took}")
     # a bulk placement is not a list: its tick hands over the dense inbox
     check(took["dense"] >= 1, f"{what}: {n:,} requests placed in bulk and "
           f"no inbox handed over dense (path=dense): {took}")
@@ -378,6 +404,10 @@ def _wave(log: Log, cluster, what: str, rows, payloads,
     if m.R * n > m._compact_layout.head_exec:
         check(took["full"] >= 1, f"{what}: {m.R * n:,} executions in one "
               f"tick and no outbox pulled whole (pull=full): {took}")
+    # past the widest sparse width the compaction is the dense code
+    if m.R * n > max(exec_tiers(m), default=0):
+        check(took["exec:dense"] >= 1, f"{what}: {m.R * n:,} executions in "
+              f"one tick and no exec list compacted dense: {took}")
     # completions fire once per entry replica; all from one tick's pass =
     # decided and executed by one tick, hence admitted by one
     ticks = {t for _, t in batches}

@@ -1178,17 +1178,20 @@ def unpack_outbox(flat, R: int, P: int, W: int, G: int) -> HostOutbox:
 # analog of the reference shipping individual DECISION packets instead of
 # whole acceptor state, PaxosInstanceStateMachine.java:1755-1842).
 #
-# What it costs (PERF.md sections 5 and 6, measured at 1M groups).  On the
-# device: while at most ``compact_blocks()`` entries were decided (8,192 of
-# the 12.6M offered positions, 1,024 laggards), O(K) scatter updates on top
-# of one bandwidth-bound pass over the masks — about 8 ms — through
-# :func:`_compact_columns`' block-sparse branch; above that the dense
-# prefix-sum scatter over the whole plane, which is O(R*W*G) per column
-# whatever was decided (about 330 ms).  The host mirrors which one ran in
-# ``compact_path_ticks_total``.  The flat buffer is bounded but not
-# O(decisions): ``CompactLayout.total_plain`` words however few decided —
-# ``taken_bits`` (R*G words) plus four exec columns of ``exec_budget`` (2G
-# by default) words each, 46 MB at 1M.  So the compaction also returns a
+# What it costs (PERF.md sections 5 and 6, measured at 1M groups on one
+# v5e).  On the device: while at most ``compact_blocks()`` entries were
+# decided (8,192 of the 12.6M offered positions, 1,024 laggards), O(K)
+# scatter updates on top of one bandwidth-bound pass over the masks, at the
+# narrowest width K of :func:`compact_tiers` that holds the count: the
+# whole compaction (both lists) takes about 2.2 ms with both at K = 128,
+# 2.8 ms with the exec list at 1,024 (a served tick's few hundred) and
+# 7.6 ms at 8,192; above that the dense prefix-sum scatter over the whole
+# plane, which is O(R*W*G) per column whatever was decided (about 330 ms).
+# The host mirrors which one ran in ``compact_path_ticks_total``.  The
+# flat buffer is bounded but not O(decisions): ``CompactLayout.total_plain``
+# words however few decided — ``taken_bits`` (R*G words) plus four exec
+# columns of ``exec_budget`` (2G by default) words each, 46 MB at 1M.  So
+# the compaction also returns a
 # HEAD of it (``CompactLayout.total_head`` words, 1.7 MB at 1M: the
 # acceptance bits packed ``32 // P`` groups to a word and the first
 # ``head_exec`` entries of the exec columns), and the host pulls the head
@@ -1259,27 +1262,41 @@ def taken_dense(co: CompactHostOutbox, G: int) -> "np.ndarray":
 
 #: the block-sparse compaction views a flat mask as rows of one lane row
 _BLOCK = 128
-#: the most hits (hence non-empty blocks) the sparse branch of
+#: the most hits (hence non-empty blocks) the sparse code of
 #: :func:`_compact_columns` takes; above it the dense code runs.  Chosen on
-#: the chip (PERF.md section 6): the sparse branch costs what K*_BLOCK
+#: the chip (PERF.md section 6): the sparse code costs what K*_BLOCK
 #: scatter updates cost whatever was decided, the dense one what the whole
 #: plane costs.
 _SPARSE_BLOCKS = 8192
+#: the narrower widths of the sparse code, tried before ``_SPARSE_BLOCKS``:
+#: a tick takes the narrowest K that holds its count.  Each was kept on the
+#: chip for what it saves over the next width up (PERF.md section 6)
+_SPARSE_TIERS = (128, 1024)
 
 
 def compact_blocks(n: int, capacity: int) -> int:
-    """K of the block-sparse compaction of an ``n``-wide mask into
-    ``capacity`` slots; 0 where the plane is too narrow for it to pay (the
-    compaction is then the dense code alone).  A function of shapes only:
-    the device branches on it and the host mirrors it (:func:`compact_path`)."""
+    """The widest K of the block-sparse compaction of an ``n``-wide mask
+    into ``capacity`` slots; 0 where the plane is too narrow for it to pay
+    (the compaction is then the dense code alone)."""
     k = min(_SPARSE_BLOCKS, capacity)
     return k if n > k * _BLOCK else 0
 
 
-def compact_path(n: int, capacity: int, count: int) -> str:
-    """Which branch :func:`_compact_columns` took for ``count`` hits."""
+def compact_tiers(n: int, capacity: int) -> tuple:
+    """Every K of that compaction, narrowest first; empty where
+    :func:`compact_blocks` is 0.  A function of shapes only: the device
+    branches on it and the host mirrors it (:func:`compact_path`)."""
     k = compact_blocks(n, capacity)
-    return "sparse" if k and count <= k else "dense"
+    return tuple(t for t in _SPARSE_TIERS if t < k) + (k,) if k else ()
+
+
+def compact_path(n: int, capacity: int, count: int) -> str:
+    """Which branch :func:`_compact_columns` took for ``count`` hits:
+    ``sparse<K>`` or ``dense``."""
+    for k in compact_tiers(n, capacity):
+        if count <= k:
+            return f"sparse{k}"
+    return "dense"
 
 
 def _compact_columns(mask_flat, cols, capacity: int):
@@ -1288,19 +1305,21 @@ def _compact_columns(mask_flat, cols, capacity: int):
     ``capacity``; hits past ``capacity`` are dropped.  Returns ``(count,
     i32 [len(cols), capacity])``.
 
-    One algorithm at two widths, chosen on the device from the mask's
+    One algorithm at several widths, chosen on the device from the mask's
     popcount.  XLA:TPU runs an element-granular scatter at about 4.6 ns per
     *offered* update, kept or dropped, so the dense code costs the plane's
     width per column whatever was decided.  With at most K hits at most K
-    blocks of ``_BLOCK`` are non-empty: the sparse branch row-gathers those
+    blocks of ``_BLOCK`` are non-empty: the sparse code row-gathers those
     blocks of the mask, ranks inside the ``[K, _BLOCK]`` tile, scatters each
     hit's *source position* to its output slot (the one K*_BLOCK-update
-    scatter) and then gathers K elements per column."""
+    scatter) and then gathers K elements per column.  So the count picks
+    the narrowest K of :func:`compact_tiers` that holds it, and every width
+    fills the same slots with the same words."""
     n = mask_flat.shape[0]
     mi = mask_flat.astype(I32)
     count = jnp.sum(mi)
     cols = [c.reshape(-1).astype(I32) for c in cols]
-    K = compact_blocks(n, capacity)
+    tiers = compact_tiers(n, capacity)
 
     def dense():
         rank = jnp.cumsum(mi) - mi
@@ -1310,10 +1329,10 @@ def _compact_columns(mask_flat, cols, capacity: int):
             for c in cols
         ])
 
-    if not K:
+    if not tiers:
         return count, dense()
 
-    def sparse():
+    def sparse(K):
         B = _BLOCK
         nb = -(-n // B)
         m2 = jnp.pad(mi, (0, nb * B - n)).reshape(nb, B)
@@ -1335,7 +1354,10 @@ def _compact_columns(mask_flat, cols, capacity: int):
         packed = jnp.stack([jnp.where(hit, c[srcc], 0) for c in cols])
         return jnp.pad(packed, ((0, 0), (0, capacity - K)))
 
-    return count, jax.lax.cond(count <= K, sparse, dense)
+    # the first tier that holds the count, or the dense code past the last
+    branch = jnp.sum(count > jnp.asarray(tiers, I32))
+    return count, jax.lax.switch(
+        branch, [functools.partial(sparse, k) for k in tiers] + [dense])
 
 
 def _exec_mask(out: TickOutbox):

@@ -25,6 +25,7 @@ axis exchange goes over the transport instead (net/, Mode B).
 from __future__ import annotations
 
 import collections
+import functools
 import threading
 import time
 from dataclasses import dataclass, field
@@ -579,13 +580,12 @@ class PaxosManager:
         #: which branch the device's compaction took for each list, one
         #: increment per compaction; mirrored from the header this loop
         #: reads anyway through the rule the device used (compact_path)
-        self._compact_path_c = {
-            (lst, path): _obs_registry().counter(
-                "compact_path_ticks_total",
-                help="outbox compactions by list and the branch the device "
-                     "took (block-sparse, or dense over the whole plane)",
-                plane=spill_ns, list=lst, path=path)
-            for lst in ("exec", "lag") for path in ("sparse", "dense")}
+        self._compact_path_c = functools.partial(
+            _obs_registry().counter, "compact_path_ticks_total",
+            help="outbox compactions by list and the branch the device took "
+                 "(block-sparse at the width K of sparse<K>, or dense over "
+                 "the whole plane)",
+            plane=spill_ns)
         #: programs a sharded plane's ticks enqueued (parallel/shard_tick.py):
         #: one tick is two dispatches, three with the placement fold
         self._mesh_dispatch_c = {}
@@ -2639,7 +2639,8 @@ class PaxosManager:
         for lst, n, cap, count in (
                 ("exec", self.R * W * G, self._exec_budget, co.n_exec),
                 ("lag", self.R * G, self._lag_budget, co.lag_n)):
-            self._compact_path_c[lst, compact_path(n, cap, count)].inc()
+            self._compact_path_c(list=lst,
+                                 path=compact_path(n, cap, count)).inc()
 
     @_locked
     def drain_pipeline(self) -> None:

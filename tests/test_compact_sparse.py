@@ -10,6 +10,7 @@ of the buffer (ISSUE 34) to it: the same host outbox while ``n_exec <= K``,
 refused by its own header above.
 """
 
+import contextlib
 import functools
 from unittest import mock
 
@@ -67,15 +68,24 @@ def exec_counts(rng, kind) -> np.ndarray:
         cnt[1, 256:296] = 1
     elif kind == "one_per_block":  # j = 0 of every 128th group: K blocks
         cnt[0, :K * 128:128] = 1
+    elif isinstance(kind, tuple):  # (layout, n): n hits in one block, or
+        layout, n = kind           # one a block (flat[i] is lane j = 0)
+        if layout == "one_block":
+            cnt[1, 256:256 + n] = 1
+        else:
+            flat[:n * 128:128] = 1
     else:  # that many single hits (count 1 -> lane j = 0)
         flat[rng.choice(R * G, kind, replace=False)] = 1
     return cnt
 
 
-def random_outbox(seed: int, hits, laggards: int) -> dict:
+def random_outbox(seed: int, hits, laggards) -> dict:
+    """``laggards``: that many at random, or ``("one_per_block", n)``."""
     rng = np.random.default_rng(seed)
     lag = rng.integers(0, W, (R, G)).astype(np.int32)  # none reaches W
-    lag.reshape(-1)[rng.choice(R * G, laggards, replace=False)] = W + 3
+    lag.reshape(-1)[np.arange(laggards[1]) * 128
+                    if isinstance(laggards, tuple)
+                    else rng.choice(R * G, laggards, replace=False)] = W + 3
     i32 = lambda hi, shape: rng.integers(-1, hi, shape).astype(np.int32)
     return dict(
         exec_req=i32(1 << 30, (R, W, G)),
@@ -132,24 +142,36 @@ CASES = [
 def test_packed_buffer_equals_the_reference_word_for_word(compact, case):
     hits, laggards = CASES[case]
     out = random_outbox(case, hits, laggards)
-    want = reference_buffer(out)
     got, head = compact(device_outbox(out))
+    with small_k():
+        assert_reference_buffer(out, got, head)
+
+
+def expected_path(ladder: tuple, count: int) -> str:
+    """The tier the rule names for ``count``: the narrowest that holds it."""
+    return next((f"sparse{k}" for k in ladder if count <= k), "dense")
+
+
+def assert_reference_buffer(out: dict, got, head) -> None:
+    """``got`` and ``head`` (one compaction of ``out``, traced under the
+    module constants in force) hold ``reference_buffer(out)`` word for word;
+    the host mirrors the branch from the header alone."""
+    want = reference_buffer(out)
     assert got.shape == want.shape == (
         tk.CompactLayout(R, G, E, LB).total_plain,)
     bad = np.flatnonzero(got != want)
     assert bad.size == 0, (bad[:8], got[bad[:8]], want[bad[:8]])
-    # and the host mirrors the branch from the header alone
     n_exec, lag_n = int(got[0]), int(got[2])
-    with small_k():
-        assert (tk.compact_path(N, E, n_exec) == "sparse") == (n_exec <= K)
-        assert (tk.compact_path(R * G, LB, lag_n) == "sparse") == (
-            lag_n <= LB)
-        assert head.shape == (tk.CompactLayout(R, G, E, LB, P).total_head,)
-    # the head holds what the sparse branch can fill: the same host outbox
+    for n, cap, count in ((N, E, n_exec), (R * G, LB, lag_n)):
+        assert tk.compact_path(n, cap, count) == expected_path(
+            tk.compact_tiers(n, cap), count)
+    Kh = tk.CompactLayout(R, G, E, LB, P).head_exec
+    assert head.shape == (tk.CompactLayout(R, G, E, LB, P).total_head,)
+    # the head holds what the sparse code can fill: the same host outbox
     # from a seventh of the words, or None and the flat buffer is pulled
     co = tk.unpack_compact(got, R, G, E, LB)
     co_h = tk.unpack_head(head, R, G, P, E, LB)
-    assert (co_h is None) == (n_exec > K)
+    assert (co_h is None) == (n_exec > Kh)
     if co_h is not None:
         for f, a, b in zip(co._fields, co_h, co):
             if f == "taken_bits":
@@ -158,17 +180,105 @@ def test_packed_buffer_equals_the_reference_word_for_word(compact, case):
                 (a, b) == (P, 0)), f
 
 
+#: the ladder lowered for this plane: exec tiers (8, 64), laggard (8, 32)
+LADDER = (8,)
+
+
+@contextlib.contextmanager
+def small_ladder():
+    """``small_k()`` with a narrower tier below it, while a program is
+    traced: every list of this plane has two sparse widths and the dense
+    code."""
+    with mock.patch.object(tk, "_SPARSE_TIERS", LADDER), small_k():
+        assert tk.compact_tiers(N, E) == (8, K)
+        assert tk.compact_tiers(R * G, LB) == (8, LB)
+        yield
+
+
+@pytest.fixture(scope="module")
+def ladder_compact():
+    """(flat, head) of one compaction under the lowered ladder, on one
+    device or GSPMD-partitioned over four virtual devices."""
+    from jax.sharding import NamedSharding
+
+    from gigapaxos_tpu.parallel import mesh as pmesh
+    from gigapaxos_tpu.parallel.shard_tick import _OUTBOX_SPECS
+
+    fns = {}
+
+    def run(out: dict, where: str) -> tuple:
+        put = lambda k, v: jnp.asarray(v)
+        if where == "mesh":
+            if len(jax.devices()) < 4:
+                pytest.skip("needs 4 virtual devices")
+            mesh = pmesh.make_mesh(jax.devices()[:4], replica_shards=1)
+            put = lambda k, v: jax.device_put(
+                v, NamedSharding(mesh, _OUTBOX_SPECS[k]))
+        fn = fns.setdefault(where, jax.jit(functools.partial(
+            tk._compact_outbox_impl, exec_budget=E, lag_budget=LB)))
+        with small_ladder():
+            return tuple(np.asarray(a) for a in fn(device_outbox(out, put)))
+
+    return run
+
+
+#: (list, tier, count - tier, where the hits lie) at every tier boundary
+BOUNDARIES = [(lst, k, d, lay)
+              for lst, tiers in (("exec", (8, K)), ("lag", (8, LB)))
+              for k in tiers for d in (-1, 0, 1)
+              for lay in (("one_per_block", "one_block") if lst == "exec"
+                          else ("one_per_block",))]
+
+
+@pytest.mark.parametrize("where", ["one_device", "mesh"])
+@pytest.mark.parametrize("lst,tier,delta,layout", BOUNDARIES, ids=[
+    f"{lst}-{k}{d:+d}-{lay}" for lst, k, d, lay in BOUNDARIES])
+def test_every_tier_boundary_equals_the_reference_word_for_word(
+        ladder_compact, lst, tier, delta, layout, where):
+    """A count one under, at and one over each width of the ladder: the
+    flat buffer and the head are the reference's whichever width ran.  A
+    width too narrow for the count would drop its last hit (one hit a block
+    fills one row of the tile a tier has no row for; hits in one block rank
+    past the tier's slots), so equality at ``tier + 1`` is the device
+    having gone wider."""
+    count = tier + delta
+    exec_hits, laggards = ((layout, count), 0) if lst == "exec" else (
+        3, (layout, count))
+    out = random_outbox(3 * tier + delta, exec_hits, laggards)
+    got, head = ladder_compact(out, where)
+    assert int(got[0 if lst == "exec" else 2]) == count
+    n, cap = (N, E) if lst == "exec" else (R * G, LB)
+    with small_ladder():
+        assert_reference_buffer(out, got, head)
+        ladder = tk.compact_tiers(n, cap)
+        up = ladder.index(tier) + (delta > 0)
+        assert tk.compact_path(n, cap, count) == (
+            f"sparse{ladder[up]}" if up < len(ladder) else "dense")
+
+
 def test_served_path_k_leaves_test_sized_planes_dense():
     # every plane the tier-1 tests build: decided from the shape, no cond
     assert tk.compact_blocks(3 * 4 * 4096, 8192) == 0
     assert tk.compact_blocks(3 * 4096, 1024) == 0
+    assert tk.compact_tiers(3 * 4 * 4096, 8192) == ()
+    assert tk.compact_tiers(3 * 4096, 1024) == ()
     assert tk.compact_path(3 * 4 * 4096, 8192, 0) == "dense"
-    # the benchmark's planes: both lists have the sparse branch
+    # the benchmark's planes: both lists have the sparse ladder, and a
+    # served tick's few hundred executions take its narrow end
     for g in (1 << 17, 1 << 20):
         assert tk.compact_blocks(3 * 4 * g, 2 * g) == tk._SPARSE_BLOCKS
         assert tk.compact_blocks(3 * g, 1024) == 1024
-        assert tk.compact_path(3 * 4 * g, 2 * g, 2310) == "sparse"
+        assert tk.compact_tiers(3 * 4 * g, 2 * g) == (
+            *tk._SPARSE_TIERS, tk._SPARSE_BLOCKS)
+        assert tk.compact_tiers(3 * g, 1024) == tuple(
+            t for t in tk._SPARSE_TIERS if t < 1024) + (1024,)
+        for count in (0, 128, 129, 400, 1024, 1025, 2310, 8192):
+            assert tk.compact_path(3 * 4 * g, 2 * g, count) == (
+                expected_path(tk.compact_tiers(3 * 4 * g, 2 * g), count))
+        assert tk.compact_path(3 * 4 * g, 2 * g, 2310) == "sparse8192"
         assert tk.compact_path(3 * 4 * g, 2 * g, 3 * 65536) == "dense"
+        assert tk.compact_path(3 * g, 1024, 0) == (
+            f"sparse{tk.compact_tiers(3 * g, 1024)[0]}")
 
 
 @pytest.mark.parametrize("tail", [0, 1, 127])

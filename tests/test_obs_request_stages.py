@@ -389,45 +389,63 @@ def _counter(snap: dict, family: str, **labels) -> float:
                and want <= set(key.partition("{")[2].rstrip("}").split(",")))
 
 
+def _tier_named(tiers: tuple, count: int) -> str:
+    """The narrowest width of ``tiers`` that holds ``count``, as the
+    counter's ``path`` names it; ``dense`` past the last."""
+    return next((f"sparse{k}" for k in tiers if count <= k), "dense")
+
+
 @pytest.mark.parametrize("mesh_devices", [0, 4])
-@pytest.mark.parametrize("k_blocks", [None, 2])
+@pytest.mark.parametrize("k_blocks", [None, 2, (3, 6)],
+                         ids=["None", "2", "3-6"])
 def test_compact_path_counter_rises_once_per_list_per_tick(monkeypatch,
                                                            k_blocks,
                                                            mesh_devices):
     """``compact_path_ticks_total`` mirrors, from the header alone, the rule
-    the device branched on: with the served K a test-sized plane is dense
-    from its shape; with K lowered to 2 a tick is ``sparse`` exactly while
-    ``n_exec <= K``.  The same through the manager with the group axis
-    sharded over four devices, where the compaction is the mesh tick's
-    second dispatch: both branches, and the answers of the one-device run."""
+    the device branched on: with the served ladder a test-sized plane is
+    dense from its shape; with K lowered to 2 a tick is ``sparse2`` exactly
+    while ``n_exec <= 2``; with the ladder lowered to (3, 6) it names the
+    narrowest width that holds the tick (0 and 3 executions: ``sparse3``, 6:
+    ``sparse6``, 12: ``dense``).  The same through the manager with the
+    group axis sharded over four devices, where the compaction is the mesh
+    tick's second dispatch: every branch, and the answers of the one-device
+    run."""
     if k_blocks is not None:
-        monkeypatch.setattr(tk, "_SPARSE_BLOCKS", k_blocks)
+        *tiers, top = k_blocks if isinstance(k_blocks, tuple) else (k_blocks,)
+        monkeypatch.setattr(tk, "_SPARSE_BLOCKS", top)
+        monkeypatch.setattr(tk, "_SPARSE_TIERS", tuple(tiers))
     cfg = GigapaxosTpuConfig()
     cfg.paxos.max_groups = 512 if mesh_devices else 128
     cfg.paxos.compact_outbox = True
     cfg.paxos.pipeline_ticks = False
     cfg.paxos.mesh_devices = mesh_devices
-    plane = f"t_compact_path_{k_blocks}_{mesh_devices}"
+    ladder = ("-".join(map(str, k_blocks)) if isinstance(k_blocks, tuple)
+              else k_blocks)
+    plane = f"t_compact_path_{ladder}_{mesh_devices}"
     apps = [KVApp() for _ in range(3)]
     m = PaxosManager(cfg, 3, apps, spill_ns=plane)
     assert (m.mesh is not None) == bool(mesh_devices)
     names = [f"g{i}" for i in range(4)]
     for name in names:
         m.create_paxos_instance(name, [0, 1, 2])
-    want = {(lst, path): 0 for lst in ("exec", "lag")
-            for path in ("sparse", "dense")}
+    lists = (("exec", m.R * m.W * m.G, m._exec_budget),
+             ("lag", m.R * m.G, m._lag_budget))
+    paths = {lst: [f"sparse{k}" for k in tk.compact_tiers(n, cap)] + ["dense"]
+             for lst, n, cap in lists}
+    want = {(lst, path): 0 for lst in paths for path in paths[lst]}
     reg = registry()
     snap0 = reg.snapshot()
     ticks = 8
+    # requests proposed before a tick, one per name: each is executed on
+    # the three replicas in that tick (0, 3, 6 and 12 executions)
+    proposed = {1: 4, 3: 1, 5: 2}
     for t in range(ticks):
-        if t == 1:  # one tick decides four requests: 12 executions > K = 2
-            for name in names:
-                m.propose(name, b"PUT k v", lambda *a: None)
+        for name in names[:proposed.get(t, 0)]:
+            m.propose(name, b"PUT k v", lambda *a: None)
         out = m.tick()
-        k = tk.compact_blocks(m.R * m.W * m.G, m._exec_budget)
-        want["exec", "sparse" if k and out.n_exec <= k else "dense"] += 1
-        kl = tk.compact_blocks(m.R * m.G, m._lag_budget)
-        want["lag", "sparse" if kl and out.lag_n <= kl else "dense"] += 1
+        for lst, n, cap in lists:
+            count = out.n_exec if lst == "exec" else out.lag_n
+            want[lst, _tier_named(tk.compact_tiers(n, cap), count)] += 1
     snap1 = reg.snapshot()
     got = {key: _counter(snap1, "compact_path_ticks_total", plane=plane,
                          list=key[0], path=key[1])
@@ -437,11 +455,34 @@ def test_compact_path_counter_rises_once_per_list_per_tick(monkeypatch,
     assert sum(v for (lst, _), v in got.items() if lst == "exec") == ticks
     assert sum(v for (lst, _), v in got.items() if lst == "lag") == ticks
     if k_blocks is None:
-        assert got["exec", "sparse"] == got["lag", "sparse"] == 0
-    else:  # both branches were met
-        assert got["exec", "sparse"] > 0 and got["exec", "dense"] > 0
+        assert paths["exec"] == paths["lag"] == ["dense"]
+    else:  # every branch of the exec list was met
+        assert all(got["exec", path] > 0 for path in paths["exec"]), got
+        assert len(paths["exec"]) == (3 if isinstance(k_blocks, tuple)
+                                      else 2)
     # whichever branch compacted them, the four requests were executed
     assert [a.db for a in apps] == [{name: {"k": "v"} for name in names}] * 3
     programs = {prog: _counter(snap1, "mesh_dispatches_total", plane=plane,
                                program=prog) for prog in ("tick", "compact")}
     assert programs == dict.fromkeys(programs, ticks if mesh_devices else 0)
+
+
+#: every width of the served ladder at 1M groups, per list, and its bounds
+_SERVED_BOUNDARIES = [
+    (lst, n, cap, k, d)
+    for lst, n, cap in (("exec", 3 * 4 * (1 << 20), 2 << 20),
+                        ("lag", 3 * (1 << 20), 1024))
+    for k in tk.compact_tiers(n, cap) for d in (-1, 0, 1)]
+
+
+@pytest.mark.parametrize("lst,n,cap,k,d", _SERVED_BOUNDARIES, ids=[
+    f"{lst}-{k}{d:+d}" for lst, _, _, k, d in _SERVED_BOUNDARIES])
+def test_compact_path_names_the_tier_at_every_boundary(lst, n, cap, k, d):
+    """The host's mirror of the device's switch at the benchmark's widths:
+    one under and at a width name it, one over names the next (or dense)."""
+    tiers = tk.compact_tiers(n, cap)
+    assert tiers == ((128, 1024, 8192) if lst == "exec" else (128, 1024))
+    up = tiers.index(k) + (d > 0)
+    assert tk.compact_path(n, cap, k + d) == (
+        f"sparse{tiers[up]}" if up < len(tiers) else "dense")
+    assert tk.compact_path(n, cap, k + d) == _tier_named(tiers, k + d)

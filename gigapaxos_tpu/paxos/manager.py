@@ -2425,7 +2425,8 @@ class PaxosManager:
         # the BatchedLogger overlap, AbstractPaxosLogger.java:99-107).  Safe
         # because responses stay held until is_synced() (log-before-respond).
         fn, args = self.tick_program(inbox, reg)
-        res = fn(*args)
+        with pc.part("launch"):
+            res = fn(*args)
         # Let go of the donated planes HERE, not when this call returns:
         # the assignments below then drop the last references to them
         # inside this phase.  Measured at 1M on the chip (PERF.md section
@@ -2433,35 +2434,37 @@ class PaxosManager:
         # inputs) which the wait at completion is then shorter by, and the
         # whole tick is 6-16 ms shorter than when they are held until the
         # outbox is done.
-        del args
-        if self._device_app:
-            self.state, self.kv, packed = res
-        elif self.mesh is not None:
-            # a dense (numpy) inbox is committed to the mesh layout by
-            # in_shardings on entry (a short one was made in it), as is the
-            # state after any eager admin-op mutation
-            self.state, packed, *demand = res
-            if demand:
-                # placement: the demand EWMA folds inside the compact
-                # dispatch (decided_now is donated away otherwise)
-                self._demand_dev, = demand
-                self._placement.adopt_device(self._demand_dev)
-                self._mesh_dispatch_c["fold"].inc()
-            self._mesh_dispatch_c["tick"].inc()
-            if self._mesh_tick_compact is not None:
-                self._mesh_dispatch_c["compact"].inc()
-        else:
-            planes, packs = res
-            (self.state, self.rstate, self._lease, self._rlease,
-             self._health, self._rhealth, demand) = planes
-            if demand is not None:
-                self._demand_dev = demand
-                self._placement.adopt_device(demand)
-            # a register plane makes each a (log, register) pair, pulled
-            # and merged into composite rows at completion
-            packed = one_or_pair(packs.out, packs.rout)
-            lease_pack = one_or_pair(packs.lease_pack, packs.rlease_pack)
-            health_pack = one_or_pair(packs.health_pack, packs.rhealth_pack)
+        with pc.part("release"):
+            del args
+            if self._device_app:
+                self.state, self.kv, packed = res
+            elif self.mesh is not None:
+                # a dense (numpy) inbox is committed to the mesh layout by
+                # in_shardings on entry (a short one was made in it), as is
+                # the state after any eager admin-op mutation
+                self.state, packed, *demand = res
+                if demand:
+                    # placement: the demand EWMA folds inside the compact
+                    # dispatch (decided_now is donated away otherwise)
+                    self._demand_dev, = demand
+                    self._placement.adopt_device(self._demand_dev)
+                    self._mesh_dispatch_c["fold"].inc()
+                self._mesh_dispatch_c["tick"].inc()
+                if self._mesh_tick_compact is not None:
+                    self._mesh_dispatch_c["compact"].inc()
+            else:
+                planes, packs = res
+                (self.state, self.rstate, self._lease, self._rlease,
+                 self._health, self._rhealth, demand) = planes
+                if demand is not None:
+                    self._demand_dev = demand
+                    self._placement.adopt_device(demand)
+                # a register plane makes each a (log, register) pair, pulled
+                # and merged into composite rows at completion
+                packed = one_or_pair(packs.out, packs.rout)
+                lease_pack = one_or_pair(packs.lease_pack, packs.rlease_pack)
+                health_pack = one_or_pair(packs.health_pack,
+                                          packs.rhealth_pack)
         # Device sweep frontier: computed ONLY at the dispatch of a tick
         # whose completion runs _sweep_outstanding (1 in 64 ticks, by the
         # tick's own number: whichever call completes it), from THIS tick's
@@ -2482,11 +2485,12 @@ class PaxosManager:
         if self.rstate is None and done_at % self._sweep_every == 0 and (
             self.outstanding or (self.bulk is not None and self.bulk.n_live)
         ):
-            fr = sweep_frontier(
-                self.state.exec_slot, self.state.member, inbox.alive
-            )
-            if fr is not None:
-                frontier = self._frontier_gather(fr)
+            with pc.part("frontier"):
+                fr = sweep_frontier(
+                    self.state.exec_slot, self.state.member, inbox.alive
+                )
+                if fr is not None:
+                    frontier = self._frontier_gather(fr)
         pc.mark("dispatch")
         this = (packed, placed, bulk_placed, frontier, lease_pack,
                 health_pack, done_at)

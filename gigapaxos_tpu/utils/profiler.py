@@ -101,10 +101,6 @@ class DelayProfiler:
             self._rate_last.clear()
 
 
-# Module-level default instance (the reference's DelayProfiler is static).
-profiler = DelayProfiler()
-
-
 class Sampler:
     """The 1-in-N instrumentation gate (``instrument(n)``,
     PaxosInstanceStateMachine.java:135-158): ``if sampler(): profiler...``."""

@@ -14,7 +14,8 @@ fsync fails here in milliseconds instead of silently holing a dashboard.
 import os
 import re
 
-from gigapaxos_tpu.obs.phase import DRIVER_PHASES, PHASE_RUNS, TICK_SCOPES
+from gigapaxos_tpu.obs.phase import (DRIVER_PARTS, DRIVER_PHASES, PHASE_RUNS,
+                                     TICK_SCOPES)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -72,6 +73,31 @@ def test_phase_runs_are_the_order_the_driver_marks_in():
         in_source = [p for i, p in enumerate(marks)
                      if i == 0 or marks[i - 1] != p]
         assert in_source == flat, (driver, in_source)
+
+
+def test_every_declared_part_is_timed_in_its_phase_and_none_other():
+    """DRIVER_PARTS (obs/phase.py) against the drivers, both ways: every
+    part a driver declares is timed with ``pc.part`` in its tick, none it
+    times is undeclared, and each sits between the mark that opens its
+    phase and the mark that closes it (the clock names a part after the
+    phase that is open, known only for a phase of ``PHASE_RUNS``)."""
+    for driver, rel in DRIVER_FILES.items():
+        src = _src(rel)
+        used = re.findall(r'\bpc\.part\(\s*["\']([a-z_]+)["\']', src)
+        phases = DRIVER_PARTS.get(driver, {})
+        declared = [p for parts in phases.values() for p in parts]
+        assert len(declared) == len(set(declared)), driver
+        assert sorted(used) == sorted(declared), (rel, used, declared)
+        runs = [list(run) for run in PHASE_RUNS.get(driver, ())]
+        for phase_name, parts in phases.items():
+            run = next(r for r in runs if phase_name in r)
+            i = run.index(phase_name)
+            assert i > 0, "a part of the first phase of a run"
+            opened = src.index(f'pc.mark("{run[i - 1]}")')
+            closed = src.index(f'pc.mark("{phase_name}")', opened)
+            for part in parts:
+                at = src.index(f'pc.part("{part}")')
+                assert opened < at < closed, (driver, phase_name, part)
 
 
 def test_tick_scopes_are_the_scopes_of_the_tick_programs():
@@ -317,6 +343,9 @@ WIRING = {
     # metric family -> file that must create it
     "tick_phase_seconds": "gigapaxos_tpu/obs/phase.py",
     "tick_seconds": "gigapaxos_tpu/obs/phase.py",
+    # the thread's CPU time per phase, and the parts of a phase
+    "tick_phase_cpu_seconds": "gigapaxos_tpu/obs/phase.py",
+    "tick_part_seconds": "gigapaxos_tpu/obs/phase.py",
     # where a request's time goes and what stalls a tick (ISSUE 26)
     "request_stage_seconds": "gigapaxos_tpu/paxos/manager.py",
     # which branch the device's outbox compaction took (ISSUE 27)

@@ -464,6 +464,16 @@ def kernels_in(fn, *args) -> dict:
     }
 
 
+def kernel_builds() -> dict:
+    """``pallas_kernel_builds_total`` by kernel and tile lanes: the tiles
+    the process's kernels took (one increment a distinct build)."""
+    from gigapaxos_tpu.obs.metrics import registry
+
+    fam = "pallas_kernel_builds_total{"
+    return {k[len(fam):-1]: v for k, v in registry().snapshot().items()
+            if k.startswith(fam)}
+
+
 def tick_program(m):
     """The jitted tick ``PaxosManager.tick`` dispatches for this manager,
     with arguments shaped like the state it holds: the manager says which
@@ -491,7 +501,8 @@ def prove_device_path(log: Log, m, what: str, on_chip: bool) -> dict:
         check(fn._cache_size() > 0, f"{what}: the manager never dispatched "
               f"{getattr(fn, '__name__', fn)} - tick_program() is stale")
     k = kernels_in(fn, *args)
-    log(f"{what}: {k}")
+    log(f"{what}: {k}; kernel builds by tile lanes so far: "
+        f"{kernel_builds()}")
     check(k["pallas_calls"] > 0, f"{what}: no pallas_call traced - the "
           f"program runs the XLA select chain")
     if on_chip:

@@ -8,24 +8,41 @@ configuration (W=8, G=1M) is ~768 MB per gather and ~10 gathers per tick
 (byte counts from shapes), scaling with W².
 
 This kernel performs the same per-lane permutation entirely in VMEM: each
-grid step loads one ``[Wp, Gb]`` tile and its ``[J, Gb]`` index tile, emits
-``out[j, g] = arr[idx[j, g], g]`` via an unrolled Wp-way select on
-registers, and writes ``[J, Gb]`` back — HBM traffic is exactly one read of
-``arr`` + ``idx`` and one write of ``out`` (the W² work stays on the VPU).
+grid step loads one ``[lead, Wp, Gb]`` tile of ``arr`` and its ``[J, Gb]``
+(or per-lead ``[lead, J, Gb]``) index tile, emits
+``out[l, j, g] = arr[l, idx[j, g], g]`` via an unrolled Wp-way select on
+registers, lead row by lead row, and writes ``[lead, J, Gb]`` back — HBM
+traffic is one read of ``arr`` + ``idx`` and one write of ``out`` (the W²
+work stays on the VPU).  ``match_planes_pallas`` tiles the same way.
+
+**The tile.**  The grid runs over lane blocks only, and ``Gb`` is the
+widest ``128 · 2^k`` that divides G and keeps the double-buffered tiles
+(arr + idx + out, two buffers each) within :data:`VMEM_BUDGET`
+(:func:`gather_lanes`, :func:`match_lanes`): 32,768 lanes at the tick's
+``[3, 4, 1M]`` int32 gathers (32 steps), 65,536 for ``[4, 1M]``, 16,384 at
+five replicas, 128 where G = 4,224.  A fixed 4,096-lane tile with the lead
+axis on the grid made a 1M-lane ``[3, 4, G]`` call 768 steps of 192 KB,
+about 0.23 µs of HBM time each at 819 GB/s, and the steps took about
+0.48 µs (a v5e trace: 370 µs a call): half of every call was the fixed
+cost of a step.  That layout also fetched a shared ``[J, G]`` index once
+per lead row (3 × 16.8 MB at 1M where 16.8 MB is needed); with the lead
+axis in the block it is fetched once per lane block.  On v5e the folded
+lead axis is most of the gain (768 steps -> 256 at 4,096 lanes); past
+8,192 lanes the width moves a call by a few percent.
 
 Used by the fused ticks whenever they run on a TPU backend
 (``use_pallas_gather()``), and there it is the ONLY path: a shape the
 kernels cannot serve is a trace-time error, never a silent drop to the
 select chain.  The one-hot XLA path is what other backends run (the CPU
 suite) and the semantic reference (``tests/test_pallas_gather.py`` checks
-the two against each other).
+the two against each other).  Each distinct build counts the lanes it took
+in ``pallas_kernel_builds_total{kernel, lanes}``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
-import math
 import os
 import threading
 
@@ -43,46 +60,81 @@ GATHER_KERNEL = "gather_planes_pallas"
 MATCH_KERNEL = "match_planes_pallas"
 
 
-def _lane_block(g: int) -> int:
-    """Largest power-of-two-times-128 divisor of g, capped at 4096 lanes
-    (callers only guarantee g % 128 == 0 — e.g. max_groups = 4224)."""
-    return math.gcd(g, 4096)
+#: VMEM one call's tiles may take, double-buffered (arr + idx + out blocks,
+#: two buffers each): under the 16 MiB of scoped VMEM a v5e kernel gets by
+#: default, so no compiler parameter is needed
+VMEM_BUDGET = 10 << 20
 
 
-def _gather_kernel(arr_ref, idx_ref, out_ref, *, wp: int, j_out: int,
-                   perlead: bool):
-    # arr [1, Wp, Gb]; idx [J, Gb] (shared) or [1, J, Gb] (per-lead);
-    # out [1, J, Gb]
-    for j in range(j_out):
-        sel = idx_ref[0, j, :] if perlead else idx_ref[j, :]
-        acc = jnp.zeros_like(out_ref[0, j, :])
-        for i in range(wp):
-            acc = jnp.where(sel == i, arr_ref[0, i, :], acc)
-        out_ref[0, j, :] = acc
+def _lane_block(g: int, lane_bytes: int, budget: int) -> int:
+    """Lanes of one tile: the largest ``128 · 2^k`` that divides g and keeps
+    the double-buffered working set (``2 · lane_bytes`` a lane) within
+    ``budget``; 128 at the least (callers only guarantee g % 128 == 0, e.g.
+    max_groups = 4224)."""
+    gb = LANES
+    while g % (2 * gb) == 0 and 2 * lane_bytes * (2 * gb) <= budget:
+        gb *= 2
+    return gb
+
+
+def gather_lanes(lead: int, wp: int, j_out: int, g: int, itemsize: int,
+                 perlead: bool, budget: int = VMEM_BUDGET) -> int:
+    """Tile lanes of a gather: ``arr [lead, Wp, gb]``, ``idx [J, gb]`` (or
+    ``[lead, J, gb]``) and ``out [lead, J, gb]`` a grid step."""
+    rows = lead * wp + (lead if perlead else 1) * j_out + lead * j_out
+    return _lane_block(g, rows * itemsize, budget)
+
+
+def match_lanes(e_planes: int, j_out: int, g: int, itemsize: int,
+                budget: int = VMEM_BUDGET) -> int:
+    """Tile lanes of a key match: ``vals`` / ``keys [E, gb]`` and ``idx`` /
+    ``out [J, gb]`` a grid step (keys and idx are int32)."""
+    return _lane_block(g, (e_planes + j_out) * (itemsize + 4), budget)
+
+
+def _count_build(kernel: str, lanes: int) -> None:
+    from ..obs.metrics import registry
+
+    registry().counter(
+        "pallas_kernel_builds_total",
+        help="Pallas plane kernels built, by the tile lanes the rule chose",
+        kernel=kernel, lanes=str(lanes)).inc()
+
+
+def _gather_kernel(arr_ref, idx_ref, out_ref, *, perlead: bool):
+    # arr [lead, Wp, Gb]; idx [J, Gb] (shared) or [lead, J, Gb] (per-lead);
+    # out [lead, J, Gb]
+    lead, wp, _ = arr_ref.shape
+    for l in range(lead):
+        for j in range(out_ref.shape[1]):
+            sel = idx_ref[l, j, :] if perlead else idx_ref[j, :]
+            acc = jnp.zeros_like(out_ref[l, j, :])
+            for i in range(wp):
+                acc = jnp.where(sel == i, arr_ref[l, i, :], acc)
+            out_ref[l, j, :] = acc
 
 
 @functools.lru_cache(maxsize=None)
 def _build(lead: int, wp: int, j_out: int, g: int, dtype_name: str,
-           interpret: bool, perlead: bool = False):
+           interpret: bool, perlead: bool, budget: int):
     from jax.experimental import pallas as pl
 
     dtype = jnp.dtype(dtype_name)
-    gb = _lane_block(g)
-    kern = functools.partial(_gather_kernel, wp=wp, j_out=j_out,
-                             perlead=perlead)
+    gb = gather_lanes(lead, wp, j_out, g, dtype.itemsize, perlead, budget)
+    _count_build(GATHER_KERNEL, gb)
     idx_spec = (
-        pl.BlockSpec((1, j_out, gb), lambda l, b: (l, 0, b)) if perlead
-        else pl.BlockSpec((j_out, gb), lambda l, b: (0, b))
+        pl.BlockSpec((lead, j_out, gb), lambda b: (0, 0, b)) if perlead
+        else pl.BlockSpec((j_out, gb), lambda b: (0, b))
     )
     return pl.pallas_call(
-        kern,
+        functools.partial(_gather_kernel, perlead=perlead),
         out_shape=jax.ShapeDtypeStruct((lead, j_out, g), dtype),
-        grid=(lead, g // gb),
+        grid=(g // gb,),
         in_specs=[
-            pl.BlockSpec((1, wp, gb), lambda l, b: (l, 0, b)),
+            pl.BlockSpec((lead, wp, gb), lambda b: (0, 0, b)),
             idx_spec,
         ],
-        out_specs=pl.BlockSpec((1, j_out, gb), lambda l, b: (l, 0, b)),
+        out_specs=pl.BlockSpec((lead, j_out, gb), lambda b: (0, 0, b)),
         interpret=interpret,
         name=GATHER_KERNEL,
     )
@@ -123,11 +175,12 @@ def gather_planes_pallas(arr, idx, interpret: bool | None = None):
     if idx.ndim > 2:
         # per-lead indices: flatten into the lead axis pairing
         ix = idx.reshape(lead, j_out, g).astype(jnp.int32)
-        out = _build(lead, wp, j_out, g, str(a.dtype), interpret,
-                     perlead=True)(a, ix)
+        out = _build(lead, wp, j_out, g, str(a.dtype), interpret, True,
+                     VMEM_BUDGET)(a, ix)
     else:
         ix = idx.astype(jnp.int32)
-        out = _build(lead, wp, j_out, g, str(a.dtype), interpret)(a, ix)
+        out = _build(lead, wp, j_out, g, str(a.dtype), interpret, False,
+                     VMEM_BUDGET)(a, ix)
     out = out.reshape(*lead_shape, j_out, g)
     return out.astype(jnp.bool_) if squeeze_bool else out
 
@@ -145,11 +198,12 @@ def _kernel_match(vals_ref, keys_ref, idx_ref, out_ref, *, e_planes: int,
 
 @functools.lru_cache(maxsize=None)
 def _build_match(e_planes: int, j_out: int, g: int, dtype_name: str,
-                 interpret: bool):
+                 interpret: bool, budget: int):
     from jax.experimental import pallas as pl
 
     dtype = jnp.dtype(dtype_name)
-    gb = _lane_block(g)
+    gb = match_lanes(e_planes, j_out, g, dtype.itemsize, budget)
+    _count_build(MATCH_KERNEL, gb)
     kern = functools.partial(_kernel_match, e_planes=e_planes, j_out=j_out)
     return pl.pallas_call(
         kern,
@@ -182,7 +236,8 @@ def match_planes_pallas(vals, keys, idx, interpret: bool | None = None):
     j_out = idx.shape[0]
     squeeze_bool = vals.dtype == jnp.bool_
     v = vals.astype(jnp.int32) if squeeze_bool else vals
-    out = _build_match(e_planes, j_out, g, str(v.dtype), interpret)(
+    out = _build_match(e_planes, j_out, g, str(v.dtype), interpret,
+                       VMEM_BUDGET)(
         v, keys.astype(jnp.int32), idx.astype(jnp.int32)
     )
     return out.astype(jnp.bool_) if squeeze_bool else out

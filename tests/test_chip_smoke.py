@@ -50,6 +50,9 @@ def test_rehearsal_runs_every_stage_with_interpreted_kernels(tmp_path):
                    "read back 3 acknowledged values",
                    "wide wave: 1,024 groups executed on 3 replicas",
                    "served tick: {'pallas_calls': 26, 'interpreted': 26",
+                   # the tile the rule took at 4,096 lanes: one block
+                   "kernel=gather_planes_pallas,lanes=4096",
+                   "kernel=match_planes_pallas,lanes=4096",
                    "program mixed: compiled and ran",
                    "program lease: compiled and ran",
                    "program health: compiled and ran",
